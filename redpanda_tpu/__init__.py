@@ -22,8 +22,33 @@ Layer map (mirrors SURVEY.md §1):
 
 __version__ = "0.1.0"
 
-# Offsets/terms are int64 end-to-end across the device tensors; enable
-# x64 at package init so no module depends on import order for it.
+# The one place that configures JAX for the whole package.
+import os as _os
+
 import jax as _jax
 
+# Offsets/terms are int64 end-to-end across the device tensors; enable
+# x64 at package init so no module depends on import order for it.
 _jax.config.update("jax_enable_x64", True)
+
+# Persistent compile cache. The tick and codec kernels are large XLA
+# programs, and a cold compile runs synchronously on the event loop,
+# so every process of a deployment (and every leg of chip_smoke.py)
+# shares one cache. JAX_COMPILATION_CACHE_DIR, when set, is JAX's own
+# setting and wins untouched; otherwise the cache sits at a fixed path
+# inside the checkout — a cache that moves between runs never hits.
+# A process pinned to the CPU (JAX_PLATFORMS=cpu: the tests, the
+# tools/ smokes) gets none: XLA:CPU compiles these programs in
+# seconds, and every load of a cached XLA:CPU executable prints a
+# machine-feature warning from its AOT loader.
+if (
+    not _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    and _jax.config.jax_platforms != "cpu"
+):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(
+            _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+            ".jax_cache",
+        ),
+    )

@@ -1608,10 +1608,12 @@ class AdminServer(HttpServer):
         return mgr.status()
 
     async def _devplane(self, _m, q, _b):
-        """Device-plane flight data (observability/devplane.py): frame
-        dispatch->ready quantiles, cross-chip folds per frame (the
-        RPL018 runtime invariant), host<->device transfer bytes,
-        per-kernel latency, and warmup-vs-steady compile counts.
+        """Device-plane flight data (observability/devplane.py): the
+        device the kernels run on (`device`: platform, device_kind,
+        device_count — null until one has run), frame dispatch->ready
+        quantiles, cross-chip folds per frame (the RPL018 runtime
+        invariant), host<->device transfer bytes, per-kernel latency,
+        and warmup-vs-steady compile counts.
         Sharded brokers merge every worker's devplane registry over
         invoke_on — raw buckets on the wire, exact quantiles — unless
         `fleet=0` asks for the local process only."""

@@ -723,6 +723,11 @@ class Broker:
 
         syschecks.run_startup_checks(self.config.data_dir)
         syschecks.note_startup(self.config.data_dir)
+        # say which device the configured device plane runs on; fatal
+        # when a device switch is on and JAX silently fell back to CPU
+        from .observability import devplane as _devplane
+
+        _devplane.startup_check()
         self.scheduler.start()
         for svc in (
             self.group_manager.service,

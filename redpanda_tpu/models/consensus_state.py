@@ -43,9 +43,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Offsets are int64 end-to-end; enable x64 before any array is created.
-jax.config.update("jax_enable_x64", True)
-
 from .fundamental import NO_OFFSET as _NO_OFFSET
 
 DEFAULT_REPLICA_SLOTS = 8

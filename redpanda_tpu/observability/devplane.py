@@ -26,6 +26,13 @@ real-ICI validation run reports from, built on three legs:
     attribution stack themselves, so attribution works with the guard
     off.
 
+The plane also says WHICH device it is observing: `device()` /
+the `devplane_device_info` gauge / the digest's `device` block carry
+platform, device_kind and device count, read off the arrays the first
+instrumented kernel returned (never assumed from configuration), and
+`startup_check()` refuses the one silent fallback there is — a device
+switch on, and JAX quietly on the CPU because it found no accelerator.
+
 All families live in one process-global `registry` (the device is
 process-global; broker instances are not) and are *adopted* into each
 broker/shard registry (`MetricsRegistry.adopt`), so they ride the
@@ -44,6 +51,7 @@ none of them sit on the steady tick path's per-event hot loop.
 
 from __future__ import annotations
 
+import logging
 import os
 import time
 from contextlib import contextmanager
@@ -51,6 +59,8 @@ from contextlib import contextmanager
 from ..metrics import HistogramChild, MetricsRegistry, _NBUCKETS
 from ..utils import compileguard
 from . import trace
+
+logger = logging.getLogger("devplane")
 
 ENABLED = os.environ.get("RP_DEVPLANE", "") == "1"
 
@@ -158,6 +168,121 @@ def register(reg: MetricsRegistry) -> None:
         )
 
 
+# ------------------------------------------------------ device identity
+#: the switches that route served-path work to a device, with the
+#: values that select it (raft/shard_state._backend, models/record,
+#: compression._zstd_backend, kafka/server.fetch_verify_enabled)
+_DEVICE_SWITCHES = {
+    "RP_QUORUM_BACKEND": ("device", "mesh"),
+    "RP_CRC_BACKEND": ("device",),
+    "RP_CODEC_BACKEND": ("device",),
+    "RP_ZSTD_BACKEND": ("tpu",),
+    "RP_FETCH_VERIFY": ("1",),
+}
+
+#: what this process's device plane runs on, as JAX reports it:
+#: {"platform", "device_kind", "device_count"}. None until the first
+#: instrumented kernel returns (or startup_check ran) — reading it
+#: earlier would initialise a backend the process may never need.
+_DEVICE: "dict | None" = None
+
+
+def device_switches() -> dict[str, str]:
+    """The device-selecting switches that are on in this environment."""
+    on = {}
+    for name, values in _DEVICE_SWITCHES.items():
+        v = os.environ.get(name, "").strip().lower()
+        if v in values:
+            on[name] = v
+    return on
+
+
+def format_switches(on: dict[str, str]) -> str:
+    return " ".join(f"{k}={v}" for k, v in sorted(on.items()))
+
+
+def _note_device(dev) -> dict:
+    global _DEVICE
+    import jax
+
+    _DEVICE = {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
+    }
+    return _DEVICE
+
+
+def device() -> "dict | None":
+    """Where the device plane's kernels ran (None: none has run yet)."""
+    return _DEVICE
+
+
+def holds_accelerator() -> bool:
+    """True once this process has run device work on a non-CPU
+    platform: it owns the chip, and a forked or spawned child that
+    needs one would fail or hang."""
+    return _DEVICE is not None and _DEVICE["platform"] != "cpu"
+
+
+def startup_check() -> "dict | None":
+    """Broker start-up: say which device the configured device plane
+    runs on, and refuse the one silent fallback there is — a device
+    switch is on, JAX was not told which platform to use, and it found
+    no accelerator, so every "device" kernel would run on XLA:CPU.
+    JAX_PLATFORMS=cpu (tests, the smokes) states that choice and passes.
+    With no switch on, JAX's backend is left untouched."""
+    on = device_switches()
+    if not on:
+        logger.info("device plane: host (no device switch is set)")
+        return None
+    import jax
+
+    facts = _note_device(jax.devices()[0])
+    asked = jax.config.jax_platforms or ""
+    switches = format_switches(on)
+    if facts["platform"] == "cpu" and asked.split(",")[0] != "cpu":
+        raise RuntimeError(
+            f"device plane configured ({switches}) but JAX found no "
+            f"accelerator and fell back to platform 'cpu' "
+            f"(JAX_PLATFORMS={asked!r}). A chip belongs to one process "
+            "at a time: another process may hold it. Set "
+            "JAX_PLATFORMS=cpu to run the device path on XLA:CPU on "
+            "purpose."
+        )
+    logger.info(
+        "device plane: %s on platform=%s device_kind=%s device_count=%d",
+        switches,
+        facts["platform"],
+        facts["device_kind"],
+        facts["device_count"],
+    )
+    return facts
+
+
+def _device_samples() -> list[tuple[dict, float]]:
+    d = _DEVICE
+    if d is None:
+        return []
+    return [
+        (
+            {"platform": d["platform"], "device_kind": d["device_kind"]},
+            float(d["device_count"]),
+        )
+    ]
+
+
+DEVICE_FAMILY = f"{registry.prefix}_devplane_device_info"
+
+registry.gauge(
+    "devplane_device_info",
+    _device_samples,
+    "the device the instrumented kernels run on, as JAX reports it "
+    "(labels: platform, device_kind; value: visible device count); "
+    "absent until the first kernel has run",
+)
+
+
 # ---------------------------------------------------------------- scopes
 _TICK_DEPTH = 0
 _FRAME_DEPTH = 0
@@ -230,6 +355,18 @@ def _block_until_ready(out):
     return jax.block_until_ready(out)
 
 
+def _note_output_device(out) -> None:
+    """Record where a kernel's result actually lives — the platform is
+    read off the returned arrays, not assumed from configuration."""
+    import jax
+
+    for leaf in jax.tree_util.tree_leaves(out):
+        devs = getattr(leaf, "devices", None)
+        if devs is not None:
+            _note_device(min(devs(), key=lambda d: d.id))
+            return
+
+
 class _Probe:
     """Dispatch→ready probe for one instrumented kernel: forwards to
     the underlying callable (a raw jit fn or compileguard._Guard),
@@ -260,6 +397,8 @@ class _Probe:
             out = self.fn(*args, **kwargs)
             out = _block_until_ready(out)
             self._child.observe(time.perf_counter() - t0)
+            if _DEVICE is None:
+                _note_output_device(out)
             return out
         finally:
             compileguard.pop_kernel()
@@ -365,6 +504,7 @@ def merged_status(snaps: list) -> dict:
     tick_violations = 0.0
     compiles: dict[str, dict] = {}
     jit_cache: dict[str, float] = {}
+    device: "dict | None" = None
     frame_hist: dict[str, HistogramChild] = {}
     kernel_hist: dict[str, HistogramChild] = {}
     for snap in snaps:
@@ -397,6 +537,13 @@ def merged_status(snaps: list) -> dict:
                 elif fam.name == JIT_CACHE_FAMILY and "kernel" in lab:
                     k = lab["kernel"]
                     jit_cache[k] = max(jit_cache.get(k, 0.0), s.value)
+                elif fam.name == DEVICE_FAMILY and device is None:
+                    # one chip, one process: only one shard can report
+                    device = {
+                        "platform": lab.get("platform", ""),
+                        "device_kind": lab.get("device_kind", ""),
+                        "device_count": int(s.value),
+                    }
         for hf in snap.hists:
             if hf.name == FRAME_FAMILY:
                 store, key = frame_hist, "frame"
@@ -419,6 +566,7 @@ def merged_status(snaps: list) -> dict:
         "enabled": True,
         "sample_every": SAMPLE_EVERY,
         "shards": len(snaps),
+        "device": device,
         "frames": {k: int(v) for k, v in sorted(frames.items())},
         "frames_total": int(frames_total),
         "folds": int(folds),

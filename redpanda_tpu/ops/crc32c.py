@@ -265,5 +265,7 @@ def crc32c_batch_device(bufs: np.ndarray, lens: np.ndarray) -> np.ndarray:
     padded[:n, : bufs.shape[1]] = bufs
     plens = np.zeros(rows, np.int64)
     plens[:n] = lens
+    devplane.count_transfer(padded.nbytes + plens.nbytes, "h2d")
     out = np.asarray(crc32c_device(jnp.asarray(padded), jnp.asarray(plens)))
+    devplane.count_transfer(out.nbytes, "d2h")
     return out[:n]

@@ -140,6 +140,7 @@ def crc_zstd_fused(
         batch[i, :PREFIX] = np.frombuffer(p, np.uint8)
         batch[i, PREFIX : PREFIX + a.size] = a
         body_len[i] = a.size
+    devplane.count_transfer(batch.nbytes + body_len.nbytes, "h2d")
     crc, nbits, streams, bits = _fused_zstd(
         jnp.asarray(batch), jnp.asarray(body_len), n
     )
@@ -147,6 +148,9 @@ def crc_zstd_fused(
     nbits = np.asarray(nbits)
     streams = np.asarray(streams)
     bits = np.asarray(bits)
+    devplane.count_transfer(
+        crc.nbytes + nbits.nbytes + streams.nbytes + bits.nbytes, "d2h"
+    )
     frames = []
     for i, a in enumerate(arrs):
         if a.size == 0:
@@ -205,12 +209,16 @@ def _fused_entry(prefixes, bodies, kernel, bound_fn, preamble_fn):
         batch[i, :PREFIX] = np.frombuffer(p, np.uint8)
         batch[i, PREFIX : PREFIX + a.size] = a
         body_len[i] = a.size
+    devplane.count_transfer(batch.nbytes + body_len.nbytes, "h2d")
     crc, out, out_len = kernel(
         jnp.asarray(batch), jnp.asarray(body_len), n
     )
     crc = np.asarray(crc)[: len(arrs)]
     out = np.asarray(out)
     out_len = np.asarray(out_len)
+    devplane.count_transfer(
+        crc.nbytes + out.nbytes + out_len.nbytes, "d2h"
+    )
     assert int(out_len.max()) <= bound_fn(n)
     blocks = []
     for i in range(len(arrs)):

@@ -173,8 +173,10 @@ def compress_chunks(chunks: list[bytes | np.ndarray]) -> list[bytes]:
     for i, a in enumerate(arrs):
         batch[i, : a.size] = a
         valid[i] = a.size
+    devplane.count_transfer(batch.nbytes + valid.nbytes, "h2d")
     out, out_len = _compress_chunks(jnp.asarray(batch), jnp.asarray(valid), n)
     out = np.asarray(out)
     out_len = np.asarray(out_len)
+    devplane.count_transfer(out.nbytes + out_len.nbytes, "d2h")
     assert int(out_len.max()) <= out_bound(n), "lz4 out_bound violated"
     return [out[i, : out_len[i]].tobytes() for i in range(len(arrs))]

@@ -163,9 +163,11 @@ def compress_chunks(chunks: list[bytes | np.ndarray]) -> list[bytes]:
     for i, a in enumerate(arrs):
         batch[i, : a.size] = a
         valid[i] = a.size
+    devplane.count_transfer(batch.nbytes + valid.nbytes, "h2d")
     out, out_len = _compress_chunks(jnp.asarray(batch), jnp.asarray(valid), n)
     out = np.asarray(out)
     out_len = np.asarray(out_len)
+    devplane.count_transfer(out.nbytes + out_len.nbytes, "d2h")
     assert int(out_len.max()) <= out_bound(n), "snappy out_bound violated"
     return [
         _preamble(int(valid[i])) + out[i, : out_len[i]].tobytes()

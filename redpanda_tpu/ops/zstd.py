@@ -225,12 +225,16 @@ def encode_chunks(
     for i, a in enumerate(arrs):
         batch[i, : a.size] = a
         valid[i] = a.size
+    devplane.count_transfer(batch.nbytes + valid.nbytes, "h2d")
     nbits, streams, bits = _encode_chunks(
         jnp.asarray(batch), jnp.asarray(valid), n
     )
     nbits = np.asarray(nbits)
     streams = np.asarray(streams)
     bits = np.asarray(bits)
+    devplane.count_transfer(
+        nbits.nbytes + streams.nbytes + bits.nbytes, "d2h"
+    )
     out = []
     for i in range(len(arrs)):
         sl = [
@@ -323,6 +327,11 @@ def decode_streams(
     for i, t in enumerate(tables):
         tsym[i] = t[0]
         tnb[i] = t[1]
+    devplane.count_transfer(
+        bufs.nbytes + tbits.nbytes + regen_v.nbytes + tsym.nbytes
+        + tnb.nbytes,
+        "h2d",
+    )
     out, end = _decode_streams(
         jnp.asarray(bufs),
         jnp.asarray(tbits),
@@ -334,6 +343,7 @@ def decode_streams(
     )
     out = np.asarray(out)
     end = np.asarray(end)
+    devplane.count_transfer(out.nbytes + end.nbytes, "d2h")
     if int(np.abs(end).max(initial=0)) != 0:
         bad = int(np.flatnonzero(end)[0])
         raise ValueError(

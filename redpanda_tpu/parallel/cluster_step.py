@@ -39,20 +39,6 @@ from ..ops.quorum import quorum_commit_step
 from ..utils import compileguard
 from .mesh import SHARD_AXIS
 
-# jax.shard_map went public in newer releases; older jax ships it under
-# jax.experimental only.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _axis_size(axis):
-    try:
-        return jax.lax.axis_size(axis)
-    except AttributeError:  # pragma: no cover - version-dependent
-        return jax.lax.psum(1, axis)
-
 RF = 3  # replication factor modeled by the ring placement
 
 
@@ -111,7 +97,7 @@ def cluster_tick(
     commit advanced and of stranded followers that installed the
     leader's snapshot boundary this round."""
     axis = SHARD_AXIS
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     leader = state.leader
 
     # 1. local append: self slot tracks the leader log (flush immediate
@@ -258,7 +244,7 @@ def election_round(
     if not (1 <= candidate_hop < RF):
         raise ValueError(f"candidate_hop must be in [1, {RF}): {candidate_hop}")
     axis = SHARD_AXIS
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     j = candidate_hop - 1
     leader = state.leader
     fol_term = state.fol_term
@@ -372,7 +358,7 @@ def election_round_sharded(mesh: Mesh, candidate_hop: int = 1):
     if not (1 <= candidate_hop < RF):
         raise ValueError(f"candidate_hop must be in [1, {RF}): {candidate_hop}")
     spec, state_specs = _cluster_specs(mesh)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda s, m: election_round(s, m, candidate_hop),
         mesh=mesh,
         in_specs=(state_specs, spec),
@@ -387,7 +373,7 @@ def election_round_sharded(mesh: Mesh, candidate_hop: int = 1):
 def cluster_tick_sharded(mesh: Mesh):
     """Build the jitted shard_map'd cluster step for `mesh`."""
     spec, state_specs = _cluster_specs(mesh)
-    fn = _shard_map(
+    fn = jax.shard_map(
         cluster_tick,
         mesh=mesh,
         in_specs=(state_specs, spec),

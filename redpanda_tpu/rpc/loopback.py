@@ -50,8 +50,6 @@ from ..utils.crc import crc32c
 from .server import Dispatcher, Service
 from .types import RpcError, Status
 
-_TIMEOUT_CTX = getattr(asyncio, "timeout", None)  # 3.11+
-
 
 @dataclass
 class NetRule:
@@ -312,15 +310,12 @@ class LoopbackTransport:
         try:
             coro = self._net.deliver(self.src, self.dst, method_id, payload)
             if timeout is not None:
-                # asyncio.timeout (3.11+) arms a timer on the current
-                # task instead of wrapping the coro in a new Task the
-                # way wait_for does — one Task per RPC was ~5% of the
+                # asyncio.timeout arms a timer on the current task
+                # instead of wrapping the coro in a new Task the way
+                # wait_for does — one Task per RPC was ~5% of the
                 # replicated-bench core
-                if _TIMEOUT_CTX is not None:
-                    async with _TIMEOUT_CTX(timeout):
-                        return await coro
-                # 3.10 fallback: a Task per RPC, but functional
-                return await asyncio.wait_for(coro, timeout)
+                async with asyncio.timeout(timeout):
+                    return await coro
             return await coro
         except (TimeoutError, asyncio.TimeoutError):
             raise RpcError(Status.TIMEOUT, f"method {method_id} timed out")

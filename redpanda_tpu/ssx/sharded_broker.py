@@ -69,6 +69,7 @@ from .shards import (
     ShardContext,
     ShardRuntime,
     bind_reuse_port,
+    device_plane_conflict,
     reserve_reuse_port,
     standdown_reason,
 )
@@ -1250,6 +1251,12 @@ class ShardedBroker:
     async def start(self) -> None:
         from ..app import Broker
 
+        if self.n_shards > 1:
+            conflict = device_plane_conflict()
+            if conflict is not None:
+                raise RuntimeError(
+                    f"--shards {self.n_shards} refused: {conflict}"
+                )
         reason = (
             "n_shards <= 1" if self.n_shards <= 1 else standdown_reason()
         )

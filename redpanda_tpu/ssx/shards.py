@@ -152,6 +152,33 @@ def standdown_reason() -> Optional[str]:
     return None
 
 
+def device_plane_conflict() -> Optional[str]:
+    """Why this process must not fork shard workers, or None. A chip
+    belongs to one process at a time: a worker configured for a device
+    backend could not reach the chip shard 0 holds (it fails, hangs,
+    or — worst — ticks on XLA:CPU beside a shard 0 on the TPU), and
+    forking a parent that already holds the chip duplicates a live
+    device runtime. Unlike standdown_reason this is a refusal, not a
+    quiet single-process fallback: the one-chip layout is --shards 1."""
+    from ..observability import devplane
+
+    on = devplane.device_switches()
+    if on:
+        return (
+            f"device plane configured ({devplane.format_switches(on)}): "
+            "a chip belongs to one process, so shard workers cannot "
+            "share it — run one process per chip (--shards 1)"
+        )
+    if devplane.holds_accelerator():
+        d = devplane.device()
+        return (
+            f"this process holds the {d['platform']} device "
+            f"({d['device_kind']}): forking it would duplicate a live "
+            "device runtime"
+        )
+    return None
+
+
 def reserve_reuse_port(
     host: str = "127.0.0.1", port: int = 0
 ) -> tuple[socket.socket, int]:
@@ -649,6 +676,9 @@ class ShardRuntime:
         keeps exactly those peer sockets and drops every other socket
         fd it inherited from the live parent (listeners, sibling
         channels — keeping them open would mask EOFs fleet-wide)."""
+        conflict = device_plane_conflict()
+        if conflict is not None:
+            raise RuntimeError(f"shard fork refused: {conflict}")
         pid = os.fork()
         if pid:
             return pid

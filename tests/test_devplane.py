@@ -180,6 +180,11 @@ assert st["tick_violations"] == 2, st["tick_violations"]
 # outside any tick scope, bare dispatches are not violations
 kern(jnp.zeros(8, jnp.int32))
 assert devplane.status()["tick_violations"] == 2
+# the digest says what the kernels ran on, read off their results
+dev = devplane.status()["device"]
+assert dev["platform"] == "cpu", dev
+assert dev["device_count"] == len(jax.devices()), dev
+assert not devplane.holds_accelerator()
 print("ARMED-BREACH-OK")
 """
 
@@ -299,3 +304,80 @@ def test_armed_sampling_cadence(tmp_path):
     )
     assert out.returncode == 0, out.stderr
     assert "ARMED-SAMPLING-OK" in out.stdout
+
+
+# -- device identity and the one-process-per-chip refusals --------------
+
+
+def _clear_switches(monkeypatch):
+    for name in devplane._DEVICE_SWITCHES:
+        monkeypatch.delenv(name, raising=False)
+
+
+def test_device_switches_lists_only_device_values(monkeypatch):
+    _clear_switches(monkeypatch)
+    assert devplane.device_switches() == {}
+    monkeypatch.setenv("RP_QUORUM_BACKEND", "host")
+    monkeypatch.setenv("RP_FETCH_VERIFY", "0")
+    assert devplane.device_switches() == {}
+    monkeypatch.setenv("RP_QUORUM_BACKEND", "mesh")
+    monkeypatch.setenv("RP_ZSTD_BACKEND", "TPU")
+    assert devplane.device_switches() == {
+        "RP_QUORUM_BACKEND": "mesh", "RP_ZSTD_BACKEND": "tpu",
+    }
+
+
+def test_startup_check_states_device_and_refuses_silent_cpu(monkeypatch):
+    import jax
+
+    _clear_switches(monkeypatch)
+    monkeypatch.setattr(devplane, "_DEVICE", None)
+    # no switch: the backend is left alone, nothing to state
+    assert devplane.startup_check() is None
+    assert devplane.device() is None
+    # a switch on the CPU the process was TOLD to use (tests pin it)
+    monkeypatch.setenv("RP_QUORUM_BACKEND", "device")
+    facts = devplane.startup_check()
+    assert facts == devplane.device()
+    assert facts["platform"] == "cpu"
+    assert facts["device_count"] == len(jax.devices())
+    assert not devplane.holds_accelerator()
+    # the same, but nobody asked for the CPU: JAX fell back to it
+    class _NotAsked:
+        jax_platforms = None
+
+    monkeypatch.setattr(jax, "config", _NotAsked)
+    with pytest.raises(RuntimeError, match="fell back to platform 'cpu'"):
+        devplane.startup_check()
+
+
+def test_sharded_broker_refuses_a_device_plane(monkeypatch, tmp_path):
+    import asyncio
+
+    from redpanda_tpu.app import BrokerConfig
+    from redpanda_tpu.ssx import shards
+    from redpanda_tpu.ssx.sharded_broker import ShardedBroker
+
+    _clear_switches(monkeypatch)
+    monkeypatch.setattr(devplane, "_DEVICE", None)
+    assert shards.device_plane_conflict() is None
+    monkeypatch.setenv("RP_QUORUM_BACKEND", "device")
+    assert "one process" in shards.device_plane_conflict()
+    owner = ShardedBroker(
+        BrokerConfig(node_id=0, data_dir=str(tmp_path), members=[0]),
+        n_shards=2,
+    )
+    with pytest.raises(RuntimeError, match="--shards 2 refused"):
+        asyncio.run(owner.start())
+    assert owner.runtime is None and owner.broker is None
+    # and with no switch on, a parent that already holds a chip may
+    # not fork either (spawn_shard / crash-restart from a live parent)
+    _clear_switches(monkeypatch)
+    monkeypatch.setattr(
+        devplane, "_DEVICE",
+        {"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1},
+    )
+    assert devplane.holds_accelerator()
+    runtime = shards.ShardRuntime(2, lambda *a: None)
+    with pytest.raises(RuntimeError, match="shard fork refused"):
+        runtime._fork_child(1)

@@ -25,9 +25,12 @@ balanced sub-object that survived the window instead of parsing the
 line wholesale, which recovers the per-bench extras even when the
 headline was cut.
 
-`--selftest` exercises the whole path without running a bench (a
-synthetic summary built from the trajectory must pass; a degraded copy
-must fail) — that's the verify.sh smoke leg.
+`--selftest` exercises the whole path without running a bench and
+without any archived round: it writes its own two-round fixture
+trajectory (made-up values, one round front-truncated), then a summary
+matching it must pass and a degraded copy must fail — that's the
+verify.sh smoke leg. The repo keeps no BENCH_r*.json today (the seed
+rounds' records were removed in PR 21; see PERF.md).
 """
 
 from __future__ import annotations
@@ -38,6 +41,7 @@ import json
 import os
 import re
 import sys
+import tempfile
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -214,7 +218,40 @@ def gate(fresh: dict, history: list, tolerance: float) -> tuple[list, list]:
     return rows, failures
 
 
-def selftest(pattern: str, tolerance: float) -> int:
+def _write_fixture_trajectory(dirpath: str) -> None:
+    """The selftest's own two-round trajectory, in the archive format
+    ({n, cmd, rc, tail, parsed}) with made-up values: round 1 parsed,
+    round 2 with `parsed: null` and a summary line whose front was cut
+    off (the salvage path), one metric per graded unit family."""
+    r1 = {
+        "metric": "fixture_sweep_p99", "value": 0.5, "unit": "ms",
+        "extra": {
+            "crc": {"metric": "fixture_crc_gbps", "value": 10.0,
+                    "unit": "GB/s"},
+        },
+    }
+    r2_line = json.dumps({
+        "metric": "fixture_sweep_p99", "value": 0.4, "unit": "ms",
+        "extra": {
+            "crc": {"metric": "fixture_crc_gbps", "value": 12.0,
+                    "unit": "GB/s"},
+            "placement": {"metric": "fixture_skew", "value": 1.1,
+                          "unit": "skew"},
+        },
+    })
+    rounds = [
+        {"n": 1, "cmd": "fixture", "rc": 0, "tail": "", "parsed": r1},
+        # front-truncated: the headline's opening brace is gone, the
+        # sub-objects survive
+        {"n": 2, "cmd": "fixture", "rc": 0, "parsed": None,
+         "tail": "noise\n" + r2_line[25:]},
+    ]
+    for doc in rounds:
+        with open(os.path.join(dirpath, f"BENCH_r{doc['n']:02d}.json"), "w") as f:
+            json.dump(doc, f)
+
+
+def selftest(tolerance: float) -> int:
     # unit-direction contract first: the mesh_flat block grades three
     # lower-better families (x_wall_* flatness ratios, fold µs, lane
     # skew) next to the existing throughput/latency units
@@ -289,9 +326,11 @@ def selftest(pattern: str, tolerance: float) -> int:
               "through the absolute gate", file=sys.stderr)
         return 2
 
-    history = load_history(pattern)
-    if not history:
-        print(f"bench_gate selftest: no trajectory matched {pattern}",
+    with tempfile.TemporaryDirectory() as tmp:
+        _write_fixture_trajectory(tmp)
+        history = load_history(os.path.join(tmp, "BENCH_r*.json"))
+    if len(history) != 2:
+        print("bench_gate selftest: fixture trajectory did not load",
               file=sys.stderr)
         return 2
     latest = history[-1][2]
@@ -343,12 +382,12 @@ def main() -> int:
                     help="allowed fractional regression (default 0.25 — "
                     "single-run benches on shared hardware are noisy)")
     ap.add_argument("--selftest", action="store_true",
-                    help="validate extraction+grading against the "
-                    "trajectory itself; no bench run needed")
+                    help="validate extraction+grading against a "
+                    "built-in fixture trajectory; no bench run needed")
     args = ap.parse_args()
 
     if args.selftest:
-        return selftest(args.history, args.tolerance)
+        return selftest(args.tolerance)
     if not args.summary:
         ap.error("--summary FILE required (or --selftest)")
 

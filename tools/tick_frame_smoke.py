@@ -147,6 +147,51 @@ def run_schedule(n: int, seed: int):
     return arrays, rows, advanced_sets, times
 
 
+#: the lanes a fold writes, compared byte for byte across backends
+PARITY_LANES = (
+    "commit_index",
+    "last_visible",
+    "match_index",
+    "flushed_index",
+    "last_seq",
+)
+
+
+def run_parity(n: int, seed: int, backend: str = "device") -> dict:
+    """Replay the identical schedule under RP_QUORUM_BACKEND=host and
+    =`backend` ("device" or "mesh") and require byte-identical
+    PARITY_LANES plus identical advanced-row sets; then hold the
+    `backend` run's lanes to the scalar oracle on a row sample.
+    Returns the `backend` run's arrays and counts (chip_smoke reads
+    lane attribution and fold times off them)."""
+    lanes = {}
+    runs = {}
+    for b in ("host", backend):
+        os.environ["RP_QUORUM_BACKEND"] = b
+        arrays, rows, advanced_sets, times = run_schedule(n, seed)
+        runs[b] = (arrays, rows, times)
+        lanes[b] = {
+            name: getattr(arrays, name)[rows].tobytes()
+            for name in PARITY_LANES
+        }
+        lanes[b]["advanced-row sets"] = [a.tobytes() for a in advanced_sets]
+    for name, want in lanes["host"].items():
+        assert want == lanes[backend][name], (
+            f"{name} diverged host vs {backend}"
+        )
+    arrays, rows, times = runs[backend]
+    oracle_check(arrays, rows, sample=2000, seed=seed + 1)
+    advanced_sets = lanes[backend]["advanced-row sets"]
+    return {
+        "arrays": arrays,
+        "rows": n,
+        "folds": len(advanced_sets),
+        "advanced": sum(len(a) // 8 for a in advanced_sets),
+        "fold_s": times,
+        "host_fold_s": runs["host"][2],
+    }
+
+
 def guard_check() -> str:
     """Fail the smoke on any steady-state recompile report; returns
     the status fragment for the OK line."""
@@ -179,28 +224,11 @@ def main() -> int:
     n = args.groups
 
     if args.parity:
-        lanes = {}
-        for backend in ("host", "device"):
-            os.environ["RP_QUORUM_BACKEND"] = backend
-            arrays, rows, advanced_sets, _ = run_schedule(n, args.seed)
-            lanes[backend] = (
-                arrays.commit_index[rows].tobytes(),
-                arrays.last_visible[rows].tobytes(),
-                [a.tobytes() for a in advanced_sets],
-            )
-        assert lanes["host"][0] == lanes["device"][0], (
-            "commit_index diverged host vs device"
-        )
-        assert lanes["host"][1] == lanes["device"][1], (
-            "last_visible diverged host vs device"
-        )
-        assert lanes["host"][2] == lanes["device"][2], (
-            "advanced-row sets diverged host vs device"
-        )
+        got = run_parity(n, args.seed)
         print(
             f"tick-frame parity OK: {n} rows, "
-            f"{len(lanes['host'][2])} folds byte-identical host vs "
-            f"device{guard_check()}"
+            f"{got['folds']} folds byte-identical host vs "
+            f"device, 2000-row oracle sample clean{guard_check()}"
         )
         return 0
 
