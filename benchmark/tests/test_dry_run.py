@@ -1,0 +1,111 @@
+"""A --cpu-dry-run of each cell at toy size prints a last line with the
+contract's keys; the controls and the planted faults come out as not
+correct. Each case boots brokers in a fresh interpreter: about half a
+minute apiece."""
+
+import json
+import os
+
+import pytest
+
+from benchmark.tests import faults
+from benchmark.tests.conftest import RESULT_KEYS, ROOT, dry_run
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    MANIFEST = json.load(_f)
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+E2E = {m["name"] for m in MANIFEST["end_to_end"]}
+LAYERS = {m["name"] for m in MANIFEST["per_layer"]}
+
+
+def _well_formed(line: dict, trace: int) -> None:
+    assert RESULT_KEYS <= set(line)
+    assert list(line)[-1] == "checks"
+    assert line["dry_run"] is True
+    assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(line["device"])
+    assert line["attempted"] > 0
+    for name, m in line["metrics"].items():
+        assert set(m) == {"value", "unit"} and name in (LAYERS if trace else E2E)
+    for c in line["checks"].values():
+        assert set(c) == {"value", "limit"}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_untraced_run_reports_every_end_to_end_metric(cell):
+    line = dry_run(cell, seed=2**31 + 11)
+    _well_formed(line, 0)
+    assert line["correct"] is True and line["failed"] == 0
+    assert set(line["metrics"]) == E2E
+    assert all(m["value"] > 0 for m in line["metrics"].values())
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_run_reports_per_layer_metrics(cell):
+    line = dry_run(cell, seed=2**31 + 12, trace=1)
+    _well_formed(line, 1)
+    assert line["correct"] is True
+    # the counters read on any platform; the trace's readers find no
+    # device plane on the CPU and say nothing rather than 0
+    assert {"tick_dispatch_ms", "h2d_bytes_per_acked_byte",
+            "elections_in_window"} <= set(line["metrics"])
+    assert not {"tick_roofline", "crc_roofline", "device_idle_pct"} & set(line["metrics"])
+    assert {"busy_s", "window_s"} <= set(line["device"])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_device_off_is_not_correct(cell):
+    line = dry_run(cell, "--control", "device_off", seed=2**31 + 13)
+    assert line["correct"] is False and line["control"] == "device_off"
+    assert line["checks"]["dispatched.quorum.heartbeat_tick"]["value"] == 0
+    assert line["checks"]["dispatched.crc32c.device"]["value"] == 0
+
+
+REPLICATED = next(w["name"] for w in MANIFEST["workloads"] if w["config"] == "rf3_1k")
+
+
+def test_control_rf1_is_not_correct():
+    line = dry_run(REPLICATED, "--control", "rf1", seed=2**31 + 14)
+    assert line["correct"] is False
+    assert line["checks"]["replicas_missing"]["value"] > 0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_flush_lagged_is_not_correct(cell):
+    """Acknowledgements that run ahead of the flush: the acks read as
+    they arrive find fewer than a majority of the logs flushed."""
+    line = dry_run(cell, "--control", "flush_lagged", seed=2**31 + 16)
+    assert line["correct"] is False and line["control"] == "flush_lagged"
+    assert line["checks"]["not_flushed_at_ack"]["value"] > 0
+    others = {k: v for k, v in line["checks"].items() if k != "not_flushed_at_ack"}
+    assert all(
+        v["value"] >= 1 if v["limit"] == ">=1" else v["value"] == v["limit"]
+        for v in others.values()
+    ), others
+
+
+@pytest.mark.parametrize("fault", faults.FAULTS)
+def test_planted_fault_is_not_correct(fault):
+    line = dry_run(REPLICATED, seed=2**31 + 15, plant=fault)
+    assert line["correct"] is False, line["checks"]
+    failing = {
+        "tick_frozen": "acked",
+        "replica_left_out": "replicas_missing",
+        "answer_altered": "fetched_wrong",
+    }[fault]
+    c = line["checks"][failing]
+    assert c["value"] != c["limit"] and (failing != "acked" or c["value"] == 0)
+
+
+def test_no_accelerator_no_result():
+    """Without --cpu-dry-run, on a machine with no TPU: exit 5, nothing
+    on standard output."""
+    import subprocess
+    import sys
+
+    got = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", CELLS[0],
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=300,
+    )
+    assert got.returncode == 5 and got.stdout.strip() == ""

@@ -1,0 +1,31 @@
+"""Warm-up of the verify-on-read CRC's device programs (see
+warmers/tick.py for how a warmer is named and called)."""
+
+from __future__ import annotations
+
+import os
+
+import numpy as np
+
+from benchmark.opsbytes import ROW_FLOOR, fetch_crc_shape
+from benchmark.reference import BODY_AT
+
+
+def fetch_verify(brokers: list, config: dict, traffic: dict, tpl: list) -> None:
+    """`crc32c.device` at the stride of this traffic's batches and every
+    row bucket up to the most batches one fetch of it can return (a
+    fetch after a stall names many partitions)."""
+    if os.environ.get("RP_FETCH_VERIFY") != "1":
+        return
+    from redpanda_tpu.ops.crc32c import crc32c_batch_device
+
+    most, _stride = fetch_crc_shape(config, traffic, tpl)
+    body = max(len(t.wire) for t in tpl) - BODY_AT
+    rows = ROW_FLOOR
+    while True:
+        crc32c_batch_device(
+            np.zeros((rows, body), np.uint8), np.full(rows, body, np.int64)
+        )
+        if rows >= most:
+            break
+        rows *= 2
