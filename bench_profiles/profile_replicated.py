@@ -70,9 +70,9 @@ async def main(n_partitions: int, duration_s: float, tag: str) -> None:
             gc.collect()
             gc.freeze()
             print("gc.freeze applied after setup", flush=True)
-        from redpanda_tpu.utils import spans as _spans
+        from redpanda_tpu.observability import devplane
 
-        _spans.reset()  # drop setup-phase accumulation (elections etc.)
+        devplane.reset()  # drop setup-phase accumulation (elections etc.)
         # GC pause tracking
         gc_pauses: list[tuple[int, float]] = []
         gc_t0 = [0.0]
@@ -165,12 +165,9 @@ async def main(n_partitions: int, duration_s: float, tag: str) -> None:
                 )
                 open(path, "w").write(s.getvalue())
                 print("saved", path, flush=True)
-        from redpanda_tpu.utils import spans
-
-        rep = spans.report()
-        if rep:
-            print("span report:", flush=True)
-            print(rep, flush=True)
+        # the window's spans (RP_DEVPLANE=1 arms the digest)
+        for name, h in devplane.status().get("host", {}).items():
+            print(f"span {name:<24} {h}", flush=True)
     finally:
         if client is not None:
             try:

@@ -761,11 +761,13 @@ class Broker:
         from .observability import alerts as _alerts
         from .observability import flightdata as _flightdata
         from .observability import profiler as _profiler
+        from .observability import trace as _trace
 
         if _flightdata.ENABLED:
             self.flightdata.start()
         if _profiler.ENABLED:
             self.profiler.acquire()
+        _trace.LoopLagProbe.acquire()
         if _alerts.ENABLED and _flightdata.ENABLED:
             self.alerts.start()
         await self.transforms.start()
@@ -871,11 +873,13 @@ class Broker:
         await self.transforms.stop()
         await self.stats_reporter.stop()
         from .observability import profiler as _profiler
+        from .observability import trace as _trace
 
         await self.alerts.stop()
         await self.flightdata.stop()
         if _profiler.ENABLED:
             self.profiler.release()
+        _trace.LoopLagProbe.release()
         pandaproxy, self.pandaproxy = self.pandaproxy, None
         if pandaproxy is not None:
             await pandaproxy.stop()

@@ -22,6 +22,7 @@ from ..models.fundamental import (
     TopicNamespace,
 )
 from ..models.record import RecordBatch, RecordBatchType
+from ..observability import trace
 from ..raft.consensus import NotLeaderError
 from ..raft.group_manager import GroupManager
 from ..raft.state_machine import StateMachine
@@ -1171,7 +1172,10 @@ class Controller:
                     self._balance_ticks += 1
                     if self._balance_ticks >= 5:  # ~5s of idle ticks
                         self._balance_ticks = 0
-                        await self._leader_balance_pass()
+                        # one span a pass (every ~5 s): the pass is
+                        # what moves leadership in a quiet cluster
+                        with trace.span("cluster.leader_balance", "wait"):
+                            await self._leader_balance_pass()
                         await self._partition_balance_pass()
                 continue
             for d in deltas:

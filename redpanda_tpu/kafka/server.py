@@ -643,7 +643,8 @@ class KafkaServer:
             if nat is not None:
                 hdr, req = nat
                 native_path = True
-                self.probe.decode[(0, True)](time.monotonic() - t_req)
+                t_decoded = time.monotonic()
+                self.probe.decode[(0, True)](t_decoded - t_req)
         if req is None:
             r = Reader(frame)
             hdr = decode_request_header(r)
@@ -709,7 +710,8 @@ class KafkaServer:
                 )
                 if req is None:
                     req = api.decode_request(body_mv, hdr.api_version)
-                self.probe.decode[(0, False)](time.monotonic() - t_req)
+                t_decoded = time.monotonic()
+                self.probe.decode[(0, False)](t_decoded - t_req)
             else:
                 req = api.decode_request(body_mv, hdr.api_version)
                 if hdr.api_key == 1:
@@ -733,14 +735,26 @@ class KafkaServer:
                 token = CURRENT_PRINCIPAL.set(ctx.principal)
                 itoken = CURRENT_INTERNAL.set(ctx.internal)
             if probe_key is not None and trace.ENABLED:
-                # flight-recorder root; its lifetime crosses into the
-                # write loop (on_written), so the contextvar scope
-                # (detach) and the end stamp (finish) split
+                # flight-recorder root, from the frame's arrival; its
+                # lifetime crosses into the write loop (on_written),
+                # so the contextvar scope (detach) and the end stamp
+                # (finish) split
                 root = self.broker.recorder.span(
                     "kafka.produce" if hdr.api_key == 0 else "kafka.fetch",
+                    "wait",
                     path="native" if native_path else "python",
                 )
-                root.__enter__()
+                root.begin(int(t_req * 1e9)).__enter__()
+                if hdr.api_key == 0:
+                    trace.record(
+                        "produce.decode", "run", root.start_ns,
+                        int(t_decoded * 1e9),
+                    )
+                    # the replicate stages run under the wait for the
+                    # ack, which opens when the handler has dispatched
+                    root.heir = trace.span(
+                        "produce.ack_wait", "wait", parent=root
+                    )
             t0 = asyncio.get_event_loop().time()
             try:
                 resp = await handler(hdr, req)
@@ -781,8 +795,8 @@ class KafkaServer:
             # staged handler (produce): dispatch done, response later —
             # encode when it settles, off the reader path
             async def finish(inner=resp, hdr=hdr, api=api, root=root):
-                if root is not None:
-                    with trace.span("produce.ack_wait", parent=root):
+                if root is not None and root.heir is not None:
+                    with root.heir:
                         body = await inner
                 else:
                     body = await inner
@@ -1888,7 +1902,8 @@ class KafkaServer:
         while True:
             if shard_router is not None:
                 await shard_prepass()
-            responses, total, has_error = read_all()
+            with trace.span("fetch.read"):
+                responses, total, has_error = read_all()
             # error partitions complete the fetch immediately — holding
             # the long-poll would stall the client's metadata refresh
             if has_error or total >= min_bytes:
@@ -1899,7 +1914,8 @@ class KafkaServer:
             await asyncio.sleep(min(0.005, deadline - now))
 
         if fetch_verify_enabled():
-            self._verify_fetch_response(responses)
+            with trace.span("fetch.verify"):
+                self._verify_fetch_response(responses)
         if session is not None:
             responses = self._finish_session_fetch(
                 session, responses, incremental

@@ -318,7 +318,7 @@ def frame_scope(kind: str):
     _FRAME_DEPTH += 1
     t0 = time.perf_counter()
     try:
-        with trace.span("devplane.frame", kind=kind):
+        with trace.span("devplane.frame", frame=kind):
             yield
     finally:
         _FRAME_DEPTH -= 1
@@ -393,10 +393,15 @@ class _Probe:
         try:
             if self._n != 1 and self._n % SAMPLE_EVERY:
                 return self.fn(*args, **kwargs)
-            t0 = time.perf_counter()
+            t0 = time.monotonic_ns()
             out = self.fn(*args, **kwargs)
             out = _block_until_ready(out)
-            self._child.observe(time.perf_counter() - t0)
+            t1 = time.monotonic_ns()
+            self._child.observe((t1 - t0) / 1e9)
+            # dispatch→ready on the span clock: the device execution
+            # lies inside it, which is what lays host spans beside a
+            # profiler trace
+            trace.record("device.dispatch", "run", t0, t1, kernel=self.name)
             if _DEVICE is None:
                 _note_output_device(out)
             return out
@@ -587,18 +592,26 @@ def merged_status(snaps: list) -> dict:
 
 
 def status() -> dict:
-    """Local-process digest (single-shard view of merged_status)."""
+    """Local-process digest (single-shard view of merged_status), with
+    what the process's spans added up to since the last reset():
+    `host`, `loop`, `spans` and `spans_dropped` (trace.WindowStore)."""
     if not ENABLED:
         return {"enabled": False}
-    return merged_status([snapshot()])
+    return {**merged_status([snapshot()]), **trace.WINDOW.status()}
 
 
 # ------------------------------------------------------------- harness
 def reset() -> None:
     """Zero every devplane counter and histogram in place (bench/test
     harness hook). In place because probes hold pre-resolved histogram
-    child refs — the objects must survive the reset."""
+    child refs — the objects must survive the reset. Empties the span
+    window store with them; raw span records are kept from here on
+    only where the probes wait for every dispatch, the plane's full
+    fidelity, so a sampled run pays for aggregates alone."""
     from .. import metrics as _metrics
+
+    trace.WINDOW.keep_raw = ENABLED and SAMPLE_EVERY == 1
+    trace.WINDOW.reset()
 
     for m in registry.families().values():
         if isinstance(m, _metrics.Counter):

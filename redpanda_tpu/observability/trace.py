@@ -11,12 +11,22 @@ probe/histogram layer. One `FlightRecorder` per broker holds:
   * a small event log for out-of-band markers (NemesisNet fault
     injections land here and also tag the span they hit).
 
-Span mechanics mirror utils/spans.py (the RP_SPANS featherweight
-profiler): a module-level ENABLED flag checked per call, a shared
-no-op context object when tracing is off, and `time.monotonic_ns()`
-stamps. Parent linkage is a contextvar within a task; across tasks
-(produce request -> batcher flush round) the caller captures
-`current_span()` and passes it back via `span(..., parent=...)`.
+This is the broker's one span system: a module-level ENABLED flag
+checked per call, a shared no-op context object when tracing is off,
+and `time.monotonic_ns()` stamps. Parent linkage is a contextvar within
+a task; across tasks (produce request -> batcher flush round) the
+caller captures `handoff_span()` and passes it back via
+`span(..., parent=...)`. A span is of one of two kinds: `run` (no
+`await` inside: the span holds the event loop) or `wait` (mostly a wait
+on something else: a future, an RPC, a thread's fsync).
+
+Besides its tree, every finished span feeds the process-global
+`WINDOW` store: per span name a count, total and self time and a
+latency histogram; a loop-lag histogram from one `LoopLagProbe` per
+event loop; and, while the device plane runs at full fidelity
+(`RP_DEVPLANE_SAMPLE=1`), the raw span records. The store rides
+`devplane.reset()` / `devplane.status()` (keys `host`, `loop`, `spans`,
+`spans_dropped`), which is how a benchmark window reads it.
 
 Env knobs:
   RP_TRACE=0          kill switch — span() returns the shared no-op,
@@ -27,12 +37,15 @@ Env knobs:
 
 from __future__ import annotations
 
+import asyncio
 import itertools
 import os
 import time
 from collections import deque
 from contextvars import ContextVar
 from typing import Optional
+
+from ..metrics import HistogramChild
 
 ENABLED = os.environ.get("RP_TRACE", "1") != "0"
 SLOW_MS = float(os.environ.get("RP_TRACE_SLOW_MS", "100"))
@@ -52,7 +65,8 @@ def _after_fork_child() -> None:
     """Fork hygiene: the id counter and the module-default recorder are
     copied by fork — reseed ids into a pid-disjoint range (stitched
     cross-process trees must never collide on span ids) and drop the
-    parent's trees/events from the child's recorder."""
+    parent's trees/events from the child's recorder, its window store
+    and its loops' lag probes."""
     global _ids
     _ids = itertools.count(((os.getpid() & 0x3FFFFF) << 40) | 1)
     r = _default_recorder
@@ -62,10 +76,29 @@ def _after_fork_child() -> None:
     r._events.clear()
     r.trees_total = 0
     r.frozen_total = 0
+    WINDOW.reset()
+    LoopLagProbe._by_loop = {}
 
 
 if hasattr(os, "register_at_fork"):
     os.register_at_fork(after_in_child=_after_fork_child)
+
+
+def _covered_ns(kids: list, lo: int, hi: int) -> int:
+    """Nanoseconds of [lo, hi) that the child intervals cover: their
+    union, clipped (children may overlap, and may start before a
+    parent that adopted them late)."""
+    total = 0
+    end = lo
+    for s, e in sorted(kids):
+        if e > hi:
+            e = hi
+        if s < end:
+            s = end
+        if e > s:
+            total += e - s
+            end = e
+    return total
 
 
 class Span:
@@ -76,6 +109,7 @@ class Span:
 
     __slots__ = (
         "name",
+        "kind",
         "span_id",
         "parent_id",
         "start_ns",
@@ -83,7 +117,10 @@ class Span:
         "tags",
         "trace_id",
         "origin",
+        "heir",
         "_root",
+        "_parent",
+        "_kids",
         "_recorder",
         "_spans",
         "_token",
@@ -92,15 +129,21 @@ class Span:
     def __init__(
         self,
         name: str,
+        kind: str = "run",
         parent: Optional["Span"] = None,
         recorder: Optional["FlightRecorder"] = None,
         tags: Optional[dict] = None,
     ):
         self.name = name
+        self.kind = kind
         self.span_id = next(_ids)
         self.start_ns = 0
         self.dur_ns = -1
         self.tags = tags
+        self._parent = parent
+        # (start, end) of every child that finished while this span
+        # was open: what finish() subtracts to get the self time
+        self._kids: Optional[list] = None
         if parent is not None:
             self.parent_id = parent.span_id
             self._root = parent._root
@@ -109,6 +152,9 @@ class Span:
             self._root = self
             # collector for every span in this tree, filled on exits
             self._spans: list[dict] = []
+            # the wait span a request root pre-makes for work that
+            # outlives its dispatch (see handoff_span)
+            self.heir: Optional["Span"] = None
             self._recorder = recorder if recorder is not None else _default_recorder
             r = _remote.get()
             if r is not None:
@@ -130,6 +176,7 @@ class Span:
     def _to_dict(self) -> dict:
         d = {
             "name": self.name,
+            "kind": self.kind,
             "id": self.span_id,
             "parent": self.parent_id,
             "start_ns": self.start_ns,
@@ -139,9 +186,17 @@ class Span:
             d["tags"] = self.tags
         return d
 
+    def begin(self, start_ns: int = 0) -> "Span":
+        """Stamp the start without entering the task's contextvar
+        scope — for a span whose lifetime crosses tasks, or whose
+        start a probe site already read off the same clock."""
+        self.start_ns = start_ns or time.monotonic_ns()
+        return self
+
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
-        self.start_ns = time.monotonic_ns()
+        if not self.start_ns:
+            self.start_ns = time.monotonic_ns()
         return self
 
     def detach(self) -> None:
@@ -157,16 +212,27 @@ class Span:
                 _current.set(None)
             self._token = None
 
-    def finish(self, exc_type=None) -> None:
-        """Stamp the end time and hand the tree to the recorder.
-        Idempotent; __exit__ is detach()+finish()."""
+    def finish(self, exc_type=None, end_ns: int = 0) -> None:
+        """Stamp the end time, feed the window store and hand the tree
+        to the recorder. Idempotent; __exit__ is detach()+finish()."""
         if self.dur_ns >= 0:
             return
-        self.dur_ns = time.monotonic_ns() - self.start_ns
+        end = end_ns or time.monotonic_ns()
+        self.dur_ns = end - self.start_ns
         if exc_type is not None:
             self.tag(error=exc_type.__name__)
         root = self._root
         root._spans.append(self._to_dict())
+        self_ns = self.dur_ns
+        if self._kids is not None:
+            self_ns -= _covered_ns(self._kids, self.start_ns, end)
+        p = self._parent
+        if p is not None and p.dur_ns < 0:
+            if p._kids is None:
+                p._kids = [(self.start_ns, end)]
+            else:
+                p._kids.append((self.start_ns, end))
+        WINDOW.add(self, self_ns)
         if root is self:
             rec = self._recorder
             if rec is not None:
@@ -192,10 +258,19 @@ class _NoopSpan:
     def tag(self, **tags):
         pass
 
+    def begin(self, start_ns=0):
+        return self
+
     def detach(self):
         pass
 
-    def finish(self, exc_type=None):
+    def finish(self, exc_type=None, end_ns=0):
+        pass
+
+    def next(self, name, kind="run"):
+        pass
+
+    def end(self):
         pass
 
     span_id = 0
@@ -205,22 +280,85 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _open_current() -> Optional[Span]:
+    """The task's current span, if it is still open. A task keeps the
+    context it was created under (a flush loop, a peer fiber, a
+    call_soon callback), so the contextvar can hold a span that
+    finished long ago: that one adopts no new children."""
+    s = _current.get()
+    if s is not None and s.dur_ns >= 0:
+        return None
+    return s
+
+
 def span(
     name: str,
+    kind: str = "run",
     parent: Optional[Span] = None,
     recorder: Optional["FlightRecorder"] = None,
     **tags,
 ):
-    """Open a trace span. Parent defaults to the task's current span;
-    pass `parent=` explicitly to stitch across tasks (e.g. a batcher
-    flush round adopting the first queued produce's span). Keep tag
-    values pre-formatted plain objects — building f-strings in the
-    argument list runs even when tracing is off (rplint RPL008)."""
+    """Open a trace span of kind `run` or `wait`. Parent defaults to
+    the task's current open span; pass `parent=` explicitly to stitch
+    across tasks (e.g. a batcher flush round adopting the first queued
+    produce's span). Keep tag values pre-formatted plain objects —
+    building f-strings in the argument list runs even when tracing is
+    off (rplint RPL008)."""
     if not ENABLED:
         return _NOOP
     if parent is None:
-        parent = _current.get()
-    return Span(name, parent=parent, recorder=recorder, tags=tags or None)
+        parent = _open_current()
+    return Span(name, kind, parent, recorder, tags or None)
+
+
+def record(
+    name: str,
+    kind: str,
+    start_ns: int,
+    end_ns: int,
+    parent: Optional[Span] = None,
+    **tags,
+) -> None:
+    """A span that is already over, from two stamps of the monotonic
+    clock its site took anyway (a probe histogram's pair, an item's
+    enqueue time): one call, no contextvar scope."""
+    if not ENABLED:
+        return
+    if parent is None:
+        parent = _open_current()
+    s = Span(name, kind, parent, None, tags or None)
+    s.start_ns = start_ns
+    s.finish(end_ns=end_ns)
+
+
+class _Phases:
+    """Consecutive child spans of one long function body, without
+    re-indenting it: next() closes the phase that is open and opens
+    the named one, end() closes the last."""
+
+    __slots__ = ("_open",)
+
+    def __init__(self) -> None:
+        self._open: Optional[Span] = None
+
+    def next(self, name: str, kind: str = "run") -> None:
+        self.end()
+        self._open = Span(name, kind, _open_current()).__enter__()
+
+    def tag(self, **tags) -> None:
+        if self._open is not None:
+            self._open.tag(**tags)
+
+    def end(self) -> None:
+        s, self._open = self._open, None
+        if s is not None:
+            s.__exit__(None, None, None)
+
+
+def phases():
+    if not ENABLED:
+        return _NOOP
+    return _Phases()
 
 
 def current_span() -> Optional[Span]:
@@ -228,7 +366,20 @@ def current_span() -> Optional[Span]:
     tracing is disabled — callers pass it straight back to span())."""
     if not ENABLED:
         return None
-    return _current.get()
+    return _open_current()
+
+
+def handoff_span() -> Optional[Span]:
+    """The span that work queued here for another task should hang
+    under: the `heir` of the request's root if it pre-made one (a
+    produce's `produce.ack_wait`, under which the replicate stages
+    run), else the current span."""
+    if not ENABLED:
+        return None
+    s = _open_current()
+    if s is not None and s._root.heir is not None:
+        return s._root.heir
+    return s
 
 
 def propagation_ctx() -> Optional[tuple[int, int]]:
@@ -288,11 +439,11 @@ class FlightRecorder:
         self.trees_total = 0
         self.frozen_total = 0
 
-    def span(self, name: str, **tags):
+    def span(self, name: str, kind: str = "run", **tags):
         """Open a *root* span recorded into this recorder."""
         if not ENABLED:
             return _NOOP
-        return Span(name, recorder=self, tags=tags or None)
+        return Span(name, kind, None, self, tags or None)
 
     def _finish_tree(self, root: Span) -> None:
         tree = {
@@ -356,6 +507,134 @@ class FlightRecorder:
             "ring": self.ring_tail(tail),
             "events": self.events(),
         }
+
+
+class WindowStore:
+    """What the spans of this process add up to since the last
+    reset(): the store a benchmark window reads through
+    `devplane.status()`. Process-global like the devplane registry (the
+    in-process brokers of a test or a benchmark share one event loop
+    and one device)."""
+
+    RAW_CAP = 1 << 18
+
+    def __init__(self) -> None:
+        # raw records are kept only at the device plane's full
+        # fidelity (devplane.reset() says when); aggregates always
+        self.keep_raw = False
+        self.reset()
+
+    def reset(self) -> None:
+        # name -> [kind, count, total_ns, self_ns, histogram]
+        self._agg: dict[str, list] = {}
+        self._raw: list[list] = []
+        self.dropped = 0
+        self._lag = HistogramChild()
+        self._lag_max = 0.0
+
+    def add(self, s: Span, self_ns: int) -> None:
+        a = self._agg.get(s.name)
+        if a is None:
+            a = self._agg[s.name] = [s.kind, 0, 0, 0, HistogramChild()]
+        a[1] += 1
+        a[2] += s.dur_ns
+        a[3] += self_ns
+        a[4].observe(s.dur_ns / 1e9)
+        if self.keep_raw:
+            if len(self._raw) < self.RAW_CAP:
+                self._raw.append([
+                    s.name, s.kind, s.start_ns, s.dur_ns, s.span_id,
+                    s.parent_id, s._root.trace_id, s.tags,
+                ])
+            else:
+                self.dropped += 1
+
+    def add_lag(self, seconds: float) -> None:
+        self._lag.observe(seconds)
+        if seconds > self._lag_max:
+            self._lag_max = seconds
+
+    def status(self) -> dict:
+        return {
+            "host": {
+                name: {
+                    "kind": kind,
+                    "count": n,
+                    "total_s": total / 1e9,
+                    "self_s": own / 1e9,
+                    "p50_ms": h.quantile(0.50) * 1e3,
+                    "p99_ms": h.quantile(0.99) * 1e3,
+                }
+                for name, (kind, n, total, own, h) in sorted(self._agg.items())
+            },
+            "loop": {
+                "samples": self._lag._count,
+                "lag_p50_ms": self._lag.quantile(0.50) * 1e3,
+                "lag_p99_ms": self._lag.quantile(0.99) * 1e3,
+                "lag_max_ms": self._lag_max * 1e3,
+            },
+            "spans": list(self._raw),
+            "spans_dropped": self.dropped,
+        }
+
+
+WINDOW = WindowStore()
+
+
+class LoopLagProbe:
+    """How long ready work waits for the one thread everything shares:
+    a timer due every 10 ms records how late it ran (Seastar's reactor
+    stall detector, as a histogram). One per event loop, refcounted
+    across the brokers that share the loop."""
+
+    INTERVAL_S = 0.010
+    _by_loop: dict = {}
+
+    def __init__(self, loop: asyncio.AbstractEventLoop) -> None:
+        self._loop = loop
+        self._refs = 0
+        self._handle: Optional[asyncio.TimerHandle] = None
+        self._due = 0.0
+
+    @classmethod
+    def acquire(cls) -> None:
+        """Start (or share) the running loop's probe; a no-op with
+        tracing off."""
+        if not ENABLED:
+            return
+        loop = asyncio.get_running_loop()
+        probe = cls._by_loop.get(loop)
+        if probe is None:
+            # dead loops must not pile up (suites create thousands)
+            cls._by_loop = {
+                l: p for l, p in cls._by_loop.items() if not l.is_closed()
+            }
+            probe = cls._by_loop[loop] = cls(loop)
+        probe._refs += 1
+        if probe._refs == 1:
+            probe._arm()
+
+    @classmethod
+    def release(cls) -> None:
+        if not ENABLED:
+            return
+        loop = asyncio.get_running_loop()
+        probe = cls._by_loop.get(loop)
+        if probe is None:
+            return
+        probe._refs -= 1
+        if probe._refs <= 0:
+            if probe._handle is not None:
+                probe._handle.cancel()
+            del cls._by_loop[loop]
+
+    def _arm(self) -> None:
+        self._due = self._loop.time() + self.INTERVAL_S
+        self._handle = self._loop.call_at(self._due, self._fire)
+
+    def _fire(self) -> None:
+        WINDOW.add_lag(max(0.0, self._loop.time() - self._due))
+        self._arm()
 
 
 # fallback recorder for spans opened outside any broker (unit tests,

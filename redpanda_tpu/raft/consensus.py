@@ -46,8 +46,9 @@ from ..models.fundamental import NO_OFFSET
 from ..storage import snapshot as snapfmt
 from ..storage.kvstore import KeySpace, KvStore, KvStoreClosed
 from ..storage.log import Log
+from ..observability import trace
 from ..utils import native as native_mod
-from ..utils import serde, spans
+from ..utils import serde
 from ..utils.locks import LockMap
 from ..utils.retry_chain import RetryChainAborted, RetryChainNode
 from . import quorum_scalar as qs
@@ -721,9 +722,18 @@ class Consensus:
         return self._has_majority(granted)
 
     async def dispatch_vote(self, leadership_transfer: bool = False) -> bool:
-        """One election round (vote_stm.cc). Returns True on win.
+        """One election round (vote_stm.cc). Returns True on win."""
+        # an election is rare (a handful a minute, not one a tick): a
+        # span each is what names a leadership move in a trace
+        with trace.span(
+            "raft.election", "wait", transfer=leadership_transfer
+        ) as sp:
+            won = await self._dispatch_vote(leadership_transfer)
+            sp.tag(won=won)
+            return won
 
-        The vote lock is held only for the local state mutations, NOT
+    async def _dispatch_vote(self, leadership_transfer: bool) -> bool:
+        """The vote lock is held only for the local state mutations, NOT
         across the remote gather — two simultaneous candidates holding
         their locks across RPCs would block each other's handle_vote
         until timeout and systematically fail contested rounds."""
@@ -927,7 +937,7 @@ class Consensus:
     ) -> rt.AppendEntriesReply:
         """Follower-side append path (consensus.cc:1734 do_append_entries),
         serialized per group (append_entries_buffer analog)."""
-        with spans.span("follower.append_total"):
+        with trace.span("raft.follower_append", path="python"):
             async with self._append_lock:
                 return await self._do_append_entries(req)
 
@@ -939,8 +949,11 @@ class Consensus:
         dispatches through handle_append_entries as usual."""
         if self._frozen:
             return None  # decode route answers with the frozen reply
-        async with self._append_lock:
-            return self.native_append_frame(payload)
+        # one span round the native call (append and flush both happen
+        # inside it): none within
+        with trace.span("raft.follower_append", path="native"):
+            async with self._append_lock:
+                return self.native_append_frame(payload)
 
     def _reply(self, status: int, seq: int) -> rt.AppendEntriesReply:
         return rt.AppendEntriesReply(
@@ -1028,7 +1041,8 @@ class Consensus:
             appended = True
             last_new_entry = batch.header.last_offset
         if appended or req.flush:
-            with spans.span("follower.flush"):
+            # synchronous: the fsync holds the event loop
+            with trace.span("raft.follower_flush"):
                 flushed = self.log.flush()
             new_offs = self.log.offsets()
             self.arrays.match_index[row, SELF_SLOT] = new_offs.dirty_offset
@@ -1296,6 +1310,10 @@ class Consensus:
                 observe(now - it.t0)
                 # fsync-done -> quorum ack (the pure commit-wait tail)
                 observe_quorum(now - it.t_q0)
+                trace.record(
+                    "raft.quorum_wait", "wait", int(it.t_q0 * 1e9),
+                    int(now * 1e9), parent=it.span,
+                )
 
     def _fail_quorum_waiters(self, make_exc) -> None:
         waiters, self._quorum_waiters = self._quorum_waiters, []
@@ -1462,7 +1480,6 @@ class Consensus:
         if lock.locked():
             return  # a fiber is already driving this follower
         async with lock:
-            spans.add("catchup.enter", 1.0)
             # while this fiber drives the follower, the batched
             # heartbeat skips its slot (consensus::suppress_heartbeats):
             # every dispatch carries term/commit anyway, and a tick-time
@@ -1505,7 +1522,6 @@ class Consensus:
                 return
             rounds += 1
             if rounds > 1:
-                spans.add("catchup.extra_round", 1.0)
                 self.probe.recovery_rounds.inc()
             slot = self._slot_map.get(peer)
             if slot is None:
@@ -1589,7 +1605,7 @@ class Consensus:
                 return await self._dispatch_append_send(
                     peer, row, slot, term, next_idx, prev, prev_term, batches
                 )
-        with spans.span("leader.read"):
+        with trace.span("raft.read"):
             batches = self.log.read(next_idx, max_bytes=1 << 20) if next_idx <= offs.dirty_offset else []
         return await self._dispatch_append_send(
             peer, row, slot, term, next_idx, prev, prev_term, batches
@@ -1608,7 +1624,7 @@ class Consensus:
             return False
         seq = int(self.arrays.next_seq[row, slot]) + 1
         self.arrays.next_seq[row, slot] = seq
-        with spans.span("leader.encode"):
+        with trace.span("raft.encode"):
             req = rt.AppendEntriesRequest(
                 group=self.group_id,
                 node_id=self.node_id,
@@ -1621,15 +1637,13 @@ class Consensus:
                 flush=True,
                 batches=[b.serialize() for b in batches],
             ).encode()
-        if spans.ENABLED:
-            spans.add(
-                "leader.rpc_empty" if not batches else "leader.rpc_data", 1.0
-            )
-            if self.group_id == 0:
-                spans.add("leader.rpc_g0", 1.0)
         try:
+            # one AppendEntries round trip to one follower, whether or
+            # not it rides the aggregator's frame
             t_wire = time.monotonic()
-            with spans.span("leader.rpc"):
+            with trace.span(
+                "raft.wire", "wait", batches=len(batches)
+            ).begin(int(t_wire * 1e9)):
                 raw = await self._send(peer, rt.APPEND_ENTRIES, req, 5.0)
             self.probe.observe_stage_wire(time.monotonic() - t_wire)
             rep = rt.AppendEntriesReply.decode(raw)
@@ -1911,6 +1925,10 @@ class Consensus:
     # ------------------------------------------------------ membership
     async def transfer_leadership(self, target: int, timeout: float = 5.0) -> None:
         """reference: consensus.cc do_transfer_leadership → timeout_now."""
+        with trace.span("raft.transfer_leadership", "wait", target=target):
+            await self._transfer_leadership(target, timeout)
+
+    async def _transfer_leadership(self, target: int, timeout: float) -> None:
         if self.role != Role.LEADER:
             raise NotLeaderError(self.leader_id)
         if target not in self._slot_map:

@@ -35,7 +35,7 @@ from . import shard_state
 from . import types as rt
 from .consensus import Consensus, Role
 from ..models.consensus_state import SELF_SLOT
-from ..utils import spans
+from ..observability import trace
 
 logger = logging.getLogger("raft.heartbeat")
 
@@ -183,7 +183,7 @@ class HeartbeatManager:
         while not self._closed:
             try:
                 t0 = time.perf_counter()
-                with spans.span("hb.tick"):
+                with trace.span("hb.tick", "wait"):
                     await self.tick()
                 if self.probe is not None:
                     self.probe.heartbeat_tick_hist.observe(
@@ -239,7 +239,9 @@ class HeartbeatManager:
         # slot re-enters the beat + lag scan: the recovery-fallback
         # role of the tick is unchanged.
         sent: dict[int, tuple] = {}
-        t_build = time.perf_counter() if spans.ENABLED else 0.0
+        # one span per phase of the tick, never one per group
+        phase = trace.phases()
+        phase.next("hb.build")
         for peer, p in plan.items():
             if (
                 p.same_epoch is not None
@@ -360,8 +362,7 @@ class HeartbeatManager:
                 spliced,
             )
 
-        if spans.ENABLED:
-            spans.add("hb.build", time.perf_counter() - t_build)
+        phase.end()
 
         async def one_node(peer: int, msg: bytes):
             try:
@@ -386,16 +387,13 @@ class HeartbeatManager:
             else:
                 p.same_epoch = None  # follower diverged: full next tick
 
-        t_send = time.perf_counter() if spans.ENABLED else 0.0
+        phase.next("hb.send_wait", "wait")
         results = await asyncio.gather(
             *(one_node(peer, entry[3]) for peer, entry in sent.items()),
             *(one_same(peer, msg) for peer, msg in same_sent.items()),
         )
         results = results[: len(sent)]
-        t_fold = 0.0
-        if spans.ENABLED:
-            spans.add("hb.send_wait", time.perf_counter() - t_send)
-            t_fold = time.perf_counter()
+        phase.next("hb.fold")
 
         # fold: flatten every successful reply into one batch
         rows_acc: list[np.ndarray] = []
@@ -574,10 +572,7 @@ class HeartbeatManager:
             # no heartbeat replies this tick, but the replicate window
             # has pending rows: drain them on the tick cadence too
             frame.flush()
-        t_scan = 0.0
-        if spans.ENABLED:
-            spans.add("hb.fold", time.perf_counter() - t_fold)
-            t_scan = time.perf_counter()
+        phase.next("hb.scan")
         # recovery: schedule catch-up for lagging followers, found with
         # one vector compare per peer (match/flushed vs leader dirty).
         # Slots with a live fiber are excluded — their lag is in-flight
@@ -625,10 +620,9 @@ class HeartbeatManager:
                 if c.role == Role.LEADER:
                     c.kick_catch_up(peer)
                     n_spawned += 1
-        if spans.ENABLED:
-            spans.add("hb.scan", time.perf_counter() - t_scan)
-            if n_spawned:
-                spans.add("hb.spawned", float(n_spawned))
+        if n_spawned:
+            phase.tag(spawned=n_spawned)
+        phase.end()
 
     def _handle_failure(
         self, c: Consensus, peer: int, reply: rt.HeartbeatReply, i: int

@@ -33,7 +33,6 @@ from typing import TYPE_CHECKING, Optional
 from ..models.record import RecordBatch, RecordBatchBuilder
 from ..models.consensus_state import SELF_SLOT
 from ..observability import trace
-from ..utils import spans
 
 if TYPE_CHECKING:  # pragma: no cover
     from .consensus import Consensus
@@ -81,10 +80,11 @@ class _Item:
         # fsync-done stamp (re-set by _flush_round): quorum-stage
         # latency = resolve time - t_q0, the pure commit-wait tail
         self.t_q0 = self.t0
-        # requester's open trace span (the produce dispatch), captured
-        # here because the flush round runs in a different task — it
-        # parents the round's raft.append/raft.flush spans
-        self.span = trace.current_span()
+        # the requester's span that waits for this item (a produce's
+        # ack wait), captured here because the flush round runs in a
+        # different task — it parents the item's raft.coalesce and
+        # raft.quorum_wait and the round's raft.append/raft.flush
+        self.span = trace.handoff_span()
 
 
 class ReplicateBatcher:
@@ -195,23 +195,27 @@ class ReplicateBatcher:
         t_append = time.monotonic()
         # coalesce stage: enqueue -> this round picking the item up
         observe_coalesce = c.probe.observe_stage_coalesce
+        append_ns = int(t_append * 1e9)
         for it in items:
             observe_coalesce(t_append - it.t0)
-        with trace.span("raft.append", parent=items[0].span, items=len(items)):
-            with spans.span("batcher.append"):
-                for it in items:
-                    it.base, it.last = c.log.append(it.batch, term=term)
-                    round_last = it.last
-                    if it.acks == 0 and not it.stages.done.done():
-                        it.stages.done.set_result((it.base, it.last))
-                    appended.append(it)
+            trace.record(
+                "raft.coalesce", "wait", int(it.t0 * 1e9), append_ns,
+                parent=it.span,
+            )
+        with trace.span(
+            "raft.append", parent=items[0].span, items=len(items)
+        ).begin(append_ns):
+            for it in items:
+                it.base, it.last = c.log.append(it.batch, term=term)
+                round_last = it.last
+                if it.acks == 0 and not it.stages.done.done():
+                    it.stages.done.set_result((it.base, it.last))
+                appended.append(it)
         c.probe.observe_append(time.monotonic() - t_append)
         c.probe.note_append(c.ledger_key, sum(it.size for it in items))
-        spans.add("batcher.round_items", float(len(items)))
         self.flush_rounds += 1
-        with trace.span("raft.flush", parent=items[0].span):
-            with spans.span("batcher.fsync"):
-                flushed = await c.log.flush_async()
+        with trace.span("raft.flush", "wait", parent=items[0].span):
+            flushed = await c.log.flush_async()
         # leadership may have moved while the fsync ran
         if c._closed or c.role != Role.LEADER or c.term != term:
             exc = NotLeaderError(c.leader_id)

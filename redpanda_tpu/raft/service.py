@@ -13,10 +13,10 @@ import asyncio
 import logging
 import struct
 
+from ..observability import trace
 from ..rpc import Service, method
 from ..storage import file_sanitizer, iofaults
 from ..utils import native as native_mod
-from ..utils import spans
 from . import types as rt
 
 logger = logging.getLogger("raft.service")
@@ -191,11 +191,10 @@ class RaftService(Service):
         # reply framing in one C call over the raw frame
         # (native/append_frame.cc via Consensus.try_native_append).
         # Debug instrumentation that must observe the Python write path
-        # (spans, file sanitizer, iofault injection) disables it, and
-        # any in-frame anomaly punts to the decode route below.
+        # (file sanitizer, iofault injection) disables it, and any
+        # in-frame anomaly punts to the decode route below.
         if (
-            not spans.ENABLED
-            and not file_sanitizer.enabled()
+            not file_sanitizer.enabled()
             and not iofaults.active()
             and native_mod.append_frame_ready()
             and len(payload) >= 14
@@ -227,6 +226,11 @@ class RaftService(Service):
 
     @method(rt.HEARTBEAT)
     async def heartbeat(self, payload: bytes) -> bytes:
+        # one span for the node-batch, never one per group
+        with trace.span("hb.follower"):
+            return await self._heartbeat(payload)
+
+    async def _heartbeat(self, payload: bytes) -> bytes:
         """Answer the whole node-batch with vector ops over the shard
         SoA — the follower half of the batched sweep. Mirrors
         Consensus.handle_heartbeat exactly; groups that need state

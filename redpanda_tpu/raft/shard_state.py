@@ -15,6 +15,7 @@ Rows are recycled through a free list; freed rows are neutralized
 from __future__ import annotations
 
 import os
+import time
 
 import numpy as np
 
@@ -23,7 +24,7 @@ from ..models.consensus_state import (
     SELF_SLOT,
     GroupState,
 )
-from ..observability import devplane
+from ..observability import devplane, trace
 from ..ops.health import health_reduce_np
 from ..utils import compileguard
 from . import quorum_scalar as qs
@@ -1140,9 +1141,14 @@ class ShardGroupArrays:
             else _EMPTY_ROWS
         )
         before = self.commit_index[touched].copy()
+        # the fold's host halves get a span each, beside the probe's
+        # device.dispatch: ten lanes up before it, five back after
+        t_up = time.monotonic_ns()
         state = self.to_device_state()
+        trace.record("tick.upload", "run", t_up, time.monotonic_ns())
         devplane.count_transfer(5 * g_rows.nbytes, "h2d")
         new = heartbeat_tick_jit(state, g_rows, g_slots, g_dirty, g_flushed, g_seqs)
+        t_back = time.monotonic_ns()
         # write back the sweep's outputs (np.array: the views produced
         # from jax buffers are read-only; rows must stay host-writable)
         self.commit_index[touched] = np.array(new.commit_index)[touched]  # rplint: disable=RPL002
@@ -1150,6 +1156,7 @@ class ShardGroupArrays:
         self.match_index = np.array(new.match_index)  # rplint: disable=RPL002
         self.flushed_index = np.array(new.flushed_index)  # rplint: disable=RPL002
         self.last_seq = np.array(new.last_seq)  # rplint: disable=RPL002
+        trace.record("tick.readback", "run", t_back, time.monotonic_ns())
         self._count_lane_readback()
         # commit/match/flushed are SAME lanes: invalidate armed frames
         # (host_tick bumps the epoch for the same reason)

@@ -36,6 +36,8 @@ import time
 
 import numpy as np
 
+from ..observability import trace
+
 _EMPTY = np.empty(0, np.int64)
 
 
@@ -157,9 +159,13 @@ class TickFrame:
         vectors with the pending columns and run the frame now —
         the heartbeat fold and the replicate-path window share one
         device call instead of two."""
-        n = self._n
-        if n == 0 and not self._force and not len(rows):
+        if self._n == 0 and not self._force and not len(rows):
             return _EMPTY
+        with trace.span("tick.fold"):
+            return self._fold(rows, slots, dirty, flushed, seqs)
+
+    def _fold(self, rows, slots, dirty, flushed, seqs) -> np.ndarray:
+        n = self._n
         t0 = time.monotonic()
         if n:
             pr = self._rows[:n]

@@ -14,6 +14,7 @@ import os
 import time
 
 from ..models.record import RecordBatch, WireSpan, span_to_wire
+from ..observability import trace
 from . import dirsync, file_sanitizer
 from .batch_cache import BatchCache, BatchCacheIndex
 from .segment import Segment
@@ -305,7 +306,9 @@ class Log:
         seg = self._active_segment(term)
         t0 = time.monotonic()
         seg.append(batch)
-        self._observe_append(time.monotonic() - t0)
+        t1 = time.monotonic()
+        self._observe_append(t1 - t0)
+        trace.record("storage.append", "run", int(t0 * 1e9), int(t1 * 1e9))
         if self._cache_index is not None:
             self._cache_index.put(batch)
         for fn in self.on_append:
@@ -375,7 +378,9 @@ class Log:
         t0 = time.monotonic()
         await seg.flush_async()
         # includes the flush-coalescer queueing delay (storage probe)
-        self._observe_flush_wait(time.monotonic() - t0)
+        t1 = time.monotonic()
+        self._observe_flush_wait(t1 - t0)
+        trace.record("storage.flush", "wait", int(t0 * 1e9), int(t1 * 1e9))
         return self._segments[-1].stable_offset
 
     # -- read --------------------------------------------------------

@@ -381,3 +381,78 @@ def test_sharded_broker_refuses_a_device_plane(monkeypatch, tmp_path):
     runtime = shards.ShardRuntime(2, lambda *a: None)
     with pytest.raises(RuntimeError, match="shard fork refused"):
         runtime._fork_child(1)
+
+
+# -- the span window store rides reset()/status() -----------------------
+
+_WINDOW_KEYS = """\
+import numpy as np
+from redpanda_tpu.observability import devplane, trace
+
+def fn(x):
+    return x
+
+kern = devplane.instrument(fn, "t.kern")
+with trace.span("before.reset"):
+    pass
+devplane.reset()
+with trace.span("outer", "wait"):
+    for _ in range(3):
+        kern(np.ones(4))
+st = devplane.status()
+assert st["enabled"] is True and st["sample_every"] == SAMPLE
+assert "before.reset" not in st["host"], st["host"]
+assert st["host"]["outer"]["kind"] == "wait"
+assert st["host"]["outer"]["count"] == 1
+d = st["host"]["device.dispatch"]
+# the probe spans exactly the calls it times
+assert d["count"] == st["kernels"]["t.kern"]["count"] == TIMED, d
+assert d["kind"] == "run" and d["total_s"] > 0
+assert st["host"]["outer"]["self_s"] <= st["host"]["outer"]["total_s"]
+assert set(st["loop"]) == {
+    "samples", "lag_p50_ms", "lag_p99_ms", "lag_max_ms"}
+assert st["spans_dropped"] == 0
+if SAMPLE == 1:
+    rows = [s for s in st["spans"] if s[0] == "device.dispatch"]
+    assert len(rows) == 3
+    assert all(s[7] == {"kernel": "t.kern"} for s in rows), rows
+    outer = next(s for s in st["spans"] if s[0] == "outer")
+    # [name, kind, start_ns, dur_ns, id, parent, trace_id, tags]
+    assert all(s[5] == outer[4] and s[6] == outer[6] for s in rows)
+    assert all(s[2] >= outer[2] for s in rows)
+else:
+    # sampled: aggregates only, no raw record is kept
+    assert st["spans"] == []
+print("ARMED-WINDOW-OK")
+"""
+
+
+@pytest.mark.parametrize("sample,timed", [(1, 3), (16, 1)])
+def test_armed_status_carries_the_span_window(tmp_path, sample, timed):
+    if os.environ.get("RP_TRACE") == "0":
+        pytest.skip("RP_TRACE=0: no span reaches the store")
+    body = _WINDOW_KEYS.replace("SAMPLE", str(sample)).replace(
+        "TIMED", str(timed))
+    out = _run_armed(
+        tmp_path, body, extra_env={"RP_DEVPLANE_SAMPLE": str(sample)}
+    )
+    assert out.returncode == 0, out.stderr
+    assert "ARMED-WINDOW-OK" in out.stdout
+
+
+@_off
+def test_off_reset_empties_the_window_and_status_stays_off():
+    from redpanda_tpu.observability import trace
+
+    if not trace.ENABLED:
+        pytest.skip("RP_TRACE=0: no span reaches the store")
+    with trace.span("t.off"):
+        pass
+    assert trace.WINDOW.status()["host"]["t.off"]["count"] == 1
+    devplane.reset()
+    assert trace.WINDOW.status()["host"] == {}
+    # unarmed, no raw record is kept and the digest says only "off"
+    with trace.span("t.off"):
+        pass
+    assert trace.WINDOW.status()["spans"] == []
+    assert devplane.status() == {"enabled": False}

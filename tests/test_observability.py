@@ -815,3 +815,249 @@ def test_refork_children_span_ids_pairwise_disjoint():
     # the parent counter stays in the low range (seeded at 1), the
     # children in their pid-shifted ranges — three disjoint id planes
     assert parent_id < (1 << 40)
+
+
+# -- one span system: kinds, self time, the window store ----------------
+
+
+@pytest.fixture
+def window():
+    """The process-global span store, emptied and keeping raw records
+    for the test, left as it was found."""
+    w = trace.WINDOW
+    keep = w.keep_raw
+    w.keep_raw = True
+    w.reset()
+    yield w
+    w.keep_raw = keep
+    w.reset()
+
+
+def _raw(window, name):
+    return [s for s in window.status()["spans"] if s[0] == name]
+
+
+@needs_trace
+def test_self_time_is_duration_less_what_children_cover(window):
+    # hand-built tree on a made-up clock: parent [1000, 2000) with
+    # children [1100, 1300), [1200, 1500) (overlapping), [1900, 2100)
+    # (runs past the parent: clipped) and a grandchild that must not
+    # count twice. Covered: 1100-1500 and 1900-2000 = 500 of 1000.
+    with span("t.parent", "wait").begin(1000) as parent:
+        with span("t.child").begin(1200) as child:
+            trace.record("t.grandchild", "run", 1250, 1400)
+            child.finish(end_ns=1500)
+        trace.record("t.child", "run", 1100, 1300)
+        trace.record("t.child", "run", 1900, 2100)
+        parent.finish(end_ns=2000)
+    host = window.status()["host"]
+    assert host["t.parent"]["total_s"] == pytest.approx(1000e-9)
+    assert host["t.parent"]["self_s"] == pytest.approx(500e-9)
+    assert host["t.child"]["count"] == 3
+    # 300 + 200 + 200 in all, less the grandchild's 150
+    assert host["t.child"]["total_s"] == pytest.approx(700e-9)
+    assert host["t.child"]["self_s"] == pytest.approx(550e-9)
+    assert host["t.grandchild"]["self_s"] == pytest.approx(150e-9)
+
+
+@needs_trace
+def test_span_kinds_run_and_wait(window):
+    rec = FlightRecorder(ring_capacity=2)
+    with rec.span("t.root", "wait") as root:
+        with span("t.default"):
+            pass
+        trace.record("t.posthoc", "wait", root.start_ns, root.start_ns + 10)
+    kinds = {s["name"]: s["kind"] for s in rec.ring_tail()[-1]["spans"]}
+    assert kinds == {"t.root": "wait", "t.default": "run", "t.posthoc": "wait"}
+    host = window.status()["host"]
+    assert {n: host[n]["kind"] for n in kinds} == kinds
+    assert {s[0]: s[1] for s in window.status()["spans"]} == kinds
+
+
+@needs_trace
+def test_window_raw_records_cap_and_dropped(window, monkeypatch):
+    monkeypatch.setattr(trace.WindowStore, "RAW_CAP", 5)
+    with span("t.root") as root:
+        for _ in range(8):
+            with span("t.leaf", n=1):
+                pass
+    st = window.status()
+    assert len(st["spans"]) == 5 and st["spans_dropped"] == 4
+    # the aggregates never drop
+    assert st["host"]["t.leaf"]["count"] == 8
+    # [name, kind, start_ns, dur_ns, id, parent, trace_id, tags]
+    leaf = st["spans"][0]
+    assert leaf[:2] == ["t.leaf", "run"] and leaf[7] == {"n": 1}
+    assert leaf[5] == root.span_id and leaf[6] == root.trace_id
+    json.dumps(st)
+    # reset empties everything; without keep_raw only aggregates stay
+    window.keep_raw = False
+    window.reset()
+    with span("t.leaf"):
+        pass
+    st = window.status()
+    assert st["spans"] == [] and st["spans_dropped"] == 0
+    assert st["host"]["t.leaf"]["count"] == 1
+
+
+@needs_trace
+def test_finished_span_in_a_task_context_adopts_no_children(window):
+    """A task keeps the context it was created under: a span opened in
+    it after that context's span finished is a root of its own."""
+
+    async def main():
+        with span("t.request") as req:
+            task = asyncio.ensure_future(later())
+        await task
+        return req
+
+    async def later():
+        await asyncio.sleep(0)
+        with span("t.later") as s:
+            return s
+
+    req = asyncio.run(main())
+    (later_row,) = _raw(window, "t.later")
+    assert req.dur_ns >= 0
+    assert later_row[5] == 0 and later_row[6] != req.trace_id
+
+
+@needs_trace
+def test_phases_are_consecutive_children(window):
+    with span("t.tick", "wait") as tick:
+        ph = trace.phases()
+        ph.next("t.build")
+        ph.next("t.send", "wait")
+        with span("t.inner"):
+            pass
+        ph.tag(peers=2)
+        ph.next("t.scan")
+        ph.end()
+        assert trace.current_span() is tick
+    rows = {s[0]: s for s in window.status()["spans"]}
+    assert [rows[n][5] for n in ("t.build", "t.send", "t.scan")] == [
+        tick.span_id] * 3
+    assert rows["t.inner"][5] == rows["t.send"][4]
+    assert rows["t.send"][1] == "wait" and rows["t.send"][7] == {"peers": 2}
+    assert rows["t.build"][2] + rows["t.build"][3] <= rows["t.send"][2]
+
+
+def test_rp_trace_off_every_site_gets_the_shared_noop(monkeypatch):
+    monkeypatch.setattr(trace, "ENABLED", False)
+    w = trace.WINDOW
+    before = w.status()
+    noop = trace._NOOP
+    rec = FlightRecorder()
+    assert span("x") is noop and span("x", "wait", k=1) is noop
+    assert rec.span("x", "wait") is noop
+    assert trace.phases() is noop
+    assert span("x").begin(5) is noop
+    assert trace.current_span() is None and trace.handoff_span() is None
+    trace.record("x", "run", 1, 2)
+    with span("x") as s:
+        s.tag(a=1)
+    ph = trace.phases()
+    ph.next("x")
+    ph.tag(a=1)
+    ph.end()
+    noop.finish(end_ns=3)
+
+    async def probe():
+        trace.LoopLagProbe.acquire()
+        assert trace.LoopLagProbe._by_loop == {}
+        trace.LoopLagProbe.release()
+
+    asyncio.run(probe())
+    assert w.status() == before and rec.trees_total == 0
+
+
+@needs_trace
+def test_loop_lag_probe_sees_a_blocked_loop_and_is_shared(tmp_path, window):
+    import time as _time
+
+    async def main():
+        async with cluster(tmp_path, n=2):
+            loop = asyncio.get_running_loop()
+            probe = trace.LoopLagProbe._by_loop[loop]
+            # two brokers on one loop share one probe
+            assert len(trace.LoopLagProbe._by_loop) == 1
+            assert probe._refs == 2
+            window.reset()
+            await asyncio.sleep(0.05)
+            quiet = window.status()["loop"]
+            _time.sleep(0.05)  # hold the loop: every timer runs late
+            await asyncio.sleep(0.03)
+            return quiet, window.status()["loop"], probe, loop
+
+    quiet, loud, probe, loop = asyncio.run(main())
+    assert quiet["samples"] >= 2
+    assert loud["samples"] > quiet["samples"]
+    assert loud["lag_max_ms"] >= 40.0  # due 10 ms in, ran after 50
+    assert loud["lag_p99_ms"] >= 40.0 > quiet["lag_p50_ms"]
+    # both brokers stopped: the probe is gone with its timer
+    assert loop not in trace.LoopLagProbe._by_loop and probe._refs == 0
+
+
+async def _one_broker_produces(tmp_path, window, counts):
+    """The raw spans of one produce each of `counts` records through a
+    one-broker cluster, by trace id."""
+    from redpanda_tpu.models.record import RecordBatchBuilder
+
+    out = []
+    async with cluster(tmp_path, n=1) as (_net, brokers):
+        client = KafkaClient([brokers[0].kafka_advertised])
+        try:
+            await client.create_topic("spans", partitions=1, replication_factor=1)
+            await client.produce("spans", 0, [(None, b"warm")])
+            for n in counts:
+                b = RecordBatchBuilder()
+                for i in range(n):
+                    b.add(b"v" * 64, key=b"k%d" % i)
+                wire = b.build().to_kafka_wire()
+                window.reset()
+                await client.produce_wire("spans", 0, wire, acks=-1)
+                await asyncio.sleep(0.02)  # on_written runs after the ack
+                rows = window.status()["spans"]
+                (root,) = [s for s in rows if s[0] == "kafka.produce"]
+                out.append([s for s in rows if s[6] == root[6]])
+        finally:
+            await client.close()
+    return out
+
+
+@needs_trace
+def test_produce_is_one_tree_across_the_layer_boundaries(tmp_path, window):
+    (rows,) = asyncio.run(_one_broker_produces(tmp_path, window, [1]))
+    by_name = {s[0]: s for s in rows}
+    assert len(by_name) == len(rows), "a span name twice in one produce"
+    parent = {s[0]: next((p[0] for p in rows if p[4] == s[5]), None)
+              for s in rows}
+    assert parent == {
+        "kafka.produce": None,
+        "produce.decode": "kafka.produce",
+        "produce.dispatch": "kafka.produce",
+        "produce.ack_wait": "kafka.produce",
+        "raft.coalesce": "produce.ack_wait",
+        "raft.append": "produce.ack_wait",
+        "storage.append": "raft.append",
+        "raft.flush": "produce.ack_wait",
+        "storage.flush": "raft.flush",
+        "raft.quorum_wait": "produce.ack_wait",
+    }
+    assert len({s[6] for s in rows}) == 1  # one trace id
+    kinds = {s[0]: s[1] for s in rows}
+    assert [n for n, k in kinds.items() if k == "wait"] == [
+        n for n in kinds if n in (
+            "kafka.produce", "produce.ack_wait", "raft.coalesce",
+            "raft.flush", "storage.flush", "raft.quorum_wait")]
+    # the root runs from the frame's arrival: decode starts with it
+    root = by_name["kafka.produce"]
+    assert by_name["produce.decode"][2] == root[2]
+    for s in rows:
+        assert s[2] >= root[2] and s[2] + s[3] <= root[2] + root[3]
+
+
+@needs_trace
+def test_span_count_does_not_grow_with_records(tmp_path, window):
+    one, many = asyncio.run(_one_broker_produces(tmp_path, window, [1, 500]))
+    assert sorted(s[0] for s in one) == sorted(s[0] for s in many)
