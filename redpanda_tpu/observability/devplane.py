@@ -104,6 +104,12 @@ _TRANSFER_BYTES = registry.counter(
     "devplane_transfer_bytes_total",
     "host<->device transfer bytes, by direction (h2d|d2h)",
 )
+_STATE_SEEDS = registry.counter(
+    "devplane_state_seeds_total",
+    "whole-lane uploads that seeded a resident device state: one a "
+    "prewarm, capacity doubling or backend change; any more means the "
+    "tick is re-uploading its lanes",
+)
 _TICK_TRANSFERS = registry.counter(
     "devplane_tick_transfers_total",
     "device transfers/dispatches observed on the steady tick path "
@@ -127,6 +133,7 @@ FRAME_FAMILY = _FRAME_HIST.name
 FRAMES_FAMILY = _FRAMES.name
 FOLDS_FAMILY = _FOLDS.name
 TRANSFER_FAMILY = _TRANSFER_BYTES.name
+STATE_SEEDS_FAMILY = _STATE_SEEDS.name
 TICK_TRANSFER_FAMILY = _TICK_TRANSFERS.name
 COMPILES_FAMILY = _COMPILES.name
 COMPILE_SECS_FAMILY = _COMPILE_SECS.name
@@ -348,6 +355,13 @@ def count_transfer(nbytes: int, direction: str) -> None:
         _TICK_TRANSFERS.inc(kind="transfer")
 
 
+def count_state_seed() -> None:
+    """The tick had no resident device state left and uploaded its
+    lanes whole (ShardGroupArrays._fold_on_device)."""
+    if ENABLED:
+        _STATE_SEEDS.inc()
+
+
 # -------------------------------------------------------------- kernels
 def _block_until_ready(out):
     import jax
@@ -506,6 +520,7 @@ def merged_status(snaps: list) -> dict:
     frames: dict[str, float] = {}
     folds = 0.0
     transfers: dict[str, float] = {}
+    state_seeds = 0.0
     tick_violations = 0.0
     compiles: dict[str, dict] = {}
     jit_cache: dict[str, float] = {}
@@ -524,6 +539,8 @@ def merged_status(snaps: list) -> dict:
                 elif fam.name == TRANSFER_FAMILY and "direction" in lab:
                     d = lab["direction"]
                     transfers[d] = transfers.get(d, 0.0) + s.value
+                elif fam.name == STATE_SEEDS_FAMILY:
+                    state_seeds += s.value
                 elif fam.name == TICK_TRANSFER_FAMILY:
                     tick_violations += s.value
                 elif fam.name == COMPILES_FAMILY and "kernel" in lab:
@@ -579,6 +596,7 @@ def merged_status(snaps: list) -> dict:
         "transfer_bytes": {
             k: int(v) for k, v in sorted(transfers.items())
         },
+        "state_seeds": int(state_seeds),
         "tick_violations": int(tick_violations),
         "frame_ms": {
             k: _hist_digest(c) for k, c in sorted(frame_hist.items())

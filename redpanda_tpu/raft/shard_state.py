@@ -196,6 +196,9 @@ class ShardGroupArrays:
         # the mesh tick read it
         self._last_changed = _EMPTY_ROWS
         self._reserving = False
+        # device backend: the GroupState that stays on the device
+        # between folds (device_tick); None until a fold seeds it
+        self._resident: "GroupState | None" = None
 
     def touch(self) -> None:
         """Invalidate armed SAME-frame heartbeat state (see mut_epoch)."""
@@ -354,6 +357,7 @@ class ShardGroupArrays:
         self._free.extend(range(new - 1, old - 1, -1))
         self._cap = new
         self.voter_epoch += 1  # cached voter counts have the old shape
+        self._resident = None  # and so has the device's copy of the lanes
         # mid-traffic compile stall fix: _grow runs on the control
         # plane (row allocation), so compiling the device sweep at the
         # new capacity HERE keeps the next live tick at its
@@ -489,11 +493,15 @@ class ShardGroupArrays:
         return GroupState(*(jnp.asarray(a) for a in lanes))
 
     # The host fold is the DEFAULT everywhere; RP_QUORUM_BACKEND=device
-    # opts in. Where the device full-fold pays — it re-uploads the SoA
-    # lanes every fold (to_device_state) — is not measured on an
-    # attached chip (tools/measure_quorum_crossover.py takes the
-    # crossover; chip_smoke.py only proves the device path runs and
-    # agrees). The math is differentially tested identical either way,
+    # opts in. The device fold keeps a GroupState resident on the
+    # device and exchanges with it only the rows a fold touches, one
+    # packed upload and one packed readback a fold (device_tick); the
+    # whole lanes go up (to_device_state) only to seed that state, and
+    # again after _grow / reserve, prewarm or a change of backend, when
+    # its shape may no longer be the mirrors'. The numpy mirrors stay
+    # the truth for every host reader and writer. Where the device
+    # fold pays against the host's is tools/measure_quorum_crossover.py's
+    # to take; the math is differentially tested identical either way,
     # and steady-state ticks skip the fold entirely (incremental sweep).
 
     def _backend(self) -> str:
@@ -1053,11 +1061,32 @@ class ShardGroupArrays:
         lanes were pre-applied by the caller) always recompute — see
         host_tick.
 
-        The reply batch is padded to power-of-two buckets so XLA
-        compiles a handful of shapes total, not one per reply count;
-        padding entries carry seq = i64 min, which the fold's
-        reply-reordering guard drops (ops.quorum.fold_replies)."""
+        The device fold keeps a GroupState resident on the device and
+        moves only `touched` (the reply rows, the quorum_dirty rows,
+        the forced rows) in one upload and one readback
+        (_fold_on_device). That is sound without tracking who writes
+        a lane, because the kernel's result is consumed on `touched`
+        alone: commit and visible are written back there, and the fold
+        changes match / flushed / last_seq at reply pairs, whose rows
+        are in it. Every lane of a touched row is scattered fresh from
+        the mirrors inside the same dispatch, and no row's result
+        reads another row, so what the resident state holds anywhere
+        else — rows a host writer changed since, freed rows, whatever
+        the full-width commit step made of stale ones — is never
+        read. Only its shape has to be the mirrors': it is dropped,
+        and seeded again by the next fold, by _grow / reserve, prewarm
+        and a change of backend, and by nothing else.
+
+        Rows and reply window share one power-of-two bucket, so XLA
+        compiles one program a power of two up to the capacity (or the
+        largest window, prewarm's `max_replies`), not one per count;
+        padding replies carry seq = i64 min, which the fold's
+        reply-reordering guard drops (ops.quorum.fold_replies), and
+        padding rows an index past the lanes, which the scatter
+        drops."""
         backend = self._backend()
+        if backend != "device":
+            self._resident = None
         if backend == "host":
             return self.host_tick(
                 group_rows,
@@ -1080,8 +1109,6 @@ class ShardGroupArrays:
         # no reply can move match/flushed, no SELF slot moved, no row
         # is forced, and no config changed, fold only the seq guard
         # host-side and skip the device round-trip entirely
-        from ..models.consensus_state import SELF_SLOT as _SELF
-
         forced = force_rows is not None and len(force_rows) > 0
         if len(group_rows) and not forced and not self.quorum_dirty.any():
             fresh = seqs > self.last_seq[group_rows, replica_slots]
@@ -1093,10 +1120,10 @@ class ShardGroupArrays:
                 > self.flushed_index[group_rows[fresh], replica_slots[fresh]]
             )
             self_moved = (
-                self.match_index[group_rows, _SELF]
+                self.match_index[group_rows, SELF_SLOT]
                 != self._folded_self_m[group_rows]
             ) | (
-                self.flushed_index[group_rows, _SELF]
+                self.flushed_index[group_rows, SELF_SLOT]
                 != self._folded_self_f[group_rows]
             )
             if not may_move.any() and not self_moved.any():
@@ -1106,31 +1133,10 @@ class ShardGroupArrays:
                     seqs[fresh],
                 )
                 return _EMPTY_ROWS
-        from ..ops.quorum import heartbeat_tick_jit
-
-        m = len(group_rows)
-        bucket = 8
-        while bucket < m:
-            bucket *= 2
-        pad = bucket - m
-        g_rows = np.zeros(bucket, np.int64)
-        g_slots = np.zeros(bucket, np.int64)
-        g_dirty = np.full(bucket, I64_MIN, np.int64)
-        g_flushed = np.full(bucket, I64_MIN, np.int64)
-        g_seqs = np.full(bucket, I64_MIN, np.int64)
-        if m:
-            g_rows[:m] = group_rows
-            g_slots[:m] = replica_slots
-            g_dirty[:m] = last_dirty
-            g_flushed[:m] = last_flushed
-            g_seqs[:m] = seqs
-
         # commit/visible writeback is restricted to the reply rows plus
         # config-dirtied rows plus forced rows, exactly the set
         # host_tick recomputes — the two backends must advance
-        # IDENTICAL row sets (the differential tests pin this).
-        # match/flushed/last_seq are only modified by the fold (reply
-        # pairs), so full writeback of those equals partial.
+        # IDENTICAL row sets (the differential tests pin this)
         dirty_rows = np.flatnonzero(self.quorum_dirty)
         parts = [group_rows, dirty_rows]
         if forced:
@@ -1140,54 +1146,79 @@ class ShardGroupArrays:
             if any(len(p) for p in parts)
             else _EMPTY_ROWS
         )
-        before = self.commit_index[touched].copy()
-        # the fold's host halves get a span each, beside the probe's
-        # device.dispatch: ten lanes up before it, five back after
-        t_up = time.monotonic_ns()
-        state = self.to_device_state()
-        trace.record("tick.upload", "run", t_up, time.monotonic_ns())
-        devplane.count_transfer(5 * g_rows.nbytes, "h2d")
-        new = heartbeat_tick_jit(state, g_rows, g_slots, g_dirty, g_flushed, g_seqs)
-        t_back = time.monotonic_ns()
-        # write back the sweep's outputs (np.array: the views produced
-        # from jax buffers are read-only; rows must stay host-writable)
-        self.commit_index[touched] = np.array(new.commit_index)[touched]  # rplint: disable=RPL002
-        self.last_visible[touched] = np.array(new.last_visible)[touched]  # rplint: disable=RPL002
-        self.match_index = np.array(new.match_index)  # rplint: disable=RPL002
-        self.flushed_index = np.array(new.flushed_index)  # rplint: disable=RPL002
-        self.last_seq = np.array(new.last_seq)  # rplint: disable=RPL002
-        trace.record("tick.readback", "run", t_back, time.monotonic_ns())
-        self._count_lane_readback()
+        before = self.commit_index[touched]
+        m = max(len(group_rows), len(touched))
+        bucket = 8
+        while bucket < m:
+            bucket *= 2
+        self._fold_on_device(
+            touched,
+            (group_rows, replica_slots, last_dirty, last_flushed, seqs),
+            bucket,
+        )
         # commit/match/flushed are SAME lanes: invalidate armed frames
         # (host_tick bumps the epoch for the same reason)
         self.touch()
-        from ..models.consensus_state import SELF_SLOT as _SELF2
-
-        self._folded_self_m[touched] = self.match_index[touched, _SELF2]
-        self._folded_self_f[touched] = self.flushed_index[touched, _SELF2]
+        self._folded_self_m[touched] = self.match_index[touched, SELF_SLOT]
+        self._folded_self_f[touched] = self.flushed_index[touched, SELF_SLOT]
         self.quorum_dirty[:] = False
         self._health_np_rows(touched)
         return touched[self.commit_index[touched] > before]
 
-    def _count_lane_readback(self, health: bool = False) -> None:
-        """devplane d2h accounting for one device fold's writeback:
-        the five lanes the kernel returns come back whole (plus the
-        three health lanes when they rode along)."""
-        if not devplane.ENABLED:
-            return
-        n = (
-            2 * self.commit_index.nbytes
-            + self.match_index.nbytes
-            + self.flushed_index.nbytes
-            + self.last_seq.nbytes
+    def _fold_on_device(
+        self, touched: np.ndarray, window: tuple, bucket: int
+    ) -> None:
+        """One fold's exchange with the resident device state
+        (ops.quorum.resident_tick has the two layouts): every lane of
+        the `touched` rows and the reply `window`'s five columns go up
+        in one packed buffer of `bucket` rows, the five lanes a fold
+        changes come back at the same rows in one, and are written
+        into the mirrors in place. The whole lanes go up first only
+        where no resident state is left (`seed`)."""
+        import jax.numpy as jnp
+
+        from ..ops.quorum import TICK_READBACK_LANES, heartbeat_tick_jit
+
+        t_up = time.monotonic_ns()
+        # donated to the call: ours again only when it has returned
+        state, self._resident = self._resident, None
+        seed = state is None
+        if seed:
+            state = self.to_device_state()
+            devplane.count_state_seed()
+        t, r = len(touched), self.replica_slots
+        packed = np.zeros((bucket, 11 + 5 * r), np.int64)
+        packed[:t, 0] = touched
+        packed[t:, 0] = self._cap
+        col = 1
+        for name in GroupState._fields:
+            lane = getattr(self, name)
+            width = 1 if lane.ndim == 1 else r
+            packed[:t, col : col + width] = lane[touched].reshape(t, width)
+            col += width
+        m = len(window[0])
+        packed[m:, col + 2 :] = I64_MIN
+        for i, column in enumerate(window):
+            packed[:m, col + i] = column
+        up = jnp.asarray(packed)
+        devplane.count_transfer(packed.nbytes, "h2d")
+        trace.record(
+            "tick.upload", "run", t_up, time.monotonic_ns(), seed=int(seed)
         )
-        if health:
-            n += (
-                self.health_max_lag.nbytes
-                + self.health_under.nbytes
-                + self.health_leaderless.nbytes
+        self._resident, back = heartbeat_tick_jit(state, up)
+        t_back = time.monotonic_ns()
+        # the fold's one readback, after its one kernel
+        out = np.asarray(back)  # rplint: disable=RPL002
+        col = 0
+        for name in TICK_READBACK_LANES:
+            lane = getattr(self, name)
+            width = 1 if lane.ndim == 1 else r
+            lane[touched] = out[:t, col : col + width].reshape(
+                (t,) + lane.shape[1:]
             )
-        devplane.count_transfer(n, "d2h")
+            col += width
+        trace.record("tick.readback", "run", t_back, time.monotonic_ns())
+        devplane.count_transfer(out.nbytes, "d2h")
 
     def _gather_heartbeats(self, hb_rows: np.ndarray) -> dict:
         """Host-side heartbeat payload field gather for a row set —
@@ -1320,7 +1351,16 @@ class ShardGroupArrays:
         self.health_max_lag = np.array(health["max_lag"])  # rplint: disable=RPL002
         self.health_under = np.array(health["under_replicated"])  # rplint: disable=RPL002
         self.health_leaderless = np.array(health["leaderless"])  # rplint: disable=RPL002
-        self._count_lane_readback(health=True)
+        devplane.count_transfer(
+            2 * self.commit_index.nbytes
+            + self.match_index.nbytes
+            + self.flushed_index.nbytes
+            + self.last_seq.nbytes
+            + self.health_max_lag.nbytes
+            + self.health_under.nbytes
+            + self.health_leaderless.nbytes,
+            "d2h",
+        )
         self.touch()
         self._folded_self_m[touched] = self.match_index[touched, SELF_SLOT]
         self._folded_self_f[touched] = self.flushed_index[touched, SELF_SLOT]
@@ -1332,19 +1372,22 @@ class ShardGroupArrays:
         return touched[self.commit_index[touched] > before], hb
 
     def prewarm(self, max_replies: int = 0) -> None:
-        """Compile the sweep kernels for the empty reply bucket (and,
-        on the device backend, the fused frame's minimum heartbeat
-        bucket) up front so the first live tick doesn't stall the
-        event loop on XLA compilation (which would starve heartbeats
-        and trigger spurious elections). Re-invoked by _grow so a
-        capacity doubling never hands the next tick a fresh trace at
-        the new [G, R] shape (the mid-traffic compile stall).
+        """Compile the sweep kernels up front so the first live tick
+        doesn't stall the event loop on XLA compilation (which would
+        starve heartbeats and trigger spurious elections). Re-invoked
+        by _grow so a capacity doubling never hands the next tick a
+        fresh trace at the new [G, R] shape (the mid-traffic compile
+        stall).
 
-        `max_replies` (device backend): also compile every larger
-        reply bucket a window of up to that many replies can land in.
-        Each bucket is its own XLA program — four to ten seconds
-        apiece on a TPU v5e (PERF.md) — so a deployment that knows its
-        partition count warms them here, ahead of traffic."""
+        Device backend: the tick program at every bucket a fold can
+        land in, a power of two from 8 up to the capacity (a fold can
+        touch every row) or, where larger, up to a window of
+        `max_replies` replies, and the fused frame's minimum heartbeat
+        bucket. Each bucket is its own XLA program — four to ten
+        seconds apiece cold on a TPU v5e (PERF.md) — so a deployment
+        that knows its partition count warms them here, ahead of
+        traffic. The resident device state is seeded again on the
+        way."""
         empty = np.array([], np.int64)
         backend = self._backend()
         # declared-warmup region: compiles here are the point of the
@@ -1358,22 +1401,18 @@ class ShardGroupArrays:
                 self._mesh_full_frame(empty, empty, empty, empty, empty)
                 self.health_refresh()
                 return
+            self._resident = None
             self.device_tick(empty, empty, empty, empty, empty)
             if backend == "device":
                 self.frame_tick(
                     empty, empty, empty, empty, empty,
                     hb_rows=np.zeros(1, np.int64),
                 )
-                from ..ops.quorum import heartbeat_tick_jit
-
                 bucket = 8
-                while bucket < max_replies:
+                while True:
+                    # all padding: nothing is scattered or folded and
+                    # nothing read back is kept
+                    self._fold_on_device(_EMPTY_ROWS, (empty,) * 5, bucket)
+                    if bucket >= max(self._cap, max_replies):
+                        break
                     bucket *= 2
-                    # all padding: seq = i64 min, which the fold's
-                    # reply-reordering guard drops, so the result is
-                    # the state unchanged and is discarded
-                    rows = np.zeros(bucket, np.int64)
-                    pad = np.full(bucket, I64_MIN, np.int64)
-                    heartbeat_tick_jit(
-                        self.to_device_state(), rows, rows, pad, pad, pad
-                    )

@@ -21,10 +21,11 @@ import os
 import numpy as np
 import pytest
 
-from redpanda_tpu.models.consensus_state import SELF_SLOT
+from redpanda_tpu.models.consensus_state import SELF_SLOT, GroupState
 from redpanda_tpu.raft import quorum_scalar as qs
 from redpanda_tpu.raft.shard_state import NO_OFFSET, ShardGroupArrays
 from redpanda_tpu.raft.tick_frame import TickFrame
+from test_devplane import _run_armed
 
 
 def run(coro):
@@ -196,6 +197,327 @@ class TestDifferential:
         assert results["host"][2] == results["device"][2]
         for k in results["host"][3]:
             assert results["host"][3][k] == results["device"][3][k], k
+
+
+_EMPTY = np.empty(0, np.int64)
+
+
+class _Trio:
+    """Three ShardGroupArrays driven through one history: `host` folds
+    with host_tick, `dev` with the device backend and its resident
+    state, `fresh` with the device backend and the resident state
+    dropped before every fold (a whole upload each time: what the
+    device backend did before it kept a state)."""
+
+    def __init__(self, monkeypatch, capacity):
+        self.mp = monkeypatch
+        self.names = ("host", "dev", "fresh")
+        self.arrays = {}
+        for name in self.names:
+            self._backend(name)
+            self.arrays[name] = ShardGroupArrays(capacity=capacity)
+
+    def _backend(self, name):
+        self.mp.setenv("RP_QUORUM_BACKEND", "host" if name == "host" else "device")
+
+    def each(self, fn):
+        """Apply one host-side write to all three (under each one's
+        own backend: alloc_row can grow, and _grow prewarms)."""
+        out = None
+        for name in self.names:
+            self._backend(name)
+            out = fn(self.arrays[name])
+        return out
+
+    def fold(self, window, force):
+        adv = {}
+        for name in self.names:
+            self._backend(name)
+            a = self.arrays[name]
+            if name == "host":
+                adv[name] = a.host_tick(*window, force_rows=force)
+            else:
+                if name == "fresh":
+                    a._resident = None
+                adv[name] = a.device_tick(*window, force_rows=force)
+        return {k: np.sort(np.asarray(v)) for k, v in adv.items()}
+
+    def assert_same(self, adv, against, step):
+        a, b = self.arrays["dev"], self.arrays[against]
+        np.testing.assert_array_equal(
+            adv["dev"], adv[against], err_msg=f"advanced rows, step {step}"
+        )
+        for lane in GroupState._fields:
+            np.testing.assert_array_equal(
+                getattr(a, lane), getattr(b, lane),
+                err_msg=f"{lane} against {against}, step {step}",
+            )
+
+
+def _random_group(a, row, rng):
+    """Make `row` a group with SELF and a random set of other voters,
+    sometimes in a joint configuration."""
+    r = a.replica_slots
+    voters = rng.random(r) < 0.6
+    voters[SELF_SLOT] = True
+    a.is_voter[row] = voters
+    a.is_voter_old[row] = (rng.random(r) < 0.5) & (rng.random() < 0.3)
+    a.voter_epoch += 1
+    a.touch()
+
+
+def _host_write(trio, row, rng):
+    """One of the writes the broker makes to a row between folds, the
+    same bytes on all three. None marks the row quorum_dirty: the
+    caller passes it as a forced row at a later fold, as the tick
+    frame does for a row whose lanes the broker wrote itself."""
+    kind = int(rng.integers(0, 7))
+    draw = rng.integers(0, 1 << 30)
+
+    def write(a):
+        g = np.random.default_rng(draw)
+        if kind == 0:  # term change
+            a.term[row] += 1
+            a.term_start[row] = int(a.match_index[row, SELF_SLOT]) + 1
+        elif kind == 1:  # become or lose leader
+            a.is_leader[row] = not a.is_leader[row]
+        elif kind == 2:  # voter or joint-configuration change
+            _random_group(a, row, g)
+        elif kind == 3:  # reset_row, then a group again
+            a.reset_row(row)
+            a.row_active[row] = True
+            _random_group(a, row, g)
+            a.is_leader[row] = True
+        elif kind == 4:  # free and re-allocate (the free list hands it back)
+            a.free_row(row)
+            assert a.alloc_row() == row
+            _random_group(a, row, g)
+            a.is_leader[row] = bool(g.random() < 0.8)
+        else:  # SELF-slot append, then flush
+            a.match_index[row, SELF_SLOT] += int(g.integers(1, 20))
+            if kind == 6:
+                a.flushed_index[row, SELF_SLOT] = a.match_index[row, SELF_SLOT]
+        a.quorum_dirty[row] = False
+        a.touch()
+
+    trio.each(write)
+
+
+class TestResidentState:
+    """The device backend keeps its GroupState on the device and
+    exchanges only the rows a fold touches (ISSUE 26). Whatever the
+    host wrote meanwhile to rows a fold does not touch, every fold has
+    to leave the mirrors and the advanced rows of a whole upload."""
+
+    @pytest.mark.parametrize("seed", [3, 17, 2026])
+    @pytest.mark.parametrize("by_the_rules", [True, False],
+                             ids=["host_and_fresh", "fresh_wild"])
+    def test_folds_between_host_writes(self, monkeypatch, seed, by_the_rules):
+        """A seeded history of folds and host-side writes, with a
+        capacity doubling in the middle. `host_and_fresh`: a written
+        row gets no reply before a fold is forced to recompute it (the
+        broker's own rule, and what makes host_tick's incremental
+        sweep comparable), and all three agree after every fold.
+        `fresh_wild`: replies land on written rows at once; the
+        resident state still agrees with a whole upload."""
+        rng = np.random.default_rng(seed)
+        cap, steps = 16, 36
+        trio = _Trio(monkeypatch, cap)
+        rows = [trio.each(lambda a: a.alloc_row()) for _ in range(cap)]
+        for row in rows:
+            draw = rng.integers(0, 1 << 30)
+
+            def setup(a, row=row, draw=draw):
+                _fill_random(a, np.array([row]), np.random.default_rng(draw))
+                a.last_seq[row] = 0
+
+            trio.each(setup)
+        trio.fold((_EMPTY,) * 5, None)  # the dirty rows, and the seed
+        waiting: list[int] = []  # written, not yet forced
+        for step in range(steps):
+            if step == steps // 2:
+                # 17th row: every lane doubles, the resident state goes
+                rows.append(trio.each(lambda a: a.alloc_row()))
+                assert trio.arrays["dev"].capacity == 2 * cap
+                assert trio.arrays["dev"]._resident is not None  # _grow's prewarm
+                draw = rng.integers(0, 1 << 30)
+
+                def fill_new(a, draw=draw):
+                    _fill_random(a, np.array(rows[-1:]), np.random.default_rng(draw))
+                    a.quorum_dirty[rows[-1]] = False
+
+                trio.each(fill_new)
+                waiting.append(rows[-1])
+            kind = step % 6
+            n = 0 if kind == 0 else int(rng.integers(1, 40))
+            pool = [r for r in rows if not (by_the_rules and r in waiting)]
+            g_rows = rng.choice(pool, n).astype(np.int64)
+            g_slots = rng.integers(1, 8, n).astype(np.int64)
+            if n > 3:  # duplicate reply pairs in one window
+                g_rows[-2:] = g_rows[:2]
+                g_slots[-2:] = g_slots[:2]
+            last = trio.arrays["dev"].last_seq[g_rows, g_slots]
+            g_seqs = last + rng.integers(-1, 3, n)  # stale ones among them
+            g_dirty = rng.integers(-1, 1500, n).astype(np.int64)
+            g_flushed = np.maximum(g_dirty - rng.integers(0, 40, n), -1)
+            force = None
+            if kind in (0, 3) and waiting:
+                k = int(rng.integers(1, len(waiting) + 1))
+                force, waiting = np.array(waiting[:k], np.int64), waiting[k:]
+            if kind == 5:  # nothing at all: an all-padding program
+                g_rows = g_slots = g_dirty = g_flushed = g_seqs = _EMPTY
+            touched = set(g_rows.tolist()) | set(
+                [] if force is None else force.tolist())
+            for row in rng.choice(rows, int(rng.integers(0, 5)), replace=False):
+                if int(row) not in touched:
+                    _host_write(trio, int(row), rng)
+                    if int(row) not in waiting:
+                        waiting.append(int(row))
+            adv = trio.fold((g_rows, g_slots, g_dirty, g_flushed, g_seqs), force)
+            trio.assert_same(adv, "fresh", step)
+            for lane in ("_folded_self_m", "_folded_self_f", "quorum_dirty"):
+                np.testing.assert_array_equal(
+                    getattr(trio.arrays["dev"], lane),
+                    getattr(trio.arrays["fresh"], lane), err_msg=lane)
+            if by_the_rules:
+                trio.assert_same(adv, "host", step)
+
+
+_ARMED_PRELUDE = """\
+import numpy as np
+from redpanda_tpu.observability import devplane
+from redpanda_tpu.raft.shard_state import ShardGroupArrays
+from redpanda_tpu.utils import compileguard
+
+assert devplane.enabled()
+moved = []  # every (direction, bytes) the program counts
+count = devplane.count_transfer
+devplane.count_transfer = lambda n, d: (moved.append((d, n)), count(n, d))[1]
+EMPTY = np.empty(0, np.int64)
+
+
+def group(a, n):
+    rows = np.array([a.alloc_row() for _ in range(n)], np.int64)
+    a.is_leader[rows] = True
+    a.is_voter[rows, :3] = True
+    a.voter_epoch += 1
+    return rows
+
+
+def replies(rows, seq, slots=(1, 2)):
+    r = np.repeat(rows, len(slots))
+    s = np.tile(np.array(slots, np.int64), len(rows))
+    off = np.full(len(r), seq, np.int64)
+    return r, s, off, off, off
+"""
+
+_FOLD_COST = """\
+per_fold = {}
+for cap in (64, 2048):
+    a = ShardGroupArrays(capacity=cap)
+    rows = group(a, 40)
+    a.device_tick(EMPTY, EMPTY, EMPTY, EMPTY, EMPTY)  # 40 dirty rows, the seed
+    devplane.reset()
+    del moved[:]
+    shapes = []
+    for seq, n in enumerate((1, 3, 4, 5, 20, 40), start=1):
+        a.match_index[rows[:n], 0] = seq
+        a.flushed_index[rows[:n], 0] = seq
+        adv = a.device_tick(*replies(rows[:n], seq))
+        assert sorted(adv) == list(rows[:n]), (cap, n, adv)
+        # one upload and one readback a fold, whatever the capacity
+        assert [d for d, _ in moved] == ["h2d", "d2h"], moved
+        shapes.append(tuple(b for _, b in moved))
+        del moved[:]
+    per_fold[cap] = shapes
+    st = devplane.status()
+    assert st["state_seeds"] == 0, st["state_seeds"]
+    assert st["host"]["tick.upload"]["count"] == 6, st["host"]
+    assert st["host"]["tick.readback"]["count"] == 6, st["host"]
+# the bytes follow the bucket and the slots, not the lanes' capacity
+assert per_fold[64] == per_fold[2048], per_fold
+slots = a.replica_slots
+for n, (up, down) in zip((1, 3, 4, 5, 20, 40), per_fold[64]):
+    bucket = max(8, 1 << (2 * n - 1).bit_length())
+    assert up == bucket * (11 + 5 * slots) * 8, (n, up)
+    assert down == bucket * (2 + 3 * slots) * 8, (n, down)
+print("FOLD-COST-OK")
+"""
+
+_SEED_COUNT = """\
+a = ShardGroupArrays(capacity=16)
+rows = group(a, 16)
+devplane.reset()
+for seq in range(1, 41):
+    pick = rows[seq % 5 :: 5]
+    a.match_index[pick, 0] = seq
+    a.flushed_index[pick, 0] = seq
+    a.term[rows[(seq + 1) % 5 :: 5]] += 1  # host writes beside the fold
+    adv = a.device_tick(*replies(pick, seq))
+    assert sorted(adv) == list(pick), (seq, adv)
+assert devplane.status()["state_seeds"] == 1
+seeds = [s[7]["seed"] for s in devplane.status()["spans"] if s[0] == "tick.upload"]
+assert seeds == [1] + [0] * 39, seeds
+extra = a.alloc_row()  # 17th row: _grow, and its prewarm seeds again
+assert a.capacity == 32
+assert devplane.status()["state_seeds"] == 2
+a.match_index[rows, 0] = 50
+a.flushed_index[rows, 0] = 50
+adv = a.device_tick(*replies(rows, 50))
+assert sorted(adv) == list(rows), adv
+assert devplane.status()["state_seeds"] == 2
+print("SEED-COUNT-OK")
+"""
+
+_PREWARM = """\
+# (capacity, max_replies, programs): a program a power of two from 8
+# up to the larger of the capacity and the largest window's bucket
+for cap, most, programs in ((32, 100, 5), (64, 10, 4)):
+    a = ShardGroupArrays(capacity=cap)
+    rows = group(a, cap)
+    compileguard.reset()
+    devplane.reset()
+    a.prewarm(max_replies=most)
+    warmed = devplane.status()["compiles"]["quorum.heartbeat_tick"]
+    assert warmed["warmup"] >= programs and warmed["steady"] == 0, warmed
+    compileguard.steady()
+    devplane.reset()
+    folds = [(0, 0), (1, 0), (8, 0), (9, 3), (most // 3, 0), (most // 2, cap),
+             (most, 0), (0, cap), (2, cap // 2 + 1)]
+    for seq, (n_replies, n_dirty) in enumerate(folds, start=1):
+        r = np.resize(rows, n_replies)
+        s = np.resize(np.arange(1, 8, dtype=np.int64), n_replies)
+        off = np.full(n_replies, seq, np.int64)
+        a.quorum_dirty[rows[:n_dirty]] = True
+        a.device_tick(r, s, off, off, off, force_rows=rows[:1])
+    assert compileguard.reports() == [], compileguard.reports()
+    st = devplane.status()
+    assert st["compiles"] == {}, st["compiles"]  # none of either phase
+    assert st["state_seeds"] == 0
+print("PREWARM-OK")
+"""
+
+
+class TestResidentCost:
+    """What a fold moves, counted by the armed devplane (an
+    import-time latch, so each case is a process of its own)."""
+
+    @pytest.mark.parametrize(
+        "body, token, sample",
+        [(_FOLD_COST, "FOLD-COST-OK", "16"),
+         (_SEED_COUNT, "SEED-COUNT-OK", "1"),
+         (_PREWARM, "PREWARM-OK", "16")],
+        ids=["bytes_follow_the_bucket", "one_seed_then_one_a_grow",
+             "prewarm_covers_every_bucket"],
+    )
+    def test_armed(self, tmp_path, body, token, sample):
+        out = _run_armed(
+            tmp_path, _ARMED_PRELUDE + body,
+            {"RP_DEVPLANE_SAMPLE": sample, "RP_COMPILEGUARD": "1",
+             "RP_QUORUM_BACKEND": "device"},
+        )
+        assert out.returncode == 0, out.stderr[-4000:]
+        assert token in out.stdout
 
 
 class TestTickFrame:
