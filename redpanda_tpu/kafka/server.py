@@ -158,6 +158,7 @@ class ConnectionContext:
         "internal",
         "fetch_session_ids",
         "client_ids",
+        "produce_open",
     )
 
     def __init__(self) -> None:
@@ -171,6 +172,8 @@ class ConnectionContext:
         # connection under a churn storm must not leak either
         self.fetch_session_ids: set[int] = set()
         self.client_ids: set[str] = set()
+        # this connection's share of KafkaServer._produce_open
+        self.produce_open = 0
         # monotonic deadline after which the SASL session is no longer
         # valid (OAUTHBEARER: derived from the token's exp at auth
         # time; None = unbounded). Monotonic, not wall: the expiry
@@ -290,6 +293,10 @@ class KafkaServer:
             lambda: self._inflight,
             "Responses decoded but not yet written, all connections",
         )
+        # produce requests arrived and not yet answered, all connections:
+        # the `open` tag of a `kafka.produce` span is its value when the
+        # request arrived. Kept only while tracing is on.
+        self._produce_open = 0
         self._inflight_stalls = broker.metrics.counter(
             "kafka_inflight_stalls_total",
             "Reader stalls on a full per-connection inflight window",
@@ -609,6 +616,8 @@ class KafkaServer:
                 # reconcile the fleet inflight gauge for responses the
                 # writer never settled
                 self._inflight -= inflight
+                self._produce_open -= ctx.produce_open
+                ctx.produce_open = 0
                 # release per-connection protocol state: an aborted
                 # connection must not leak its fetch sessions or its
                 # quota-bucket references through a churn storm
@@ -746,6 +755,9 @@ class KafkaServer:
                 )
                 root.begin(int(t_req * 1e9)).__enter__()
                 if hdr.api_key == 0:
+                    root.tag(open=self._produce_open)
+                    self._produce_open += 1
+                    ctx.produce_open += 1
                     trace.record(
                         "produce.decode", "run", root.start_ns,
                         int(t_decoded * 1e9),
@@ -762,7 +774,7 @@ class KafkaServer:
                 if root is not None:
                     # error path never reaches the write loop, so the
                     # span can't close at write time — stamp it here
-                    root.finish()
+                    self._finish_root(root, ctx)
                 logger.exception(
                     "%s v%d handler failed", api.name, hdr.api_version
                 )
@@ -789,7 +801,7 @@ class KafkaServer:
             ):
                 done_obs(time.monotonic() - t_req)
                 if root is not None:
-                    root.finish()
+                    self._finish_root(root, ctx)
 
         if asyncio.iscoroutine(resp):
             # staged handler (produce): dispatch done, response later —
@@ -813,6 +825,8 @@ class KafkaServer:
                 return _TrackedResponse(finish(), on_written)
             return finish()
         if resp is None:  # acks=0 produce: no response on the wire
+            if root is not None:
+                self._finish_root(root, ctx)
             return None
         head = encode_response_header(
             hdr.api_key, hdr.api_version, hdr.correlation_id
@@ -821,6 +835,14 @@ class KafkaServer:
         if on_written is not None:
             return _TrackedResponse(out, on_written)
         return out
+
+    def _finish_root(self, root, ctx: ConnectionContext) -> None:
+        """End a request's root span; a produce request leaves the
+        open count with it (once: the span ends once)."""
+        if root.dur_ns < 0 and root.name == "kafka.produce":
+            self._produce_open -= 1
+            ctx.produce_open -= 1
+        root.finish()
 
     @staticmethod
     def _encode_response(api, msg, version: int) -> bytes:

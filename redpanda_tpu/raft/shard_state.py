@@ -1203,7 +1203,8 @@ class ShardGroupArrays:
         up = jnp.asarray(packed)
         devplane.count_transfer(packed.nbytes, "h2d")
         trace.record(
-            "tick.upload", "run", t_up, time.monotonic_ns(), seed=int(seed)
+            "tick.upload", "run", t_up, time.monotonic_ns(),
+            seed=int(seed), rows=t, replies=m, bucket=bucket,
         )
         self._resident, back = heartbeat_tick_jit(state, up)
         t_back = time.monotonic_ns()
