@@ -229,10 +229,11 @@ class ReplicateBatcher:
             int(c.arrays.flushed_index[row, SELF_SLOT]), flushed
         )
         c.arrays.touch()
-        # SELF-slot movement (the flush-clamp release): with a shard
-        # tick frame wired the quorum recompute batches into the next
-        # frame flush (one vectorized call for every group's round);
-        # direct fixtures keep the per-round scalar oracle
+        # SELF-slot movement: with a shard tick frame wired the frame
+        # decides from the row's lanes whether this move alone can
+        # advance anything (a lone voter, the flush-clamp release) and
+        # folds at once, or lets it ride the fold the first follower's
+        # reply brings; direct fixtures keep the per-round scalar oracle
         frame = c._tick_frame
         if frame is not None:
             frame.note_self(row)

@@ -472,6 +472,42 @@ class ShardGroupArrays:
         )
         return bool(advanced)
 
+    def self_move_can_advance(self, row: int) -> bool:
+        """Could a fold, run now for a SELF-slot move alone, change
+        `commit_index` or `last_visible` of `row`? Reads the row's
+        mirrors as they stand, any SELF value: False only where the
+        scalar rule (quorum_scalar.leader_commit_index,
+        leader_majority_dirty) provably changes nothing.
+
+        With one voter set of n voters the quorum value is held by at
+        least m = n // 2 + 1 of them, SELF among them at most once, so
+        the commit can pass `commit_index` only if m - 1 OTHER voters
+        already hold a committed match (min of match and flushed) above
+        it, and the majority dirty offset can pass `last_visible` only
+        if m - 1 others hold a match above that. `last_visible` also
+        follows the commit up, so a row whose visible offset is behind
+        its commit is not reasoned about either. Everything else the
+        rule reads (the leader's flush clamp, `term_start`) can only
+        hold an advance back, never make one, and is left out: True
+        errs to a fold. A joint configuration and a row that does not
+        lead get True, the answer before this predicate existed."""
+        if not self.is_leader[row] or self.is_voter_old[row].any():
+            return True
+        commit = self.commit_index[row]
+        visible = self.last_visible[row]
+        if visible < commit:
+            return True
+        voters = self.is_voter[row]
+        others = voters.copy()
+        others[SELF_SLOT] = False
+        need = int(np.count_nonzero(voters)) // 2  # m - 1
+        match = self.match_index[row]
+        committed = np.minimum(match, self.flushed_index[row])
+        return bool(
+            np.count_nonzero(others & (committed > commit)) >= need
+            or np.count_nonzero(others & (match > visible)) >= need
+        )
+
     # -- batched device sweep ----------------------------------------
     def to_device_state(self) -> GroupState:
         import jax.numpy as jnp
@@ -921,16 +957,20 @@ class ShardGroupArrays:
             into the lanes by the caller (the tick frame's pending-reply
             enqueue path pre-applies cell updates inline for the
             catch-up fiber's progress checks, so the movement detection
-            above cannot see them — the frame passes those rows here).
+            above cannot see them — the frame passes those rows here,
+            and the rows of the leader's own flushes with them).
 
         Soundness: every OTHER mutation path (per-replicate replies,
         catch-up, become-leader) calls scalar_commit_update itself or
         enqueues into the tick frame (which forces its rows through
         here), so a row skipped has had no quorum-input change since
-        the value this sweep last used. Steady-state ticks — the
-        common case at 50k groups — touch no rows and cost O(replies)
-        gathers only, which is what makes a 50k-group live tick fit
-        inside one 50 ms heartbeat interval on a single host core.
+        the value this sweep last used, but for a SELF-slot move that
+        self_move_can_advance proved changes nothing (it stays in the
+        frame's force set and rides the next fold). Steady-state
+        ticks — the common case at 50k groups — touch no rows and cost
+        O(replies) gathers only, which is what makes a 50k-group live
+        tick fit inside one 50 ms heartbeat interval on a single host
+        core.
         """
         from ..models.consensus_state import SELF_SLOT
 
@@ -1057,9 +1097,11 @@ class ShardGroupArrays:
         call. The HOST fold is the default at every size (see
         _backend); RP_QUORUM_BACKEND=device routes to the compiled
         device program. Returns rows whose
-        commit advanced. `force_rows` (tick-frame pending rows whose
-        lanes were pre-applied by the caller) always recompute — see
-        host_tick.
+        commit advanced. `force_rows` always recompute (see
+        host_tick): the tick frame's rows of replies whose cells the
+        caller pre-applied, and its rows whose SELF slot the leader's
+        own flush moved, those that asked for this fold and those
+        that only ride it (TickFrame.note_self).
 
         The device fold keeps a GroupState resident on the device and
         moves only `touched` (the reply rows, the quorum_dirty rows,

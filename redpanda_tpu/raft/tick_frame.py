@@ -4,14 +4,18 @@ The reference handles every append reply with per-group scalar work
 (consensus.cc:274 update_follower_index → maybe_update_leader_commit
 _idx); our per-reply analog was `scalar_commit_update` — a Python
 quorum fold per reply, the dominant interpreter cost of the live
-produce path at high partition counts (BENCH_r05). The tick frame
-turns that per-reply math into an O(1) enqueue: reply ingestion sites
-(consensus.process_append_reply, replicate_batcher._flush_round) push
-into pending-reply COLUMNS here, and one loop-soon flush folds the
-whole window through `ShardGroupArrays.frame_tick` — a single
-vectorized call covering fold + quorum-commit advance (+ heartbeat
-payload gather on the device backend) — then fires the registered
-commit-advance callbacks for the rows that moved.
+produce path at high partition counts. The tick frame turns that
+per-reply math into an O(1) enqueue: the reply ingestion site
+(consensus.process_append_reply) pushes into pending-reply COLUMNS
+here, and one loop-soon flush folds the whole window through
+`ShardGroupArrays.frame_tick` — a single vectorized call covering
+fold + quorum-commit advance (+ heartbeat payload gather on the
+device backend) — then fires the registered commit-advance callbacks
+for the rows that moved. The leader's own flush
+(replicate_batcher._flush_round → `note_self`) schedules a fold only
+where the row's lanes say the fold could advance something; a
+replicated round is folded once, when a follower's reply makes a
+majority.
 
 Division of labor (the documented punt): per-reply CELL bookkeeping
 (match/flushed/last_seq writes behind the seq guard) stays inline at
@@ -74,6 +78,8 @@ class TickFrame:
         self.flushes = 0
         self.replies_folded = 0
         self.max_batch = 0
+        # SELF-slot moves that scheduled no fold of their own
+        self.self_deferred = 0
 
     # -- registration (control plane) ---------------------------------
     def register(self, row: int, on_advance, group_id: int | None = None) -> None:
@@ -134,11 +140,33 @@ class TickFrame:
             self._schedule()
 
     def note_self(self, row: int) -> None:
-        """Local append/fsync moved the SELF slot (the flush-clamp
-        release); recompute the row's quorum at the next flush."""
+        """Local append/fsync moved the SELF slot. Fold at once only
+        if the fold could change the row, by the lanes as the mirrors
+        hold them now (`self_move_can_advance`): the flush-clamp
+        release, a lone voter, a reply applied inline whose fold is
+        pending. Otherwise (a replicated round whose followers have not
+        answered yet) the move rides the next fold that touches the
+        row, which reads every lane of it fresh from the mirrors.
+
+        Nothing is lost by waiting, and the code relies on that
+        argument: commit and visible are pure functions of the row's
+        lanes, the predicate says False only where the SELF move
+        leaves both as they are, and every other writer that can
+        change them causes a fold itself (`enqueue_reply` forces the
+        row, configuration and term changes set `quorum_dirty`, the
+        heartbeat fold compares the SELF lanes it last folded). The
+        row stays in the force set all the same, so that the frame's
+        next fold for any reason, at the latest the next heartbeat's
+        (it drains a frame with anything pending), recomputes it: a
+        second line that costs nothing, not what correctness rests
+        on."""
         self._force.add(int(row))
-        if not self._scheduled:
+        if self._scheduled:
+            return
+        if self.arrays.self_move_can_advance(row):
             self._schedule()
+        else:
+            self.self_deferred += 1
 
     # -- the frame ----------------------------------------------------
     def flush(self) -> np.ndarray:

@@ -414,6 +414,11 @@ class PartitionShard:
             "largest reply window one tick-frame fold covered",
         )
         self.metrics.gauge(
+            "shard_tick_frame_self_deferred_total",
+            lambda: tf.self_deferred,
+            "leader flushes that rode a later tick-frame fold",
+        )
+        self.metrics.gauge(
             "shard_tick_frame_pending",
             lambda: tf.pending,
             "replies + forced rows awaiting the next tick-frame flush",

@@ -216,3 +216,54 @@ def test_produce_pipelining_overlaps_rounds(tmp_path):
             await b.stop()
 
     run(main())
+
+
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_acks_all_round_folds_once_at_rf3(tmp_path, monkeypatch, backend):
+    """One fold a replicated batch (ISSUE 28): the leader's own flush
+    finds both followers at the commit and schedules nothing, and the
+    first reply's fold commits the round. On the device backend that
+    is one `tick.upload` record, of one row."""
+    from redpanda_tpu.observability import trace
+
+    monkeypatch.setenv("RP_QUORUM_BACKEND", backend)
+    monkeypatch.setattr(trace, "ENABLED", True)
+    monkeypatch.setattr(trace.WINDOW, "keep_raw", True)
+
+    async def main():
+        cluster = RaftCluster(tmp_path, n_nodes=3)
+        # a heartbeat that finds the round half way folds it too, as
+        # it should: the leader's are stopped for the measured round,
+        # and the election timeout leaves it the time
+        await cluster.start(election_timeout=1.0, heartbeat=0.05)
+        await cluster.create_group()
+        leader = await cluster.wait_leader(timeout=15.0)
+        gm = cluster.nodes[leader.node_id]
+        arrays, row, frame = gm.arrays, leader.row, gm.tick_frame
+        _b, last = await leader.replicate(data_batch(b"warm"), acks=-1)
+        for _ in range(200):  # both followers caught up, nothing pending
+            if (arrays.flushed_index[row, :3] == last).all() and not frame.pending:
+                break
+            await asyncio.sleep(0.01)
+        assert (arrays.flushed_index[row, :3] == last).all()
+        assert arrays.commit_index[row] == last
+        await gm.heartbeat_manager.stop()
+        trace.WINDOW.reset()
+        flushes, deferred = frame.flushes, frame.self_deferred
+        _b, last = await leader.replicate(data_batch(b"once"), acks=-1)
+        assert leader.commit_index >= last
+        folds = [
+            s[7] for s in trace.WINDOW.status()["spans"] if s[0] == "tick.upload"
+        ]
+        assert frame.self_deferred - deferred == 1
+        assert frame.flushes - flushes == 1
+        if backend == "device":
+            assert [(f["rows"], f["seed"]) for f in folds] == [(1, 0)], folds
+        else:
+            assert folds == []
+        await cluster.stop()
+
+    try:
+        run(main())
+    finally:
+        trace.WINDOW.reset()

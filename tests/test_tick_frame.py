@@ -651,6 +651,250 @@ class TestTickFrame:
         assert frame.flush() is not None  # no-op, no raise
 
 
+def _random_rows(arrays, rng):
+    """Every row of `arrays` randomized around its own commit index,
+    in a range small enough that ties and the rule's edges are common:
+    voters and learners, SELF a voter or not, joint configurations,
+    followers ahead of and behind the leader's flush, never-acked
+    slots, `term_start` on both sides of the commit, and now and then
+    a visible offset behind it."""
+    g, r = arrays.capacity, arrays.replica_slots
+    rows = np.array([arrays.alloc_row() for _ in range(g)], np.int64)
+    commit = rng.integers(-1, 40, g).astype(np.int64)
+    match = commit[:, None] + rng.integers(-4, 6, (g, r))
+    match[:, SELF_SLOT] = commit + rng.integers(0, 8, g)
+    flushed = match - rng.integers(0, 4, (g, r))
+    never = rng.random((g, r)) < 0.1
+    match[never] = NO_OFFSET
+    flushed[never] = NO_OFFSET
+    arrays.match_index[rows] = np.maximum(match, NO_OFFSET)
+    arrays.flushed_index[rows] = np.maximum(flushed, NO_OFFSET)
+    voters = rng.random((g, r)) < 0.7
+    voters[:, SELF_SLOT] = rng.random(g) < 0.9
+    arrays.is_voter[rows] = voters
+    joint = rng.random(g) < 0.2
+    arrays.is_voter_old[rows] = joint[:, None] & (rng.random((g, r)) < 0.5)
+    arrays.is_leader[rows] = rng.random(g) < 0.9
+    arrays.commit_index[rows] = commit
+    arrays.term_start[rows] = commit + rng.integers(-3, 6, g)
+    behind = rng.random(g) < 0.05
+    arrays.last_visible[rows] = np.where(
+        behind, commit - 1, commit + rng.integers(0, 4, g)
+    )
+    arrays.voter_epoch += 1
+    return rows
+
+
+def _lead(arrays, voters, at=10):
+    """One leader row, its first `voters` slots voting, every lane and
+    the commit at `at`, nothing dirty: a group at rest."""
+    row = arrays.alloc_row()
+    arrays.is_leader[row] = True
+    arrays.is_voter[row, :voters] = True
+    arrays.match_index[row, :voters] = at
+    arrays.flushed_index[row, :voters] = at
+    arrays.commit_index[row] = at
+    arrays.last_visible[row] = at
+    arrays.voter_epoch += 1
+    arrays.quorum_dirty[:] = False
+    return row
+
+
+def _self_move(arrays, row, to):
+    arrays.match_index[row, SELF_SLOT] = to
+    arrays.flushed_index[row, SELF_SLOT] = to
+
+
+def _reply(arrays, frame, row, slot, to, seq):
+    """One append reply as consensus.process_append_reply ingests it:
+    the cells inline, the quorum math to the frame."""
+    arrays.last_seq[row, slot] = seq
+    arrays.match_index[row, slot] = to
+    arrays.flushed_index[row, slot] = to
+    frame.enqueue_reply(row, slot, to, to, seq)
+
+
+class TestSelfMovePredicate:
+    """`self_move_can_advance` against the oracle: False is allowed
+    only where the scalar rule changes nothing."""
+
+    @pytest.mark.parametrize("seed", range(8))
+    def test_false_means_the_scalar_rule_changes_nothing(self, seed):
+        rng = np.random.default_rng(2800 + seed)
+        said_no = moved_on_yes = 0
+        for slots in range(1, 9):
+            arrays = ShardGroupArrays(capacity=192, replica_slots=slots)
+            for row in _random_rows(arrays, rng):
+                lanes = (arrays.match_index, arrays.flushed_index)
+                at_rest = [lane[row].copy() for lane in lanes]
+                commit = int(arrays.commit_index[row])
+                visible = int(arrays.last_visible[row])
+                can = arrays.self_move_can_advance(row)
+                # the SELF slot as the round left it, then wherever
+                # else a flush could have put it: the answer reads the
+                # other slots alone, so it has to hold for all of them
+                m0, f0 = (int(lane[row, SELF_SLOT]) for lane in lanes)
+                for m, f in ((m0, f0), (m0 + 50, m0 + 50), (m0 + 50, f0),
+                             (NO_OFFSET, NO_OFFSET)):
+                    arrays.match_index[row, SELF_SLOT] = m
+                    arrays.flushed_index[row, SELF_SLOT] = f
+                    assert can == arrays.self_move_can_advance(row)
+                    arrays.scalar_commit_update(row)
+                    after = (int(arrays.commit_index[row]),
+                             int(arrays.last_visible[row]))
+                    if not can:
+                        assert after == (commit, visible), (
+                            f"seed {seed}, {slots} slots, row {row}: deferred, "
+                            f"and the rule moved {(commit, visible)} to {after}"
+                        )
+                    elif after != (commit, visible):
+                        moved_on_yes += 1
+                    arrays.commit_index[row] = commit
+                    arrays.last_visible[row] = visible
+                for lane, was in zip(lanes, at_rest):
+                    lane[row] = was
+                said_no += not can
+        # neither answer is the trivial one
+        assert said_no > 100 and moved_on_yes > 100, (said_no, moved_on_yes)
+
+    CASES = {
+        # name: (voting slots, what differs from a group at rest, answer)
+        "rf3_followers_at_the_commit": (3, {}, False),
+        "rf3_one_follower_ahead": (3, {"match": {1: 20}, "flushed": {1: 20}}, True),
+        "rf3_one_follower_ahead_unflushed": (3, {"match": {1: 20}}, True),
+        "rf5_one_follower_ahead": (5, {"match": {1: 20}, "flushed": {1: 20}}, False),
+        "rf5_two_followers_ahead": (
+            5, {"match": {1: 20, 3: 20}, "flushed": {1: 20, 3: 20}}, True),
+        "rf1": (1, {}, True),
+        "rf2_lone_follower_at_the_commit": (2, {}, False),
+        "rf3_learner_ahead": (3, {"match": {5: 20}, "flushed": {5: 20}}, False),
+        "rf3_joint": (3, {"old": {4: True}}, True),
+        "rf3_not_leader": (3, {"leader": False}, True),
+        "rf3_visible_behind_commit": (3, {"visible": 9}, True),
+        "no_voters": (0, {}, True),
+    }
+
+    @pytest.mark.parametrize("name", list(CASES))
+    def test_named_rows(self, name):
+        voters, change, answer = self.CASES[name]
+        arrays = ShardGroupArrays(capacity=8)
+        row = _lead(arrays, voters)
+        for slot, v in change.get("match", {}).items():
+            arrays.match_index[row, slot] = v
+        for slot, v in change.get("flushed", {}).items():
+            arrays.flushed_index[row, slot] = v
+        for slot, v in change.get("old", {}).items():
+            arrays.is_voter_old[row, slot] = v
+        arrays.is_leader[row] = change.get("leader", True)
+        arrays.last_visible[row] = change.get("visible", 10)
+        _self_move(arrays, row, 30)
+        assert arrays.self_move_can_advance(row) is answer
+
+
+@pytest.fixture(params=["host", "device"])
+def backend(request, monkeypatch):
+    monkeypatch.setenv("RP_QUORUM_BACKEND", request.param)
+    return request.param
+
+
+class TestSelfMoveFoldsOnce:
+    """The leader's own flush schedules a fold only where the fold
+    could advance the row (ISSUE 28), on the host fold and on the
+    device program alike."""
+
+    def test_rf3_self_move_rides_the_first_reply_s_fold(self, backend):
+        async def main():
+            arrays = ShardGroupArrays(capacity=8)
+            row = _lead(arrays, 3)
+            fired = []
+            frame = TickFrame(arrays)
+            frame.register(row, lambda: fired.append(int(arrays.commit_index[row])))
+            _self_move(arrays, row, 20)
+            frame.note_self(row)
+            for _ in range(3):
+                await asyncio.sleep(0)
+            assert (frame.flushes, frame.self_deferred) == (0, 1)
+            assert frame.pending == 1 and arrays.commit_index[row] == 10
+            _reply(arrays, frame, row, 1, 20, 1)
+            await asyncio.sleep(0)
+            assert (frame.flushes, frame.self_deferred) == (1, 1)
+            assert arrays.commit_index[row] == 20 and arrays.last_visible[row] == 20
+            assert fired == [20]
+            # the second follower's reply folds and moves nothing
+            _reply(arrays, frame, row, 2, 20, 1)
+            await asyncio.sleep(0)
+            assert fired == [20] and frame.pending == 0
+
+        run(main())
+
+    def test_flush_clamp_release_folds_at_once(self, backend):
+        """Both followers flushed the round before the leader's own
+        `fsync` returned: the leader's flush was what held the commit,
+        so its SELF move is the one that releases it."""
+        async def main():
+            arrays = ShardGroupArrays(capacity=8)
+            row = _lead(arrays, 3)
+            fired = []
+            frame = TickFrame(arrays)
+            frame.register(row, lambda: fired.append(int(arrays.commit_index[row])))
+            arrays.match_index[row, SELF_SLOT] = 20  # appended, not flushed
+            _reply(arrays, frame, row, 1, 20, 1)
+            _reply(arrays, frame, row, 2, 20, 1)
+            await asyncio.sleep(0)
+            assert frame.flushes == 1 and arrays.commit_index[row] == 10
+            assert arrays.last_visible[row] == 20
+            _self_move(arrays, row, 20)
+            frame.note_self(row)
+            await asyncio.sleep(0)
+            assert (frame.flushes, frame.self_deferred) == (2, 0)
+            assert arrays.commit_index[row] == 20 and fired == [20]
+
+        run(main())
+
+    def test_self_move_rides_a_fold_already_scheduled(self, backend):
+        async def main():
+            arrays = ShardGroupArrays(capacity=8)
+            row, other = _lead(arrays, 3), _lead(arrays, 3)
+            frame = TickFrame(arrays)
+            _self_move(arrays, other, 20)
+            _reply(arrays, frame, other, 1, 20, 1)  # schedules the fold
+            _self_move(arrays, row, 20)
+            frame.note_self(row)
+            assert frame.self_deferred == 0  # not asked: it rides
+            await asyncio.sleep(0)
+            assert frame.flushes == 1 and frame.pending == 0
+            assert arrays.commit_index[other] == 20
+            assert arrays.commit_index[row] == 10
+
+        run(main())
+
+    @pytest.mark.parametrize("beat", ["drain", "replies"])
+    def test_deferred_row_is_recomputed_by_the_next_heartbeat(self, backend, beat):
+        """The second line: a deferred row stays forced, so the
+        heartbeat's fold recomputes it from the mirrors even where no
+        reply ever named it. Made visible by a lane written behind the
+        frame's back, which no writer in the tree does."""
+        arrays = ShardGroupArrays(capacity=8)
+        row, other = _lead(arrays, 3), _lead(arrays, 3)
+        fired = []
+        frame = TickFrame(arrays)
+        frame.register(row, lambda: fired.append(row))
+        _self_move(arrays, row, 20)
+        frame.note_self(row)
+        assert (frame.flushes, frame.self_deferred, frame.pending) == (0, 1, 1)
+        arrays.match_index[row, 2] = 20
+        arrays.flushed_index[row, 2] = 20
+        if beat == "drain":
+            # heartbeat_manager.tick with no reply of its own to fold
+            assert frame.pending
+            frame.flush()
+        else:
+            one = np.array([1], np.int64)
+            frame.fold_now(np.array([other]), one, one * 10, one * 10, one)
+        assert frame.flushes == 1 and frame.pending == 0
+        assert arrays.commit_index[row] == 20 and fired == [row]
+
+
 class TestGrowPrewarm:
     def test_grow_does_not_leave_compile_for_next_tick(self, monkeypatch):
         """Satellite: after _grow on the device backend, the next
