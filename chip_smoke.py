@@ -95,7 +95,7 @@ DEVICE_ENV = {
 DEVPLANE_ENV = {"RP_DEVPLANE": "1", "RP_DEVPLANE_SAMPLE": "1"}
 
 RECORD_BYTES = 1024  # BASELINE.md: 1 KB records
-BATCH_RECORDS = 64   # 64 x 1 KB per produce batch (bench.py config #3)
+BATCH_RECORDS = 64   # 64 x 1 KB per produce batch (BASELINE.md config #3)
 LZ4_BATCH_RECORDS = 30  # body < 32 KiB: inside the fused kernel's bound
 KAFKA_BATCH_HEADER = 61  # bytes of a v2 record batch before its records
 

@@ -114,8 +114,8 @@ class BrokerConfig:
     # 3x acks=all throughput and 4x better p99 on this box.
     gc_governor: bool = True
     # SLO declaration the live burn-rate alerting evaluates
-    # (observability/alerts.py): a bench_profiles/slo_*.json profile
-    # name or a path to one; None follows RP_SLO_PROFILE (default
+    # (observability/alerts.py): an observability/slo/slo_*.json
+    # profile name or a path to one; None follows RP_SLO_PROFILE (default
     # "default")
     slo_profile: Optional[str] = None
     # PEM file overriding the license verification key (the built-in
@@ -261,7 +261,7 @@ class Broker:
         register_exporter(self.metrics, self.health_sampler)
         # flight-data plane (observability/flightdata|alerts|profiler):
         # metrics-history ring with windowed reducers, live burn-rate
-        # SLO evaluation of the bench_profiles/slo_*.json declarations,
+        # SLO evaluation of the observability/slo/slo_*.json declarations,
         # and the always-on wall-stack profiler the alert auto-capture
         # snapshots from. Each piece has its own stand-down env knob.
         from .observability import alerts as _alerts

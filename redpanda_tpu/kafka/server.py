@@ -238,8 +238,7 @@ class KafkaServer:
         )
         # cumulative produce payload bytes: the flight-data history
         # ring turns this into exact windowed ingest rates
-        # (/v1/metrics/history?family=kafka_produce_bytes_total), and
-        # bench.py cross-checks that rate against its own throughput
+        # (/v1/metrics/history?family=kafka_produce_bytes_total)
         self._produce_bytes = broker.metrics.counter(
             "kafka_produce_bytes_total",
             "record-batch bytes accepted by produce",

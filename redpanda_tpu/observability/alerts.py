@@ -1,10 +1,9 @@
 """Flight-data plane, part 2: burn-rate SLO alerting.
 
-The SLO declarations already exist — `bench_profiles/slo_*.json` grade
-`bench.py --slo` runs offline. This module loads the *same* files at
-broker startup and judges them live against the metrics-history ring
-(`flightdata.MetricsHistory`), using the SRE multi-window burn-rate
-pattern: a rule fires only when BOTH a fast window (default 1 min —
+The SLO declarations are the `slo/slo_*.json` files beside this
+module. It loads them at broker startup and judges them live against
+the metrics-history ring (`flightdata.MetricsHistory`), using the SRE
+multi-window burn-rate pattern: a rule fires only when BOTH a fast window (default 1 min —
 catches the burn quickly) and a slow window (default 10 min — rejects
 blips) breach, and clears as soon as the fast window recovers. Burn
 rate is observed/threshold, so 1.0 is exactly "burning the budget".
@@ -50,18 +49,16 @@ DEFAULT_FAST_S = _env_float("RP_ALERT_FAST_S", 60.0)
 DEFAULT_SLOW_S = _env_float("RP_ALERT_SLOW_S", 600.0)
 DEFAULT_PROFILE = os.environ.get("RP_SLO_PROFILE", "default")
 
-_PROFILE_DIR = os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "..", "..", "bench_profiles"
-)
+_PROFILE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "slo")
 
-# mirror of bench_profiles/slo_default.json's "slo" block, used when
-# the profile files are not shipped next to the package
+# mirror of slo/slo_default.json's "slo" block, used when the profile
+# files are not shipped with the package
 _BUILTIN_SLO = {"p99_ms": 40.0, "p999_ms": 160.0, "max_lag": 1024}
 
 
 def load_slo_profile(name: Optional[str] = None) -> dict:
-    """The declaration `bench.py --slo` grades against, reused live.
-    `name` is a profile name (default/single/tiered) or a path to a
+    """The SLO declaration the live rules are built from. `name` is a
+    profile name (default/single/tiered) or a path to a
     json file; a missing file degrades to the built-in default block
     rather than refusing to boot the broker."""
     name = name or DEFAULT_PROFILE

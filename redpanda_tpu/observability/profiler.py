@@ -1,8 +1,7 @@
 """Flight-data plane, part 3: live continuous profiler.
 
-Promotes the offline samplers (`bench_profiles/sampler.py`,
-`loop_attrib.py`) into an always-on wall-stack profiler the broker can
-answer from at any moment — "why is it slow *right now*" without
+An always-on wall-stack profiler the broker can answer from at any
+moment — "why is it slow *right now*" without
 restarting under a profiler:
 
   * a daemon sampler thread walks `sys._current_frames()` at a bounded
@@ -11,8 +10,8 @@ restarting under a profiler:
     including a loop blocked in a syscall mid-callback, which the
     suspended-task sampler at /v1/debug/cpu_profiler is blind to;
   * the event-loop thread's sample is prefixed with the asyncio task
-    currently running on that loop (the `loop_attrib.py` attribution,
-    read from `asyncio.tasks._current_tasks` without patching
+    currently running on that loop (read from
+    `asyncio.tasks._current_tasks` without patching
     `Handle._run`), so stacks group by owning fiber;
   * samples land in per-second buckets kept for a rolling window
     (default 120 s): `GET /v1/debug/profile?seconds=N` answers from

@@ -5,8 +5,6 @@
 """
 
 from .quorum import (
-    build_heartbeats,
-    build_heartbeats_jit,
     fold_replies,
     fold_replies_jit,
     follower_commit_step,
@@ -21,8 +19,6 @@ from .quorum import (
 from .crc32c import crc32c_batch_device, crc32c_device
 
 __all__ = [
-    "build_heartbeats",
-    "build_heartbeats_jit",
     "fold_replies",
     "fold_replies_jit",
     "follower_commit_step",

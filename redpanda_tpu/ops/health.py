@@ -19,9 +19,7 @@ whole fleet's health rolls up in a single XLA dispatch:
   a leader (metadata-cache `leader_of() is None` analog, but from the
   live raft lanes instead of the controller snapshot).
 
-`tick_frame_health` fuses this onto `ops.quorum.tick_frame` so the
-live replication plane pays ~zero extra dispatches for health; the
-scalar oracle for differential testing is `raft.health_scalar`.
+The scalar oracle for differential testing is `raft.health_scalar`.
 """
 
 from __future__ import annotations
@@ -30,10 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.consensus_state import SELF_SLOT, GroupState
+from ..models.consensus_state import SELF_SLOT
 from ..observability import devplane
 from ..utils import compileguard
-from . import quorum as q
 
 
 def health_reduce(
@@ -87,42 +84,7 @@ def health_reduce_np(
     }
 
 
-def tick_frame_health(
-    state: GroupState,
-    group_idx: jax.Array,
-    replica_slot: jax.Array,
-    last_dirty: jax.Array,
-    last_flushed: jax.Array,
-    seq: jax.Array,
-    hb_idx: jax.Array,
-    leader_known: jax.Array,  # [G] bool
-    active: jax.Array,        # [G] bool
-) -> tuple[GroupState, dict[str, jax.Array], dict[str, jax.Array]]:
-    """`ops.quorum.tick_frame` + health reduction over the POST-advance
-    state, fused into one compiled program: the live replication frame
-    pays zero extra dispatches for fleet health."""
-    state, hb = q.tick_frame(
-        state, group_idx, replica_slot, last_dirty, last_flushed, seq, hb_idx
-    )
-    health = health_reduce(
-        state.match_index,
-        state.commit_index,
-        state.is_voter,
-        state.is_voter_old,
-        state.is_leader,
-        leader_known,
-        active,
-    )
-    return state, hb, health
-
-
 health_reduce_jit = devplane.instrument(
     compileguard.instrument(jax.jit(health_reduce), "health.reduce"),
     "health.reduce",
-)
-tick_frame_health_jit = devplane.instrument(
-    compileguard.instrument(
-        jax.jit(tick_frame_health, donate_argnums=0), "health.tick_frame"
-    ),
-    "health.tick_frame",
 )

@@ -30,7 +30,7 @@ vector/matrix work over [N]-shaped tensors:
 
 The resulting blocks trade ratio for parallelism (matches cannot cross
 cell boundaries) but are bit-valid LZ4; ratio on redpanda-like payloads
-is within ~10-25% of liblz4's greedy parse (see bench.py compress).
+is within ~10-25% of liblz4's greedy parse.
 
 Spec constraints honored: last sequence is literals-only, no match
 starts within the final 12 bytes, offsets ≤ 65535 (chunks ≤ 64 KiB).

@@ -24,10 +24,6 @@ groups on a shard, replacing the reference's per-group scalar loops:
   monotone-seq reordering guard (types.h:107-117), replacing the
   per-reply scalar path (consensus.cc:274 update_follower_index).
 
-* `build_heartbeats` — gather per-target-node (group, term,
-  commit_index, last_dirty) vectors from state, replacing the
-  per-group iteration in heartbeat_manager.cc:203.
-
 All kernels are pure jnp on `[G]`/`[G, R]` int64/bool tensors — XLA
 fuses the sort + arithmetic into a handful of HBM passes; no Python
 per-group work anywhere.
@@ -195,21 +191,6 @@ def fold_replies(
     )
 
 
-def build_heartbeats(state: GroupState, group_idx: jax.Array) -> dict[str, jax.Array]:
-    """Gather heartbeat payload vectors for a set of groups (typically
-    all leader groups targeting one peer node) in one device gather —
-    the batched analog of heartbeat_manager.cc:203's per-group loop.
-    Returns arrays the RPC layer serializes into one node-level
-    heartbeat request (heartbeat_manager.h:54-83)."""
-    return {
-        "group": group_idx,
-        "term": state.term[group_idx],
-        "commit_index": state.commit_index[group_idx],
-        "last_dirty": state.match_index[group_idx, SELF_SLOT],
-        "last_visible": state.last_visible[group_idx],
-    }
-
-
 def local_append_update(
     state: GroupState, group_idx: jax.Array, dirty: jax.Array, flushed: jax.Array
 ) -> GroupState:
@@ -250,12 +231,6 @@ local_append_update_jit = devplane.instrument(
         "quorum.local_append_update",
     ),
     "quorum.local_append_update",
-)
-build_heartbeats_jit = devplane.instrument(
-    compileguard.instrument(
-        jax.jit(build_heartbeats), "quorum.build_heartbeats"
-    ),
-    "quorum.build_heartbeats",
 )
 
 
@@ -332,35 +307,4 @@ heartbeat_tick_jit = devplane.instrument(
         jax.jit(resident_tick, donate_argnums=0), "quorum.heartbeat_tick"
     ),
     "quorum.heartbeat_tick",
-)
-
-
-def tick_frame(
-    state: GroupState,
-    group_idx: jax.Array,
-    replica_slot: jax.Array,
-    last_dirty: jax.Array,
-    last_flushed: jax.Array,
-    seq: jax.Array,
-    hb_idx: jax.Array,
-) -> tuple[GroupState, dict[str, jax.Array]]:
-    """One fused live tick frame — the complete replication plane as a
-    single compiled program: (b) fold the tick window's accumulated
-    append-reply columns into match/flushed with the seq reordering
-    guard, (c) advance every group's commit/visible via the masked
-    quorum step, then (a) gather the next frame's heartbeat payload
-    fields for `hb_idx` from the POST-advance state. The three stages
-    the reference interleaves per group (heartbeat_manager.cc:203 +
-    consensus.cc:274/2704) collapse into one XLA dispatch; the caller
-    (raft.tick_frame.TickFrame) only handles the residue in Python."""
-    state = fold_replies(state, group_idx, replica_slot, last_dirty, last_flushed, seq)
-    state = quorum_commit_step(state)
-    return state, build_heartbeats(state, hb_idx)
-
-
-tick_frame_jit = devplane.instrument(
-    compileguard.instrument(
-        jax.jit(tick_frame, donate_argnums=0), "quorum.tick_frame"
-    ),
-    "quorum.tick_frame",
 )

@@ -14,7 +14,7 @@ directions are array programs over the shard SoA:
           are all vectorized; no per-group log walks on the tick.
   fold:   ONE jitted device call (ops.quorum.heartbeat_tick_jit) folds
           every reply from every node AND advances every group's
-          commit index (the north-star kernel; bench.py measures it).
+          commit index (the north-star kernel).
           Replies aligned with the request (the common case) fold via
           vector ops; stragglers take the per-entry slow path.
 
@@ -229,8 +229,8 @@ class HeartbeatManager:
         epoch0 = arrays.mut_epoch
         same_sent: dict[int, bytes] = {}
 
-        # vector build per peer (build_heartbeats analog): seqs, prevs,
-        # terms, commits and prev-terms in a handful of gathers.
+        # vector build per peer: seqs, prevs, terms, commits and
+        # prev-terms in a handful of gathers.
         # Suppression (consensus::suppress_heartbeats semantics): slots
         # with a live append/catch-up fiber are skipped — every dispatch
         # already carries term/commit — so under full produce load the

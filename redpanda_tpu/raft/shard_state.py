@@ -168,7 +168,7 @@ class ShardGroupArrays:
         self.el_jitter = np.zeros(g, np.float64)
         self.last_el = np.zeros(g, np.float64)
         # health lanes (ops.health): refreshed for changed rows by the
-        # per-tick sweep (host) or the fused frame program (device);
+        # per-tick fold (host and device alike, from the mirrors);
         # `health_refresh` recomputes all rows on demand. row_active
         # distinguishes allocated rows from free-list residents so a
         # recycled row never reads as a leaderless partition.
@@ -1263,18 +1263,6 @@ class ShardGroupArrays:
         trace.record("tick.readback", "run", t_back, time.monotonic_ns())
         devplane.count_transfer(out.nbytes, "d2h")
 
-    def _gather_heartbeats(self, hb_rows: np.ndarray) -> dict:
-        """Host-side heartbeat payload field gather for a row set —
-        the (a) stage of the tick frame on the numpy backend, same
-        fields as ops.quorum.build_heartbeats."""
-        return {
-            "group": hb_rows,
-            "term": self.term[hb_rows],
-            "commit_index": self.commit_index[hb_rows],
-            "last_dirty": self.match_index[hb_rows, SELF_SLOT],
-            "last_visible": self.last_visible[hb_rows],
-        }
-
     def frame_tick(  # rplint: hot
         self,
         group_rows: np.ndarray,
@@ -1282,137 +1270,21 @@ class ShardGroupArrays:
         last_dirty: np.ndarray,
         last_flushed: np.ndarray,
         seqs: np.ndarray,
-        hb_rows: "np.ndarray | None" = None,
         force_rows: "np.ndarray | None" = None,
-    ) -> tuple:
-        """One fused tick frame: fold the window's pending reply
-        columns, advance commits, and (optionally) gather the next
-        frame's heartbeat payload fields for `hb_rows` — the whole
-        live replication plane per tick as one call. Returns
-        (advanced_rows, hb_fields | None).
-
-        On the host backend (default, see _backend) the fold+commit
-        runs through the incremental host sweep and the field gather
-        is a handful of numpy takes. RP_QUORUM_BACKEND=device routes
-        everything through ops.quorum.tick_frame_jit: one compiled
-        program produces post-advance state AND the heartbeat vectors,
-        so the payload gather never re-uploads state.
-        RP_QUORUM_BACKEND=mesh shards the lanes across the device mesh
-        (parallel/mesh_frame): fold/commit/health stay chip-local with
-        one cross-chip totals fold per frame, and the heartbeat gather
-        is served from the host mirrors (chip-local by construction —
-        no device gather traffic at all)."""
-        backend = self._backend()
-        if backend == "mesh":
-            advanced = self._mesh_tick(
-                group_rows,
-                replica_slots,
-                last_dirty,
-                last_flushed,
-                seqs,
-                force_rows=force_rows,
-            )
-            hb = (
-                self._gather_heartbeats(hb_rows)
-                if hb_rows is not None and len(hb_rows)
-                else None
-            )
-            return advanced, hb
-        if backend == "host" or hb_rows is None or not len(hb_rows):
-            advanced = self.device_tick(
-                group_rows,
-                replica_slots,
-                last_dirty,
-                last_flushed,
-                seqs,
-                force_rows=force_rows,
-            )
-            hb = (
-                self._gather_heartbeats(hb_rows)
-                if hb_rows is not None and len(hb_rows)
-                else None
-            )
-            return advanced, hb
-        from ..ops.health import tick_frame_health_jit
-
-        m = len(group_rows)
-        bucket = 8
-        while bucket < m:
-            bucket *= 2
-        g_rows = np.zeros(bucket, np.int64)
-        g_slots = np.zeros(bucket, np.int64)
-        g_dirty = np.full(bucket, I64_MIN, np.int64)
-        g_flushed = np.full(bucket, I64_MIN, np.int64)
-        g_seqs = np.full(bucket, I64_MIN, np.int64)
-        if m:
-            g_rows[:m] = group_rows
-            g_slots[:m] = replica_slots
-            g_dirty[:m] = last_dirty
-            g_flushed[:m] = last_flushed
-            g_seqs[:m] = seqs
-        # heartbeat rows padded to their own power-of-two bucket (pad
-        # gathers row 0 and is sliced off) — a handful of compiled
-        # shapes total, same scheme as the reply bucket
-        h = len(hb_rows)
-        hbucket = 8
-        while hbucket < h:
-            hbucket *= 2
-        h_rows = np.zeros(hbucket, np.int64)
-        h_rows[:h] = hb_rows
-        dirty_rows = np.flatnonzero(self.quorum_dirty)
-        parts = [group_rows, dirty_rows]
-        if force_rows is not None and len(force_rows):
-            parts.append(np.asarray(force_rows, np.int64))
-        touched = (
-            np.unique(np.concatenate(parts))
-            if any(len(p) for p in parts)
-            else _EMPTY_ROWS
+    ) -> np.ndarray:
+        """One tick frame: fold the window's pending reply columns and
+        advance commits, the whole live replication plane per tick as
+        one call. Returns the advanced rows. device_tick picks the
+        fold by backend; this is the name the tick-discipline rules
+        (RPL011, RPL018) hold the frame's callers to."""
+        return self.device_tick(
+            group_rows,
+            replica_slots,
+            last_dirty,
+            last_flushed,
+            seqs,
+            force_rows=force_rows,
         )
-        before = self.commit_index[touched].copy()
-        state = self.to_device_state()
-        devplane.count_transfer(
-            5 * g_rows.nbytes + h_rows.nbytes + 2 * self.row_active.nbytes,
-            "h2d",
-        )
-        new, hb_dev, health = tick_frame_health_jit(
-            state,
-            g_rows,
-            g_slots,
-            g_dirty,
-            g_flushed,
-            g_seqs,
-            h_rows,
-            self.leader_id >= 0,
-            self.row_active,
-        )
-        self.commit_index[touched] = np.array(new.commit_index)[touched]  # rplint: disable=RPL002
-        self.last_visible[touched] = np.array(new.last_visible)[touched]  # rplint: disable=RPL002
-        self.match_index = np.array(new.match_index)  # rplint: disable=RPL002
-        self.flushed_index = np.array(new.flushed_index)  # rplint: disable=RPL002
-        self.last_seq = np.array(new.last_seq)  # rplint: disable=RPL002
-        # health rode along in the same program — zero extra dispatches
-        self.health_max_lag = np.array(health["max_lag"])  # rplint: disable=RPL002
-        self.health_under = np.array(health["under_replicated"])  # rplint: disable=RPL002
-        self.health_leaderless = np.array(health["leaderless"])  # rplint: disable=RPL002
-        devplane.count_transfer(
-            2 * self.commit_index.nbytes
-            + self.match_index.nbytes
-            + self.flushed_index.nbytes
-            + self.last_seq.nbytes
-            + self.health_max_lag.nbytes
-            + self.health_under.nbytes
-            + self.health_leaderless.nbytes,
-            "d2h",
-        )
-        self.touch()
-        self._folded_self_m[touched] = self.match_index[touched, SELF_SLOT]
-        self._folded_self_f[touched] = self.flushed_index[touched, SELF_SLOT]
-        self.quorum_dirty[:] = False
-        hb = {
-            k: np.array(v)[:h]  # rplint: disable=RPL002
-            for k, v in hb_dev.items()
-        }
-        return touched[self.commit_index[touched] > before], hb
 
     def prewarm(self, max_replies: int = 0) -> None:
         """Compile the sweep kernels up front so the first live tick
@@ -1425,12 +1297,11 @@ class ShardGroupArrays:
         Device backend: the tick program at every bucket a fold can
         land in, a power of two from 8 up to the capacity (a fold can
         touch every row) or, where larger, up to a window of
-        `max_replies` replies, and the fused frame's minimum heartbeat
-        bucket. Each bucket is its own XLA program — four to ten
-        seconds apiece cold on a TPU v5e (PERF.md) — so a deployment
-        that knows its partition count warms them here, ahead of
-        traffic. The resident device state is seeded again on the
-        way."""
+        `max_replies` replies. Each bucket is its own XLA program —
+        four to ten seconds apiece cold on a TPU v5e (PERF.md) — so a
+        deployment that knows its partition count warms them here,
+        ahead of traffic. The resident device state is seeded again on
+        the way."""
         empty = np.array([], np.int64)
         backend = self._backend()
         # declared-warmup region: compiles here are the point of the
@@ -1447,10 +1318,6 @@ class ShardGroupArrays:
             self._resident = None
             self.device_tick(empty, empty, empty, empty, empty)
             if backend == "device":
-                self.frame_tick(
-                    empty, empty, empty, empty, empty,
-                    hb_rows=np.zeros(1, np.int64),
-                )
                 bucket = 8
                 while True:
                     # all padding: nothing is scattered or folded and

@@ -9,8 +9,7 @@ per-reply math into an O(1) enqueue: the reply ingestion site
 (consensus.process_append_reply) pushes into pending-reply COLUMNS
 here, and one loop-soon flush folds the whole window through
 `ShardGroupArrays.frame_tick` — a single vectorized call covering
-fold + quorum-commit advance (+ heartbeat payload gather on the
-device backend) — then fires the registered commit-advance callbacks
+fold + quorum-commit advance — then fires the registered commit-advance callbacks
 for the rows that moved. The leader's own flush
 (replicate_batcher._flush_round → `note_self`) schedules a fold only
 where the row's lanes say the fold could advance something; a
@@ -111,9 +110,8 @@ class TickFrame:
 
     def health_totals(self) -> dict:
         """Aggregate partition-health view over this shard's lanes.
-        The per-frame sweep (host) / fused frame program (device) keeps
-        the lanes warm for every row the window touched; refresh first
-        so rows that moved OUTSIDE a frame (leadership changes, frozen
+        The per-frame fold keeps the lanes warm for every row the
+        window touched; refresh first so rows that moved OUTSIDE a frame (leadership changes, frozen
         followers with no reply traffic) are also current."""
         self.arrays.health_refresh()
         return self.arrays.health_totals()
@@ -234,7 +232,7 @@ class TickFrame:
         self.replies_folded += len(rows)
         if len(rows) > self.max_batch:
             self.max_batch = len(rows)
-        advanced, _ = self.arrays.frame_tick(
+        advanced = self.arrays.frame_tick(
             rows, slots, dirty, flushed, seqs, force_rows=force
         )
         probe = self.probe

@@ -218,11 +218,10 @@ class MemoryGovernor:
 
     The reference never faces this (seastar pre-allocates and never
     runs a tracing collector); CPython's gen2 mark pass over a large
-    settled broker heap is a latency cliff — measured r4 on this box:
-    one 837 ms gen2 pause inside a 6 s replicated-produce window, and
-    freezing the boot graph tripled acks=all throughput
-    (bench_profiles/profile_replicated.py, 10.0 -> 28.2 MB/s,
-    p99 233 -> 59 ms).
+    settled broker heap is a latency cliff — seen in round 4 on a
+    one-core CPU box: one 837 ms gen2 pause inside a 6 s
+    replicated-produce window, and freezing the boot graph tripled
+    acks=all throughput there (not measured on the chip).
 
     Policy:
       - on start: collect once, then gc.freeze() the settled object

@@ -174,7 +174,7 @@ class KafkaFrameReply(Envelope):
 
 
 class ShardStats(Envelope):
-    """Per-shard attribution counters (bench_profiles tables)."""
+    """Per-shard attribution counters."""
 
     SERDE_FIELDS = [
         ("shard", u16),

@@ -2,9 +2,9 @@
 
 The replicate batcher coalesces fsyncs *within* one raft group, but a
 broker hosting 1k groups under rotating producers issues one executor
-round-trip per group per produce — at ~1.1 ms measured queue latency
-each, the executor hand-off dominated the leader flush path
-(bench_profiles, r4 span `batcher.fsync`). The reference hits the same
+round-trip per group per produce, and the executor hand-off (about a
+millisecond of queue latency each on a CPU box; not measured on the
+chip) dominated the leader flush path. The reference hits the same
 wall differently and solves it in segment_appender's shared flush
 queue; here one coalescer per event loop gathers every fsync request
 that arrives while an executor round is in flight and settles them in

@@ -294,8 +294,15 @@ async def run_chaos(
     admin_ops: bool = False,
     nemesis=None,
     store_faults=None,
+    min_acked: int = 0,
 ) -> dict:
-    """`tiered=True` runs the same fault schedule against a
+    """`min_acked` keeps the fault schedule running past `duration_s`
+    (for at most four times as long) until the producer has that many
+    acknowledgements: on a loaded core a fixed window is a count of
+    scheduler slices, not of the cluster's availability. The seeded
+    schedule is the same, only longer.
+
+    `tiered=True` runs the same fault schedule against a
     remote.write topic with aggressive segment roll + retention, with
     archival passes + housekeeping churning THROUGHOUT the faults —
     the validator's fetch-from-0 then crosses the remote/local seam,
@@ -377,10 +384,14 @@ async def run_chaos(
                 admin_ops_fuzzer(cluster, random.Random(seed ^ 0x5EED), fuzz_stop)
             )
 
-        deadline = asyncio.get_event_loop().time() + duration_s
+        clock = asyncio.get_event_loop().time
+        deadline = clock() + duration_s
+        last_call = deadline + 4 * duration_s
         down: int | None = None
         events = []
-        while asyncio.get_event_loop().time() < deadline:
+        while clock() < deadline or (
+            len(producer.acked) < min_acked and clock() < last_call
+        ):
             await asyncio.sleep(rng.uniform(0.4, 0.9))
             action = rng.choice(faults)
             if down is not None:

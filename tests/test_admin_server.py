@@ -85,8 +85,14 @@ async def _admin_surface(tmp_path):
         assert st == 200 and body["status"] == "ready"
         st, body = await http(addr, "GET", "/v1/brokers")
         assert st == 200 and len(body["brokers"]) == 3
-        st, body = await http(addr, "GET", "/v1/cluster/health_overview")
-        assert st == 200 and body["nodes_down"] == []
+        # a node is down until its first status ping has come back
+        deadline = asyncio.get_event_loop().time() + 10
+        while True:
+            st, body = await http(addr, "GET", "/v1/cluster/health_overview")
+            if (st, body["nodes_down"]) == (200, []):
+                break
+            assert asyncio.get_event_loop().time() < deadline, (st, body)
+            await asyncio.sleep(0.1)
 
         # topic lifecycle over HTTP
         st, body = await http(

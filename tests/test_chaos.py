@@ -15,7 +15,13 @@ from chaos_harness import run_chaos
 
 def test_chaos_network_partitions(tmp_path):
     stats = asyncio.run(
-        run_chaos(tmp_path, seed=101, duration_s=5.0, faults=("partition",))
+        run_chaos(
+            tmp_path,
+            seed=101,
+            duration_s=5.0,
+            faults=("partition",),
+            min_acked=21,
+        )
     )
     assert stats["acked"] > 20, stats
     assert any(e[0] == "partition" for e in stats["events"])

@@ -16,6 +16,8 @@ the nemesis lifts.
 
 import asyncio
 import contextlib
+import json
+import os
 import time
 
 import pytest
@@ -282,6 +284,42 @@ def test_slo_profile_loading():
     fallback = _alerts.load_slo_profile("no-such-profile")
     assert fallback["profile"] == "builtin-default"
     assert _alerts.rules_from_slo(fallback["slo"])
+
+
+@pytest.mark.parametrize(
+    "name,slo,rules",
+    [
+        (
+            "default",
+            {"p99_ms": 40.0, "p999_ms": 160.0, "max_lag": 1024},
+            {"produce_p99": 0.04, "produce_p999": 0.16, "replication_lag": 1024.0},
+        ),
+        (
+            "single",
+            {"p99_ms": 15.0, "p999_ms": 60.0},
+            {"produce_p99": 0.015, "produce_p999": 0.06},
+        ),
+        ("tiered", {"cold_p99_ms": 250.0, "warm_p99_ms": 60.0}, {}),
+        (
+            "traffic",
+            {"p99_ms": 250.0, "p999_ms": 750.0},
+            {"produce_p99": 0.25, "produce_p999": 0.75},
+        ),
+    ],
+)
+def test_slo_profile_files(name, slo, rules):
+    """The four shipped profiles: what `load_slo_profile` returns for
+    each name, the thresholds the latency and lag rules take from it,
+    and that a file holds nothing the loader does not read."""
+    assert _alerts.load_slo_profile(name) == {"profile": name, "slo": slo}
+    got = {
+        r.name: r.threshold
+        for r in _alerts.rules_from_slo(slo)
+        if r.name in ("produce_p99", "produce_p999", "replication_lag")
+    }
+    assert got == pytest.approx(rules)
+    with open(os.path.join(_alerts._PROFILE_DIR, f"slo_{name}.json")) as f:
+        assert set(json.load(f)) == {"profile", "description", "slo"}
 
 
 # ------------------------------------------ continuous profiler

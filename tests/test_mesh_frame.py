@@ -97,7 +97,7 @@ def _replay(arrays, sched):
     """Run every frame; returns the per-frame advanced-row sets."""
     advanced = []
     for rr, slots, dirty, flushed, seq in sched:
-        adv, _ = arrays.frame_tick(rr, slots, dirty, flushed, seq)
+        adv = arrays.frame_tick(rr, slots, dirty, flushed, seq)
         advanced.append(np.sort(np.asarray(adv, np.int64)))
     return advanced
 

@@ -983,7 +983,14 @@ def test_loop_lag_probe_sees_a_blocked_loop_and_is_shared(tmp_path, window):
             assert len(trace.LoopLagProbe._by_loop) == 1
             assert probe._refs == 2
             window.reset()
-            await asyncio.sleep(0.05)
+            # two timers of a quiet loop, however long a loaded core
+            # takes to run them
+            deadline = loop.time() + 5.0
+            while (
+                window.status()["loop"]["samples"] < 2
+                and loop.time() < deadline
+            ):
+                await asyncio.sleep(0.01)
             quiet = window.status()["loop"]
             _time.sleep(0.05)  # hold the loop: every timer runs late
             await asyncio.sleep(0.03)

@@ -226,20 +226,6 @@ class TestFoldReplies:
         assert np.all(np.asarray(new.commit_index) == 100)
 
 
-class TestBuildHeartbeats:
-    def test_gather(self):
-        state = make_group_state(8, 4)
-        state = state._replace(
-            term=jnp.arange(8, dtype=jnp.int64),
-            commit_index=jnp.arange(8, dtype=jnp.int64) * 10,
-            match_index=state.match_index.at[:, 0].set(jnp.arange(8, dtype=jnp.int64) * 100),
-        )
-        hb = q.build_heartbeats(state, jnp.array([2, 5]))
-        assert hb["term"].tolist() == [2, 5]
-        assert hb["commit_index"].tolist() == [20, 50]
-        assert hb["last_dirty"].tolist() == [200, 500]
-
-
 class TestDeviceCrc32c:
     @pytest.mark.parametrize("seed,stride", [(0, 64), (1, 256), (2, 1024)])
     def test_differential_vs_host(self, seed, stride):

@@ -61,9 +61,20 @@ def test_standalone_three_process_cluster(tmp_path):
         )
 
     async def drive():
-        from redpanda_tpu.kafka.client import KafkaClient
+        from redpanda_tpu.kafka.client import KafkaClient, KafkaClientError
+        from redpanda_tpu.kafka.protocol.headers import ErrorCode
 
-        c = KafkaClient([("127.0.0.1", p) for p in kafka])
+        class Client(KafkaClient):
+            """Counts leader resolutions: one a produce attempt, so the
+            excess over the calls made is the client's own retries."""
+
+            resolved = 0
+
+            async def leader_conn(self, *a, **kw):
+                self.resolved += 1
+                return await super().leader_conn(*a, **kw)
+
+        c = Client([("127.0.0.1", p) for p in kafka])
         deadline = time.time() + 30
         while True:
             try:
@@ -73,12 +84,50 @@ def test_standalone_three_process_cluster(tmp_path):
                 if time.time() > deadline:
                     raise
                 await asyncio.sleep(0.5)
+        deadline = time.time() + 30
+        # fresh processes still move leadership about (first elections,
+        # then the leader balancer), and a produce that straddles a
+        # move is retried by a client with no idempotent producer: wait
+        # until every partition names the same leader on two reads
+        seen = None
+        while True:
+            md = await c.metadata(["proc"])
+            now = sorted(
+                (p.partition_index, p.leader_id)
+                for t in md.topics
+                if t.error_code == 0
+                for p in t.partitions
+            )
+            if len(now) == 3 and now == seen and all(l >= 0 for _p, l in now):
+                break
+            assert time.time() < deadline, (seen, now)
+            seen = now
+            await asyncio.sleep(0.5)
+        calls = 0
+        c.resolved = 0
         for i in range(30):
-            await c.produce("proc", i % 3, [(b"k%d" % i, b"v%d" % i)])
-        total = 0
+            # the topic is committed, but the broker this produce is
+            # routed by may not have applied it yet, and says so before
+            # anything is sent
+            while True:
+                try:
+                    calls += 1
+                    await c.produce("proc", i % 3, [(b"k%d" % i, b"v%d" % i)])
+                    break
+                except KafkaClientError as e:
+                    if (
+                        e.code != ErrorCode.unknown_topic_or_partition
+                        or time.time() > deadline
+                    ):
+                        raise
+                    await asyncio.sleep(0.2)
+        retried = c.resolved - calls
+        got = []
         for p in range(3):
-            total += len(await c.fetch("proc", p, 0))
-        assert total == 30
+            got += [(k, v) for _o, k, v in await c.fetch("proc", p, 0)]
+        # exactly once each; a failure says whether the client retried
+        assert len(got) == 30, (retried, sorted(got))
+        assert set(got) == {(b"k%d" % i, b"v%d" % i) for i in range(30)}
         await c.close()
 
     def tail(i):

@@ -115,7 +115,7 @@ class TestDifferential:
         # quorum_dirty is set by alloc/reset; clear it and use the
         # tick frame's force path, the live enqueue route
         arrays.quorum_dirty[:] = False
-        advanced, _ = arrays.frame_tick(
+        advanced = arrays.frame_tick(
             np.empty(0, np.int64),
             np.empty(0, np.int64),
             np.empty(0, np.int64),
@@ -169,8 +169,8 @@ class TestDifferential:
             )
 
     def test_host_device_frame_identical(self, monkeypatch):
-        """Backend parity for the fused program: byte-identical commit
-        decisions and heartbeat payload fields host vs device."""
+        """Backend parity for frame_tick: byte-identical advanced set,
+        commit_index and last_visible host vs device."""
         g = 96
         results = {}
         for backend in ("host", "device"):
@@ -180,23 +180,15 @@ class TestDifferential:
             rng = np.random.default_rng(11)
             _fill_random(arrays, rows, rng)
             arrays.quorum_dirty[:] = False
-            hb_rows = rows[:: 3].copy()
-            advanced, hb = arrays.frame_tick(
-                *([np.empty(0, np.int64)] * 5),
-                hb_rows=hb_rows,
-                force_rows=rows,
+            advanced = arrays.frame_tick(
+                *([np.empty(0, np.int64)] * 5), force_rows=rows
             )
             results[backend] = (
                 np.sort(np.asarray(advanced)).tobytes(),
                 arrays.commit_index[rows].tobytes(),
                 arrays.last_visible[rows].tobytes(),
-                {k: np.asarray(v).tobytes() for k, v in hb.items()},
             )
-        assert results["host"][0] == results["device"][0]
-        assert results["host"][1] == results["device"][1]
-        assert results["host"][2] == results["device"][2]
-        for k in results["host"][3]:
-            assert results["host"][3][k] == results["device"][3][k], k
+        assert results["host"] == results["device"]
 
 
 _EMPTY = np.empty(0, np.int64)

@@ -185,6 +185,7 @@ async def _follows_leadership(tmp_path):
                 await asyncio.sleep(0.2)
 
 
+@pytest.mark.timing
 def test_transform_follows_leadership(tmp_path):
     asyncio.run(_follows_leadership(tmp_path))
 

@@ -6,16 +6,14 @@ asserted, not measured. This tool measures a FULL FOLD (every group
 advancing — the worst case; steady-state ticks skip the sweep entirely
 since the r3 incremental change) through shard_state.host_tick and
 through the device path, at several shard sizes, using the honest
-device methodology (distinct settled inputs, per-call blocking; see
-bench.py bench_fused's methodology note).
+device methodology (distinct settled inputs, per-call blocking).
 
 Usage:
     python tools/measure_quorum_crossover.py     # whatever JAX finds
 
 The report names the platform and device kind it ran on, as JAX
 reports them: a table from JAX_PLATFORMS=cpu says nothing about a
-chip. Prints a table plus the measured crossover; pass --update-docs
-to write the report file under bench_profiles/.
+chip. Prints a table plus the measured crossover.
 """
 
 from __future__ import annotations
@@ -71,7 +69,6 @@ def measure(g: int, backend: str, iters: int = 8) -> float:
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--update-docs", action="store_true")
     args = ap.parse_args()
     import jax
 
@@ -99,14 +96,6 @@ def main() -> None:
     )
     report = "\n".join(lines)
     print(report)
-    if args.update_docs:
-        path = os.path.join(
-            os.path.dirname(__file__), "..", "bench_profiles",
-            "quorum_crossover.txt",
-        )
-        with open(path, "w") as f:
-            f.write(report + "\n")
-        print(f"\nwrote {path}")
 
 
 if __name__ == "__main__":

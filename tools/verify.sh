@@ -84,9 +84,6 @@ env JAX_PLATFORMS=cpu python tools/rpsan_smoke.py
 echo "== health-plane smoke (partition_health + bounded /metrics) =="
 env JAX_PLATFORMS=cpu python tools/scrape_smoke.py --health
 
-echo "== bench gate selftest (trajectory extraction + grading) =="
-python tools/bench_gate.py --selftest
-
 echo "== flight-data smoke (history ring + alerts + profiler) =="
 env JAX_PLATFORMS=cpu python tools/scrape_smoke.py --alerts
 
