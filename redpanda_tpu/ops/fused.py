@@ -12,7 +12,7 @@ Row layout ([B, PREFIX + n + CELL] uint8, zero-padded):
     [ crc_prefix (40 B) | records body (n bucket) | CELL guard ]
 
 The Kafka batch CRC covers crc_prefix||body (model/record.h:398), so
-the CRC scan reads the row head; LZ4 compresses the body slice only.
+the CRC reads the row head; LZ4 compresses the body slice only.
 Reference: BASELINE.md north-star #1 ("CRC32c + compress"),
 src/v/compression/compression.h:21 registry gating.
 """
@@ -43,17 +43,20 @@ PREFIX = 40  # models/record.py _CRC_PREFIX packed size
 def _fused(data: jax.Array, body_len: jax.Array, n: int):
     """data [B, PREFIX + n + CELL] uint8; body_len int32[B].
     Returns (crc uint32[B] over prefix||body, lz4 blocks + lengths)."""
-    # CRC slice: width PREFIX+n rounded up to the 512-byte fold chunk —
+    # CRC slice: width PREFIX+n rounded up to the 512-byte CRC chunk —
     # the matrix is allocated with that slack, zero-padded
     crc_w = ((PREFIX + n + 511) // 512) * 512
     crc = crc32c_device(
         data[:, :crc_w], (body_len + PREFIX).astype(jnp.int64)
     )
-    # barrier: without it XLA fuses the crc path's 512-chunk relayout
-    # into the lz4 slice's consumers and the combined program runs
-    # ~1000x slower (measured: 8.5 s vs ~1 ms for this shape). The
-    # barrier materializes the body slice once, then both kernels run
-    # at their standalone speeds off the single upload.
+    # barrier: without it XLA is free to fuse the crc path's view of
+    # the row (its [rows * chunks, 512] reshape) into the lz4 slice's
+    # consumers. Measured when the CRC was a scan over chunks, that
+    # fusion ran the combined program ~1000x slower (8.5 s vs ~1 ms for
+    # this shape); not timed again on the chip since the CRC takes all
+    # chunks at once, so the barrier stays: it materializes the body
+    # slice once, then both kernels run at their standalone speeds off
+    # the single upload.
     body = jax.lax.optimization_barrier(
         data[:, PREFIX : PREFIX + n + CELL]
     )

@@ -246,6 +246,105 @@ class TestDeviceCrc32c:
         out = dev_crc.crc32c_batch_device(data, np.array([9]))
         assert int(out[0]) == 0xE3069283
 
+    # One differential test of the chunk-parallel body against the host
+    # CRC, a case each: the lengths around a chunk's and the stride's
+    # edges, every row bucket the benchmark's warmer makes (8 to 64),
+    # chunk counts that are no power of two (ops/fused.py's crc_w), and
+    # a matrix above _TILE chunk rows, which runs the tiled loop.
+    @pytest.mark.parametrize(
+        "rows,stride,lens",
+        [
+            *[(1, 2048, [n]) for n in (0, 1, 511, 512, 513, 2048 - 512, 2048)],
+            *[(r, 1024, None) for r in (1, 3, 8, 9, 16, 31, 32, 39, 64)],
+            (8, 3 * 512, None),
+            (8, 129 * 512, None),
+            (17, 262144, None),
+        ],
+        ids=lambda v: "x".join(map(str, v)) if isinstance(v, list) else str(v),
+    )
+    def test_chunk_parallel_differential(self, rows, stride, lens):
+        rng = np.random.default_rng(rows * 131 + stride)
+        if lens is None:
+            lens = rng.integers(0, stride + 1, rows)
+            lens[0] = stride
+            lens[-1] = max(0, stride - 512)
+        lens = np.asarray(lens, np.int64)
+        mat = np.zeros((rows, stride), dtype=np.uint8)
+        for i, n in enumerate(lens):
+            mat[i, :n] = rng.integers(0, 256, n, dtype=np.uint8)
+        want = [host_crc.crc32c(mat[i, :n].tobytes()) for i, n in enumerate(lens)]
+        want = np.array(want, np.uint32)
+        if stride & (stride - 1):
+            # crc32c_batch_device pads a stride to a power of two: the
+            # kernel itself takes any whole number of chunks, as bytes
+            # (ops/fused.py) and as words
+            got = np.asarray(dev_crc.crc32c_device(mat, lens))
+            words = np.asarray(dev_crc.crc32c_device(mat.view("<u4"), lens))
+            np.testing.assert_array_equal(words, want)
+        else:
+            got = dev_crc.crc32c_batch_device(mat, lens)
+        np.testing.assert_array_equal(got, want)
+
+    @staticmethod
+    def _lowered(rows, lanes, dtype):
+        fn = dev_crc.crc32c_device
+        while hasattr(fn, "fn"):  # devplane / compileguard wrappers
+            fn = fn.fn
+        return fn.lower(
+            jax.ShapeDtypeStruct((rows, lanes), dtype),
+            jax.ShapeDtypeStruct((rows,), jnp.int64),
+        ).as_text()
+
+    @pytest.mark.parametrize(
+        "lanes,dtype", [(65536, jnp.uint8), (16384, jnp.uint32)]
+    )
+    def test_live_shape_lowers_to_no_loop(self, lanes, dtype):
+        """[8, 65536] bytes is what a fetch of the benchmark's batches
+        dispatches (as words): every chunk in one step. Above the tile
+        the loop is over tiles."""
+        assert "while" not in self._lowered(8, lanes, dtype)
+        assert "while" in self._lowered(128, lanes, dtype)
+
+    def test_one_transfer_each_way_and_warmed_shapes_only(self, monkeypatch):
+        """A call is one dispatch that takes its host arrays up with it
+        (no transfer of its own before it) and one readback, and after
+        the warmer's calls (full rows at every row bucket) no count of
+        payloads of that body compiles anything."""
+        from redpanda_tpu.utils import compileguard
+
+        moved = []
+        monkeypatch.setattr(
+            dev_crc.devplane,
+            "count_transfer",
+            lambda nbytes, direction: moved.append((direction, nbytes)),
+        )
+        kernel = dev_crc.crc32c_device
+
+        def dispatch(*args):
+            assert all(type(a) is np.ndarray for a in args)
+            moved.append(("dispatch", sum(a.nbytes for a in args)))
+            return kernel(*args)
+
+        monkeypatch.setattr(dev_crc, "crc32c_device", dispatch)
+        body = 1500
+        for rows in (8, 16, 32, 64):  # benchmark/warmers/crc.py
+            dev_crc.crc32c_batch_device(
+                np.zeros((rows, body), np.uint8), np.full(rows, body, np.int64)
+            )
+        warmed = compileguard.compile_counts()["crc32c.device"]
+        rng = np.random.default_rng(3)
+        for n in (1, 3, 39):
+            mat = rng.integers(0, 256, (n, body), dtype=np.uint8)
+            lens = np.full(n, body, np.int64)
+            del moved[:]
+            got = dev_crc.crc32c_batch_device(mat, lens)
+            assert [d for d, _ in moved] == ["h2d", "dispatch", "d2h"]
+            assert moved[0][1] == moved[1][1]  # counted what went up
+            np.testing.assert_array_equal(
+                got, host_crc.crc32c_batch(mat, lens.astype(np.uint64))
+            )
+        assert compileguard.compile_counts()["crc32c.device"] == warmed
+
 
 class TestClusterStep:
     def test_multi_device_tick(self):
