@@ -10,12 +10,19 @@ the run is correct when each holds:
                       past the close                                  = 0
   never_fetched       acknowledged batches no fetch returned at the
                       offset the ack gave                             = 0
-  fetched_wrong       acknowledged batches that came back with other
-                      bytes than the reference's, crc field onward    = 0
+  fetched_wrong       acknowledged batches of which a fetch returned
+                      something their template says did not come back
+                      (a pass-through topic: other bytes than the
+                      reference's, crc field onward; a topic with a
+                      codec: a CRC that does not hold, another codec or
+                      header, or records that the reference's decoder
+                      does not read back byte for byte)               = 0
   replicas_missing    of a seeded sample of the acknowledged batches
                       (each partition's last among them), copies that
                       the configuration's replication factor promises
-                      and a broker's log does not hold byte for byte  = 0
+                      and a broker's log does not hold: not there, not
+                      what the template says came back, or not the
+                      bytes of the other copies, crc field onward     = 0
   not_flushed_at_ack  of the acks sampled as they arrived (one every
                       `ack_sample_s`), those that fewer than a majority
                       of the replicas had flushed to their logs when
@@ -27,10 +34,12 @@ the run is correct when each holds:
                       window (each, under `--trace 1`; one in 16
                       otherwise)                                      >= 1
 
-The load generator compares each fetched batch with the reference's
-bytes as it arrives (generators/open_loop.py: `fetched_template`), in
-its own process; the replicas are read here, in-process, through each
-broker's partition, as chip_smoke.py's `_check_replicas` does.
+The load generator asks its template of each fetched batch as it
+arrives (generators/open_loop.py: `fetched_template`), in its own
+process; the replicas are read here, in-process, through each broker's
+partition, as chip_smoke.py's `_check_replicas` does, and held to the
+same template. Neither compares a stored batch with bytes of its own:
+what has to come back is the template's to say (reference.py).
 """
 
 from __future__ import annotations
@@ -96,6 +105,11 @@ def sample_rows(acked: list, seed: int, n: int) -> list:
 
 
 async def replicas_missing(brokers: list, config: dict, rows: list, tpl: list) -> int:
+    """Copies of the sampled batches that are not held. The copies of
+    one batch also have to be each other's bytes from the crc field on:
+    where the reference pins the bytes that follows, and where the
+    broker rewrites the batch it says that the followers hold what the
+    leader stored and no compression of their own."""
     rf = {t["name"]: t["replication_factor"] for t in config["topics"]}
     missing = 0
     wait_until = time.monotonic() + CATCH_UP_S
@@ -103,15 +117,19 @@ async def replicas_missing(brokers: list, config: dict, rows: list, tpl: list) -
         parts = cluster.replicas(brokers, topic, p)
         missing += max(0, rf[topic] - len(parts))
         end = base + tpl[ti].records
+        first = None
         for part in parts[: rf[topic]]:
             while part.high_watermark() < end and time.monotonic() < wait_until:
                 await asyncio.sleep(0.05)
             held = False
             if part.high_watermark() >= end:
                 got = part.read_kafka(base, 1, upto_kafka=end)
-                held = bool(got) and got[0][0] == base and (
-                    got[0][1].to_kafka_wire()[CRC_AT:] == tpl[ti].tail
-                )
+                if got and got[0][0] == base:
+                    wire = got[0][1].to_kafka_wire()
+                    held = tpl[ti].came_back(wire)
+                    if held and first is None:
+                        first = wire[CRC_AT:]
+                    held = held and wire[CRC_AT:] == first
             missing += not held
     return missing
 

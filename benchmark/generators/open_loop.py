@@ -21,13 +21,12 @@ from __future__ import annotations
 
 import asyncio
 import json
-import struct
 import time
 from collections import deque
 
 import numpy as np
 
-from benchmark.reference import CRC_AT, split_batches
+from benchmark.reference import split_batches
 from benchmark.run import resolve
 
 NOT_LEADER = (3, 5, 6)  # unknown_topic_or_partition, leader_not_available, not_leader
@@ -69,7 +68,10 @@ async def run(spec: dict, say) -> dict:
     seconds = sum(s for s, _r in steps)
     bootstrap = [tuple(a) for a in spec["bootstrap"]]
     tpl = resolve(traffic["templates"]["maker"], "templates")(seed, traffic, config)
-    by_crc = {t.crc: i for i, t in enumerate(tpl)}
+    # what a stored batch is known by, and whether it came back, are the
+    # templates' to say (benchmark/reference.py)
+    by_key = {t.key: i for i, t in enumerate(tpl)}
+    key_of = tpl[0].key_of
     linger = float(traffic["linger_ms"]) / 1e3
     max_in_flight = int(traffic["max_in_flight"])
     max_request = int(traffic["max_request_bytes"])
@@ -92,7 +94,8 @@ async def run(spec: dict, say) -> dict:
     # one row a batch:
     # [topic, p, tpl, base, t_due, t_ack, err, t_sent, tries, in_request]
     rows: list[list] = []
-    fetched: dict[tuple[str, int, int], tuple[float, int]] = {}
+    # (topic, partition, base) -> when, which template, how many bytes
+    fetched: dict[tuple[str, int, int], tuple[float, int, int]] = {}
     fetch_errors: list[str] = []
     fetches = requests = 0
     late: list[float] = []
@@ -304,11 +307,10 @@ async def run(spec: dict, say) -> dict:
                 code, wire = answers.get(tp, (-1, b""))
                 got = split_batches(wire) if code == 0 else []
                 for base, batch in got:
-                    crc = struct.unpack_from(">I", batch, CRC_AT)[0]
-                    ti = by_crc.get(crc, -1)
-                    if ti >= 0 and batch[CRC_AT:] != tpl[ti].tail:
+                    ti = by_key.get(key_of(batch), -1)
+                    if ti >= 0 and not tpl[ti].came_back(batch):
                         ti = -1
-                    fetched.setdefault((tp[0], tp[1], base), (now, ti))
+                    fetched.setdefault((tp[0], tp[1], base), (now, ti, len(batch)))
                     want[tp].discard(base)
                 if got and at in want[tp]:
                     # the answer began elsewhere than at the offset asked
@@ -358,8 +360,7 @@ async def run(spec: dict, say) -> dict:
 
     out_rows = []
     for row in rows:
-        t_fetch, got_ti = fetched.get((row[0], row[1], row[3]), (0.0, -2))
-        out_rows.append(row + [t_fetch, got_ti])
+        out_rows.append(row + list(fetched.get((row[0], row[1], row[3]), (0.0, -2, 0))))
     pending = sum(1 for r in rows if r[3] < 0 and r[6] is None)
     return {
         "t0": t0,
@@ -367,7 +368,7 @@ async def run(spec: dict, say) -> dict:
         "steps": steps,
         "columns": ["topic", "partition", "template", "base", "t_due",
                     "t_ack", "error", "t_sent", "tries", "in_request",
-                    "t_fetch", "fetched_template"],
+                    "t_fetch", "fetched_template", "fetched_bytes"],
         "rows": out_rows,
         "unanswered": pending,
         "consumers_stuck": len(stuck),

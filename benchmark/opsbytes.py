@@ -45,18 +45,18 @@ def crc_shape(body_bytes: int, batches: int) -> tuple[int, int]:
     return pow2_at_least(batches, ROW_FLOOR), pow2_at_least(body_bytes, CRC_CHUNK)
 
 
-def fetch_crc_shape(config: dict, traffic: dict, tpl: list) -> tuple[int, int]:
+def fetch_crc_shape(config: dict, traffic: dict, batch_bytes: int) -> tuple[int, int]:
     """(rows, stride) of the largest `crc32c.device` dispatch a fetch of
-    this traffic stages. The broker verifies all of a fetch's batches in
-    one dispatch, and a consumer's fetch can name every partition it
-    tails: its share of each topic's partitions, with as many whole
-    batches of each as `fetch_max_bytes` holds (one at the least), each
-    as long as the longest template from its first crc-covered byte on."""
-    longest = max(len(t.wire) for t in tpl)
-    a_partition = max(1, int(traffic["fetch_max_bytes"]) // longest)
+    this traffic stages where a stored batch is `batch_bytes` long on
+    the wire. The broker verifies all of a fetch's batches in one
+    dispatch, and a consumer's fetch can name every partition it tails:
+    its share of each topic's partitions, with as many whole batches of
+    each as `fetch_max_bytes` holds (one at the least), each taken from
+    its first crc-covered byte on."""
+    a_partition = max(1, int(traffic["fetch_max_bytes"]) // batch_bytes)
     consumers = int(traffic["consumers"])
     partitions = sum(-(-t["partitions"] // consumers) for t in config["topics"])
-    return crc_shape(longest - BODY_AT, partitions * a_partition)
+    return crc_shape(batch_bytes - BODY_AT, partitions * a_partition)
 
 
 def crc_bytes(rows: int, stride: int) -> int:

@@ -34,16 +34,18 @@ def tick_roofline(ctx: dict, params: dict):
 
 def crc_roofline(ctx: dict, params: dict):
     """Bytes bound it: least time is every crc-covered byte of the
-    batches that were fetched in the traced seconds, read once
-    (opsbytes.crc_bytes of one row of that length a batch; the padding
-    the program adds is its own cost), over the peak HBM rate."""
+    batches that were fetched in the traced seconds, as long as they
+    were stored (what a broker compressed is shorter than what was
+    sent), read once (opsbytes.crc_bytes of one row of that length a
+    batch; the padding the program adds is its own cost), over the peak
+    HBM rate."""
     if ctx.get("trace") is None or not ctx.get("fetched_in_trace"):
         return None
     secs, n = tr.module_seconds(ctx["trace"], params["module"])
     if n == 0 or secs <= 0:
         return None
     least = sum(
-        opsbytes.crc_bytes(1, len(ctx["templates"][ti].wire) - opsbytes.BODY_AT)
-        for ti in ctx["fetched_in_trace"]
+        opsbytes.crc_bytes(1, stored - opsbytes.BODY_AT)
+        for stored in ctx["fetched_in_trace"]
     ) / _peak(ctx)
     return 100.0 * least / secs

@@ -77,16 +77,27 @@ def load_traffic(path: str) -> dict:
     return traffic
 
 
-def load_cell(workload: str, traffic_file: str | None = None) -> dict:
+def load_cell(
+    workload: str, traffic_file: str | None = None, manifest_file: str | None = None
+) -> dict:
     """The cell's entry with its configuration, traffic and per-layer
-    metric files, all found by the names in BENCHMARK.json."""
+    metric files, all found by the names in BENCHMARK.json. With a
+    manifest of the builder's own (`--manifest`), its keys are laid
+    over BENCHMARK.json's (`workloads` at the least), and the cell's
+    configuration and traffic files are the ones beside it."""
     manifest = load_json(ROOT, "BENCHMARK.json")
+    configs = os.path.join(HERE, "configs")
+    traffics = os.path.join(HERE, "traffic")
+    if manifest_file:
+        manifest = {**manifest, **load_json(manifest_file)}
+        configs = traffics = os.path.dirname(os.path.abspath(manifest_file))
     cell = next((w for w in manifest["workloads"] if w["name"] == workload), None)
     if cell is None:
-        raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
-    config = load_json(HERE, "configs", cell["config"] + ".json")
+        raise KeyError(
+            f"no workload {workload!r} in {manifest_file or 'BENCHMARK.json'}")
+    config = load_json(configs, cell["config"] + ".json")
     traffic = load_traffic(
-        traffic_file or os.path.join(HERE, "traffic", cell["traffic"] + ".json")
+        traffic_file or os.path.join(traffics, cell["traffic"] + ".json")
     )
 
     def reports(m: dict) -> bool:
@@ -167,6 +178,7 @@ def percentile(values: list[float], q: float) -> float:
 
 # columns of a record row (generators/open_loop.py)
 TPL, BASE, T_DUE, T_ACK, ERR, IN_REQUEST, T_FETCH, GOT = 2, 3, 4, 5, 6, 9, 10, 11
+GOT_BYTES = 12
 
 
 def reduce_records(rec: dict, drain_s: float) -> dict:
@@ -459,9 +471,18 @@ def build_result(args, loaded: dict, device: dict, facts: dict) -> dict:
             1e3 * percentile(rec["late_s"] or [0.0], 0.95), 3),
         "sampled_dispatches": {
             k: v["count"] for k, v in window["devplane"].get("kernels", {}).items()},
+        "dispatch_p50_ms": {
+            k: v["p50_ms"] for k, v in window["devplane"].get("kernels", {}).items()
+            if v["count"]},
         "sample_every": window["devplane"].get("sample_every"),
         "transfer_bytes": window["devplane"].get("transfer_bytes"),
         "clients": rec["clients"],
+        # a batch as sent, and as the median fetch returned it: apart
+        # where the broker recompresses
+        "batch_bytes": {
+            "sent": len(facts["templates"][0].wire),
+            "stored_p50": percentile(
+                [r[GOT_BYTES] for r in rec["rows"] if r[GOT] >= 0] or [0], 0.5)},
     }
     if len(rec["steps"]) > 1:
         result["detail"]["steps"] = by_step(rec, float(facts["traffic"]["drain_s"]))
@@ -503,9 +524,10 @@ def read_layers(args, loaded: dict, device: dict, facts: dict, result: dict) -> 
         "config": facts["config"],
         "traffic": facts["traffic"],
         "templates": facts["templates"],
-        # the template of every batch a fetch returned in the traced seconds
+        # the length, as stored, of every batch that a fetch returned in
+        # the traced seconds and that came back
         "fetched_in_trace": [
-            r[GOT] for r in facts["rec"]["rows"]
+            r[GOT_BYTES] for r in facts["rec"]["rows"]
             if r[GOT] >= 0 and t_a <= r[T_FETCH] <= t_b
         ],
     }
@@ -537,6 +559,10 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--traffic-file", metavar="FILE",
                     help="for a sweep, never a cell: drive this traffic file "
                     "in place of the cell's")
+    ap.add_argument("--manifest", metavar="FILE",
+                    help="for the builder and the tests, never the driver: "
+                    "find the cell in FILE, laid over BENCHMARK.json, and its "
+                    "configuration and traffic files beside FILE")
     ap.add_argument("--control", choices=CONTROLS,
                     help="run with one stated guarantee broken; must come "
                     "out as not correct")
@@ -545,7 +571,7 @@ def main(argv: list[str] | None = None) -> int:
         log(f"{ROOT} holds no redpanda_tpu/: there is no system to measure")
         return EXIT_NOT_A_CHECKOUT
     try:
-        loaded = load_cell(args.workload, args.traffic_file)
+        loaded = load_cell(args.workload, args.traffic_file, args.manifest)
     except (KeyError, OSError, ValueError) as e:
         log(f"cannot load the cell: {e!r}")
         return EXIT_BAD_CELL

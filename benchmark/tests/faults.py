@@ -10,6 +10,8 @@ the broken path.
                    replicas miss what the window acknowledges
   answer_altered   one byte of every fetched record set is flipped after
                    the broker's own verify-on-read
+  recompress_skipped  on a topic that sets a codec the broker stores
+                   what it was sent: the batches come back plain
 """
 
 from __future__ import annotations
@@ -17,6 +19,8 @@ from __future__ import annotations
 import numpy as np
 
 FAULTS = ("tick_frozen", "replica_left_out", "answer_altered")
+#: faults only a topic with a codec can have (tests/recompress/)
+CODEC_FAULTS = ("recompress_skipped",)
 
 
 def plant(name: str) -> None:
@@ -70,8 +74,15 @@ def _answer_altered(brokers) -> None:
     cls._verify_fetch_response = verify_then_flip
 
 
+def _recompress_skipped(brokers) -> None:
+    from redpanda_tpu.models.record import RecordBatch
+
+    RecordBatch.recompressed = lambda self, ctype, verify_crc=None: self
+
+
 _FAULTS = {
     "tick_frozen": _tick_frozen,
     "replica_left_out": _replica_left_out,
     "answer_altered": _answer_altered,
+    "recompress_skipped": _recompress_skipped,
 }

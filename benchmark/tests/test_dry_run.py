@@ -96,6 +96,32 @@ def test_planted_fault_is_not_correct(fault):
     assert c["value"] != c["limit"] and (failing != "acked" or c["value"] == 0)
 
 
+RECOMPRESS = ("omb_100_lz4.lz4_smoke", "--manifest",
+              os.path.join(ROOT, "benchmark", "tests", "recompress", "manifest.json"))
+
+
+def test_a_recompressing_topic_runs_from_a_manifest_and_is_correct():
+    """A topic with compression.type=lz4 through set-up, generator,
+    comparison and readers, its cell in a manifest of the test's own:
+    the device's cell-parse LZ4 (here on the CPU backend) passes the
+    reference's decoder, and the stored batches are shorter than sent."""
+    line = dry_run(*RECOMPRESS, seed=2**31 + 17)
+    _well_formed(line, 0)
+    assert line["correct"] is True and line["failed"] == 0, line["checks"]
+    assert set(line["metrics"]) == E2E
+    assert line["checks"]["dispatched.fused.crc_lz4"]["value"] >= 1
+    assert line["checks"]["fetched_wrong"] == {"value": 0, "limit": 0}
+
+
+@pytest.mark.parametrize("fault", faults.CODEC_FAULTS)
+def test_planted_codec_fault_is_not_correct(fault):
+    line = dry_run(*RECOMPRESS, seed=2**31 + 18, plant=fault)
+    assert line["correct"] is False, line["checks"]
+    assert line["checks"]["fetched_wrong"]["value"] > 0
+    assert line["checks"]["replicas_missing"]["value"] > 0
+    assert line["checks"]["acked"]["value"] > 0
+
+
 def test_no_accelerator_no_result():
     """Without --cpu-dry-run, on a machine with no TPU: exit 5, nothing
     on standard output."""

@@ -95,3 +95,107 @@ def test_open_loop_due_times_are_even_and_staggered():
     assert np.allclose(np.diff(all_due), 0.125)
     stairs = steps_of({"schedule": [[1, 4], [1, 8]]}, 99.0)
     assert len(due_times(stairs, 1, 0)) == 12
+
+
+# -- the reference's LZ4 decoder (benchmark/codecs/lz4.py) ------------
+
+import struct  # noqa: E402
+
+from benchmark.codecs import lz4  # noqa: E402
+
+
+def test_xxh32_known_vectors():
+    assert lz4.xxh32(b"") == 0x02CC5D05
+    assert lz4.xxh32(b"abc") == 0x32D153FF
+    assert lz4.xxh32(b"Nobody inspects the spammish repetition") == 0xE2293B2F
+    assert lz4.xxh32(b"", seed=1) == 0x0B2CB792
+
+
+def frame(blocks: list, flg: int = 0x60, bd: int = 0x40, size: int | None = None,
+          content: bytes | None = None, hc: int | None = None, end: bool = True) -> bytes:
+    """An LZ4 frame by hand. `blocks` are (bytes, stored) pairs; the
+    checksums follow `flg`; `hc` overrides the header checksum."""
+    desc = bytes([flg, bd]) + (struct.pack("<Q", size) if flg & 0x08 else b"")
+    out = struct.pack("<I", lz4.MAGIC) + desc
+    out += bytes([(lz4.xxh32(desc) >> 8) & 0xFF if hc is None else hc])
+    for data, stored in blocks:
+        out += struct.pack("<I", len(data) | (0x80000000 if stored else 0)) + data
+        if flg & 0x10:
+            out += struct.pack("<I", lz4.xxh32(data))
+    if end:
+        out += struct.pack("<I", 0)
+    if flg & 0x04:
+        out += struct.pack("<I", lz4.xxh32(content))
+    return out
+
+
+# "ab", then ten bytes from two back (the match overlaps what it
+# writes), then five literals: the format's own smallest shapes
+OVERLAP = bytes([0x26]) + b"ab" + struct.pack("<H", 2) + bytes([0x50]) + b"vwxyz"
+OVERLAP_OUT = b"ab" + b"ab" * 5 + b"vwxyz"
+# a literal run and a match that both need length bytes: 15 + 255 + 14
+# literals, a match of 4 + 15 + 255 + 3 from 284 back
+LONG = (bytes([0xFF, 255, 14]) + bytes(range(256)) + bytes(28) + struct.pack("<H", 284)
+        + bytes([255, 3]) + bytes([0x10]) + b"!")
+LONG_OUT = bytes(range(256)) + bytes(28) + (bytes(range(256)) + bytes(21)) + b"!"
+
+
+def test_lz4_decodes_what_the_format_allows():
+    assert lz4.decode(frame([(OVERLAP, False)])) == OVERLAP_OUT
+    assert lz4.decode(frame([(LONG, False)])) == LONG_OUT
+    assert lz4.decode(frame([])) == b""
+    assert lz4.decode(frame([(b"plain", True), (OVERLAP, False)])) == b"plain" + OVERLAP_OUT
+    both = b"plain" + OVERLAP_OUT
+    assert lz4.decode(frame([(b"plain", True), (OVERLAP, False)], flg=0x7C,
+                            size=len(both), content=both)) == both
+    # a dependent block may reach into the block before it, an
+    # independent one may not
+    reach = bytes([0x00]) + struct.pack("<H", 5) + bytes([0x10]) + b"."
+    assert lz4.decode(frame([(b"plain", True), (reach, False)], flg=0x40)) == b"plainplai."
+    assert lz4.decode(frame([(b"plain", True), (reach, False)], flg=0x60)) is None
+
+
+REFUSED = dict([
+    ("empty", b""),
+    ("magic", b"\x05" + frame([(b"x", True)])[1:]),
+    ("skippable_frame", struct.pack("<II", 0x184D2A50, 0)),
+    ("version", frame([(b"x", True)], flg=0xA0)),
+    ("reserved_flg_bit", frame([(b"x", True)], flg=0x62)),
+    ("dictionary", frame([(b"x", True)], flg=0x61)),
+    ("reserved_bd_bit", frame([(b"x", True)], bd=0x41)),
+    ("block_size_code_under_4", frame([(b"x", True)], bd=0x30)),
+    ("header_checksum", frame([(b"x", True)], hc=(lz4.xxh32(bytes([0x60, 0x40])) >> 8) + 1 & 0xFF)),
+    ("no_end_mark", frame([(b"x", True)], end=False)),
+    ("block_past_the_end", frame([(b"x", True)])[:-5] + b"\x00\x00\x00"),
+    ("block_over_bd_s_size", frame([(bytes(65537), True)])),
+    ("decoded_block_over_bd_s_size", frame([(
+        bytes([0x1F]) + b"a" + struct.pack("<H", 1) + bytes([255] * 257 + [0]) + bytes([0x10]) + b"b",
+        False)])),
+    ("literals_over_bd_s_size", frame([(
+        bytes([0x1F]) + b"a" + struct.pack("<H", 1) + bytes([255] * 256 + [235]) + bytes([0xA0]) + b"0123456789",
+        False)])),
+    ("match_length_bytes_cut", frame([(bytes([0x1F]) + b"a" + struct.pack("<H", 1) + bytes([255]), False)])),
+    ("block_checksum", frame([(b"x", True)], flg=0x70)[:-8] + b"\x00\x00\x00\x00" + bytes(4)),
+    ("content_checksum", frame([(b"x", True)], flg=0x64, content=b"y")),
+    ("content_size", frame([(b"x", True)], flg=0x68, size=2)),
+    ("trailing_byte", frame([(b"x", True)]) + b"\x00"),
+    ("offset_zero", frame([(bytes([0x10]) + b"a" + struct.pack("<H", 0) + bytes([0x10]) + b"b", False)])),
+    ("offset_past_the_start", frame([(bytes([0x10]) + b"a" + struct.pack("<H", 2) + bytes([0x10]) + b"b", False)])),
+    ("literals_past_the_block", frame([(bytes([0x50]) + b"abc", False)])),
+    ("offset_cut", frame([(bytes([0x10]) + b"a" + b"\x01", False)])),
+    ("length_bytes_cut", frame([(bytes([0xF0, 255]), False)])),
+    ("ends_on_a_match", frame([(bytes([0x10]) + b"a" + struct.pack("<H", 1), False)])),
+])
+
+
+@pytest.mark.parametrize("name", sorted(REFUSED))
+def test_lz4_refuses_what_the_format_refuses(name):
+    assert lz4.decode(REFUSED[name]) is None
+
+
+def test_lz4_reads_the_program_s_host_frame():
+    from redpanda_tpu.compression import lz4_codec
+
+    for content in (b"", b"a", bytes(3000), bytes(range(256)) * 40,
+                    ref.make_templates(3, 1, 5, 200, random_share=0.5)[0].wire):
+        assert lz4.decode(lz4_codec.compress_frame(content)) == content

@@ -42,14 +42,15 @@ def test_hand_made_trace_gives_hand_counted_numbers():
     tpl = make_templates(1, 2, 4, 128)
     ctx = {"trace": t, "peaks": {"hbm_bytes_per_s": 819e9},
            "lanes": {"capacity": 2048, "slots": 8}, "templates": tpl,
-           "fetched_in_trace": [0, 1, 1]}
+           "fetched_in_trace": [len(tpl[0].wire), len(tpl[1].wire), 300]}
     assert readers.device_idle_pct(ctx, {}) == pytest.approx(75.0)
     want = 100 * opsbytes.tick_bytes(2048, 8) / 819e9 / 2.0e-3
     assert readers.tick_roofline(ctx, {"module": "^jit_heartbeat_tick"}) == pytest.approx(want)
-    # three batches fetched in the traced seconds: their crc-covered
-    # bytes, a length and a result each, whatever the program padded
+    # three batches fetched in the traced seconds, one of them stored
+    # shorter than it was sent: their crc-covered bytes as stored, a
+    # length and a result each, whatever the program padded
     body = len(tpl[0].wire) - 21
-    want = 100 * 3 * (body + 12) / 819e9 / 0.5e-3
+    want = 100 * (2 * (body + 12) + (300 - 21 + 12)) / 819e9 / 0.5e-3
     assert readers.crc_roofline(ctx, {"module": "^jit_crc32c_device"}) == pytest.approx(want)
 
 
@@ -58,7 +59,7 @@ def test_readers_say_nothing_when_there_is_nothing_to_read():
     for ctx in ({"trace": None}, {"trace": empty, "peaks": {"hbm_bytes_per_s": 1.0},
                                   "lanes": {"capacity": 64, "slots": 8},
                                   "templates": make_templates(1, 1, 1, 64),
-                                  "fetched_in_trace": [0]}):
+                                  "fetched_in_trace": [85]}):
         assert readers.device_idle_pct(ctx, {}) is None
         assert readers.tick_roofline(ctx, {"module": "x"}) is None
         assert readers.crc_roofline(ctx, {"module": "x"}) is None
@@ -81,9 +82,9 @@ def test_bytes_from_known_shapes():
     tpl = make_templates(1, 1, 39, 1024)
     config = {"topics": [{"partitions": 1000}]}
     traffic = {"fetch_max_bytes": 131072, "consumers": 8}
-    assert opsbytes.fetch_crc_shape(config, traffic, tpl) == (512, 65536)
+    assert opsbytes.fetch_crc_shape(config, traffic, len(tpl[0].wire)) == (512, 65536)
     one = {"topics": [{"partitions": 1}]}
-    assert opsbytes.fetch_crc_shape(one, {**traffic, "consumers": 1}, tpl) == (8, 65536)
+    assert opsbytes.fetch_crc_shape(one, {**traffic, "consumers": 1}, len(tpl[0].wire)) == (8, 65536)
 
 
 @pytest.mark.skipif(
