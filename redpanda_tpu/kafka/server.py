@@ -1289,10 +1289,18 @@ class KafkaServer:
                     if recompress:
                         # recompressed() verifies the wire crc in the
                         # same pass, transcodes codec mismatches, and
-                        # no-ops when the codec already matches
-                        batch = batch.recompressed(
-                            ctype_cfg, verify_crc=batch.header.crc
-                        )
+                        # no-ops when the codec already matches; it
+                        # tags this span with the `path` it took
+                        with trace.span("produce.recompress") as sp:
+                            sent = len(batch.body)
+                            batch = batch.recompressed(
+                                ctype_cfg, verify_crc=batch.header.crc
+                            )
+                            sp.tag(
+                                codec=int(ctype_cfg),
+                                bytes_in=sent,
+                                bytes_out=len(batch.body),
+                            )
                     # order guard: the PREVIOUS batch must be cached in
                     # FIFO order before this one dispatches. Awaiting
                     # lazily (instead of after every replicate) makes

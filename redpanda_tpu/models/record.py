@@ -37,6 +37,7 @@ import numpy as np
 
 from .. import compression as compression_mod
 from ..compression import CompressionType
+from ..observability import trace
 from ..utils import crc as crc_mod
 from ..utils import native as native_mod
 from ..utils import vint
@@ -429,8 +430,13 @@ class RecordBatch:
         verify pass) AND the compressed block — the BASELINE.md
         north-star #1 'CRC32c + compress' path. Everything else runs
         the host codec registry. The device call is synchronous on the
-        event loop. The host path is the default; which one wins is
-        not measured on an attached chip."""
+        event loop. The host path is the default, and on an attached
+        chip it wins: on the TPU v5e (PERF.md section 5, PR 32) a 40 KB
+        batch takes 0.403 s here, 0.402 s of it the fused program over
+        `row_bucket`'s eight rows (52.5 ms over the one row that holds
+        the batch), and the served produce reads 418 ms against 7.8 ms
+        through the host registry. Tags the current span with the
+        `path` taken (`device` / `host`) for `produce.recompress`."""
         import os
 
         if self.header.compression == ctype:
@@ -472,6 +478,7 @@ class RecordBatch:
             from ..compression import lz4_codec
             from ..ops.fused import crc_lz4_fused
 
+            trace.tag_current(path="device")
             crcs, blocks = crc_lz4_fused(
                 [self.header.crc_prefix()], [body]
             )
@@ -484,6 +491,7 @@ class RecordBatch:
                 )
             frame = lz4_codec.frame_from_blocks([blocks[0]], [body])
         else:
+            trace.tag_current(path="host")
             if verify_crc is not None and self.compute_crc() != (
                 verify_crc & 0xFFFFFFFF
             ):

@@ -53,10 +53,13 @@ def _fused(data: jax.Array, body_len: jax.Array, n: int):
     # the row (its [rows * chunks, 512] reshape) into the lz4 slice's
     # consumers. Measured when the CRC was a scan over chunks, that
     # fusion ran the combined program ~1000x slower (8.5 s vs ~1 ms for
-    # this shape); not timed again on the chip since the CRC takes all
-    # chunks at once, so the barrier stays: it materializes the body
-    # slice once, then both kernels run at their standalone speeds off
-    # the single upload.
+    # this shape). Timed again on the TPU v5e at PR 32, [8, 66048],
+    # since the CRC takes all chunks at once: 401.8 ms with the
+    # barrier, 399.8 without, 399.7 for the LZ4 alone, same results
+    # (PERF.md section 5): it no longer decides anything, and stays
+    # until a change to this program's speed takes it out with a
+    # measurement. It materializes the body slice once; both kernels
+    # then run at their standalone speeds off the single upload.
     body = jax.lax.optimization_barrier(
         data[:, PREFIX : PREFIX + n + CELL]
     )

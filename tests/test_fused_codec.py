@@ -231,3 +231,121 @@ def test_uncompressed_config_forces_decompression(tmp_path):
                 assert [(k, v) for _o, k, v in got] == recs
 
     asyncio.run(main())
+
+
+# -- the served path against the benchmark's plain reference (ISSUE 32) ---
+REF_SEED = 2**31 + 32
+REF_RECORDS, REF_RECORD_BYTES = 5, 1024
+
+
+def _reference_templates(codec_topic: bool):
+    """Seeded `compressible.random_share` batches of a few 1 KB records,
+    half of every value random: `RewrittenTemplate`s where the topic
+    names lz4, plain ones (a record shorter, so that no byte count of
+    theirs is one of the others') where it passes batches through."""
+    from benchmark.templates import compressible
+
+    configs = {"compression.type": "lz4"} if codec_topic else {}
+    records = REF_RECORDS if codec_topic else REF_RECORDS - 1
+    return compressible.random_share(
+        REF_SEED,
+        {"templates": {"count": 2, "random_share": 0.5}, "batch_records": records},
+        {"topics": [{"name": "t", "configs": configs}], "record_bytes": REF_RECORD_BYTES},
+    )
+
+
+async def _serve_reference_batches(tmp_path, rewritten, plain):
+    """Three brokers, an lz4 topic and a pass-through one at RF=3: what
+    a fetch returns of the first, each replica's copy of it, and the
+    base offsets the acks gave."""
+    from redpanda_tpu.models.fundamental import kafka_ntp
+    from benchmark import reference as ref
+
+    async with broker_cluster(tmp_path, 3) as brokers:
+        async with client_for(brokers) as client:
+            await client.create_topic(
+                "codec", partitions=1, replication_factor=3,
+                configs={"compression.type": "lz4"})
+            await client.create_topic("plain", partitions=1, replication_factor=3)
+            bases = [await client.produce_wire("codec", 0, t.wire, acks=-1)
+                     for t in rewritten]
+            await client.produce_wire("plain", 0, plain[0].wire, acks=-1)
+            wire, _next = await client.fetch_raw("codec", 0, 0)
+            end = bases[-1] + REF_RECORDS
+            copies = []
+            for b in brokers:
+                part = b.partition_manager.get(kafka_ntp("codec", 0))
+                assert part is not None, b.node_id
+                for _ in range(200):
+                    if part.high_watermark() >= end:
+                        break
+                    await asyncio.sleep(0.05)
+                copies.append([
+                    batch.to_kafka_wire()
+                    for _base, batch in part.read_kafka(0, 1 << 20, upto_kafka=end)
+                ])
+    return bases, ref.split_batches(wire), copies
+
+
+@pytest.mark.parametrize("path", ["device", "host"])
+def test_recompressing_topic_agrees_with_the_plain_reference(tmp_path, monkeypatch, path):
+    """The program against benchmark/reference.py at a small size: what
+    an lz4 topic stores of a plain batch is, by the reference's own
+    decoder (benchmark/codecs/lz4.py, no liblz4), the batch that was
+    sent; every replica holds the leader's bytes; and every rewritten
+    batch left one `produce.recompress` span that says what it did."""
+    from benchmark import reference as ref
+    from redpanda_tpu.observability import trace
+
+    if path == "device":
+        monkeypatch.setenv("RP_CODEC_BACKEND", "device")
+    else:
+        monkeypatch.delenv("RP_CODEC_BACKEND", raising=False)
+    monkeypatch.setattr(trace, "ENABLED", True)
+    monkeypatch.setattr(trace.WINDOW, "keep_raw", True)
+    trace.WINDOW.reset()
+    rewritten, plain = _reference_templates(True), _reference_templates(False)
+    assert all(isinstance(t, ref.RewrittenTemplate) for t in rewritten)
+    # compile the fused program before anything ticks, as the benchmark's
+    # warmer does: a compile on the loop outlasts the election timeout
+    RecordBatch.from_kafka_wire(rewritten[0].wire).recompressed(CompressionType.lz4)
+    trace.WINDOW.reset()
+    try:
+        bases, fetched, copies = asyncio.run(
+            _serve_reference_batches(tmp_path, rewritten, plain))
+        spans = trace.WINDOW.status()["spans"]
+    finally:
+        trace.WINDOW.reset()
+
+    assert [base for base, _b in fetched] == bases
+    for (_base, stored), t in zip(fetched, rewritten):
+        assert t.came_back(stored) and t.key_of(stored) == t.key
+        assert len(stored) < len(t.wire)
+        assert not any(o.came_back(stored) for o in rewritten if o is not t)
+        # one flipped stored byte, in the records section or the header
+        for at in (len(stored) - 9, ref.RECORDS_AT + 11, ref.AFTER_ATTRIBUTES + 1):
+            flipped = bytearray(stored)
+            flipped[at] ^= 0x01
+            assert not t.came_back(bytes(flipped)), at
+        # what was sent is no answer on this topic
+        assert not t.came_back(t.wire)
+    # every replica holds the leader's stored bytes, from the crc field on
+    assert len(copies) == 3
+    for held in copies:
+        assert [w[ref.CRC_AT:] for w in held] == [b[ref.CRC_AT:] for _o, b in fetched]
+
+    by_id = {s[4]: s for s in spans}
+    recompress = [s for s in spans if s[0] == "produce.recompress"]
+    said = [
+        {"path": path, "codec": int(CompressionType.lz4),
+         "bytes_in": len(t.wire) - ref.RECORDS_AT,
+         "bytes_out": len(stored) - ref.RECORDS_AT}
+        for t, (_base, stored) in zip(rewritten, fetched)
+    ]
+    # one a batch the broker rewrote (a produce that was retried on a
+    # lost leadership is rewritten again), none for the plain topic
+    assert len(recompress) >= len(rewritten)
+    assert all(s[7] in said for s in recompress), [s[7] for s in recompress]
+    assert all(tags in [s[7] for s in recompress] for tags in said)
+    for s in recompress:
+        assert s[1] == "run" and by_id[s[5]][0] == "produce.dispatch"
