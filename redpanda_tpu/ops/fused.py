@@ -1,13 +1,17 @@
-"""Fused device CRC32C + LZ4 over record-batch bodies: ONE upload.
+"""Fused device CRC32C + LZ4 over record-batch bodies: one dispatch.
 
-The round-2 lesson (BENCH_r02): each kernel alone wins device-resident
-but loses end-to-end because the host->device copy dominates. Fusing
-validation and compression into one program amortizes that single
-upload across BOTH ops — the host must otherwise run two full passes
-(crc ~8 GB/s native + lz4 ~1.6 GB/s liblz4), so the combined host
-throughput is ~1.3 GB/s while the fused device path pays one transfer.
+One program takes a batch's row up once and gives back both what the
+broker needs of it: the Kafka CRC of prefix||body (validated against the
+wire's, in place of the host's verify pass) and the body as a standard
+LZ4 block. One upload, one launch, one readback: a crossing to the
+device costs about half a millisecond whatever it carries, and on the
+TPU v5e they are most of a call (PERF.md section 5, PR 33: of 2.3 ms
+for a 40 KB batch at [1, 66048] the program is 1.0 on the device; the
+host's crc and liblz4 do the same work in 0.08 ms, which is why the
+host path is the default and this one a switch).
 
-Row layout ([B, PREFIX + n + CELL] uint8, zero-padded):
+Row layout ([B, PREFIX + n + CELL] uint8, zero-padded; B is the bodies
+of the call to the next power of two):
 
     [ crc_prefix (40 B) | records body (n bucket) | CELL guard ]
 
@@ -25,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..observability import devplane
+from ..observability import devplane, trace
 from ..utils import compileguard
 from .crc32c import crc32c_device
 from .cellparse import CELL
@@ -39,32 +43,33 @@ from .zstd import _encode_one as _zstd_encode_one
 PREFIX = 40  # models/record.py _CRC_PREFIX packed size
 
 
+def _one_readback(crc: jax.Array, out: jax.Array, out_len: jax.Array):
+    """[B, m + 8] uint8: each row's block, then its length and its CRC
+    as 4 little-endian bytes each. Every crossing to the device costs
+    0.4-0.5 ms whatever it carries (PERF.md section 5), so the three
+    results come back as one array."""
+    tail = jnp.stack([out_len.astype(jnp.uint32), crc], axis=1)  # [B, 2]
+    shifts = jnp.arange(0, 32, 8, dtype=jnp.uint32)
+    tail_bytes = ((tail[:, :, None] >> shifts) & 255).astype(jnp.uint8)
+    return jnp.concatenate([out, tail_bytes.reshape(out.shape[0], 8)], axis=1)
+
+
 @functools.partial(jax.jit, static_argnums=(2,))
 def _fused(data: jax.Array, body_len: jax.Array, n: int):
     """data [B, PREFIX + n + CELL] uint8; body_len int32[B].
-    Returns (crc uint32[B] over prefix||body, lz4 blocks + lengths)."""
+    Returns the lz4 blocks, their lengths and the crc over prefix||body
+    of every row as `_one_readback` lays them out."""
     # CRC slice: width PREFIX+n rounded up to the 512-byte CRC chunk —
     # the matrix is allocated with that slack, zero-padded
     crc_w = ((PREFIX + n + 511) // 512) * 512
     crc = crc32c_device(
         data[:, :crc_w], (body_len + PREFIX).astype(jnp.int64)
     )
-    # barrier: without it XLA is free to fuse the crc path's view of
-    # the row (its [rows * chunks, 512] reshape) into the lz4 slice's
-    # consumers. Measured when the CRC was a scan over chunks, that
-    # fusion ran the combined program ~1000x slower (8.5 s vs ~1 ms for
-    # this shape). Timed again on the TPU v5e at PR 32, [8, 66048],
-    # since the CRC takes all chunks at once: 401.8 ms with the
-    # barrier, 399.8 without, 399.7 for the LZ4 alone, same results
-    # (PERF.md section 5): it no longer decides anything, and stays
-    # until a change to this program's speed takes it out with a
-    # measurement. It materializes the body slice once; both kernels
-    # then run at their standalone speeds off the single upload.
-    body = jax.lax.optimization_barrier(
-        data[:, PREFIX : PREFIX + n + CELL]
-    )
-    out, out_len = _compress_chunks(body, body_len, n)
-    return crc, out, out_len
+    # no optimization_barrier between the two: with the CRC taking all
+    # chunks at once it decides nothing (TPU v5e, PERF.md section 5:
+    # 2.900 ms with it, 2.865 without, same device time, same blocks)
+    body = data[:, PREFIX : PREFIX + n + CELL]
+    return _one_readback(crc, *_compress_chunks(body, body_len, n))
 
 
 _fused = devplane.instrument(
@@ -79,11 +84,8 @@ def _fused_snappy(data: jax.Array, body_len: jax.Array, n: int):
     crc = crc32c_device(
         data[:, :crc_w], (body_len + PREFIX).astype(jnp.int64)
     )
-    body = jax.lax.optimization_barrier(
-        data[:, PREFIX : PREFIX + n + CELL]
-    )
-    out, out_len = _snappy_chunks(body, body_len, n)
-    return crc, out, out_len
+    body = data[:, PREFIX : PREFIX + n + CELL]
+    return _one_readback(crc, *_snappy_chunks(body, body_len, n))
 
 
 _fused_snappy = devplane.instrument(
@@ -94,9 +96,11 @@ _fused_snappy = devplane.instrument(
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _fused_zstd(data: jax.Array, body_len: jax.Array, n: int):
-    """Same layout/barrier recipe as _fused, zstd entropy stage instead
-    of LZ4 (different output shape: code lengths + 4 huff0 streams per
-    row; frame scaffolding is host work)."""
+    """Same layout as _fused, zstd entropy stage instead of LZ4
+    (different output shape: code lengths + 4 huff0 streams per row;
+    frame scaffolding is host work). Its optimization_barrier dates
+    from the CRC that was a scan over chunks and was never timed since
+    (no cell runs this program)."""
     crc_w = ((PREFIX + n + 511) // 512) * 512
     crc = crc32c_device(
         data[:, :crc_w], (body_len + PREFIX).astype(jnp.int64)
@@ -207,7 +211,11 @@ def _fused_entry(prefixes, bodies, kernel, bound_fn, preamble_fn):
         n *= 2
     crc_w = ((PREFIX + n + 511) // 512) * 512
     width = max(PREFIX + n + CELL, crc_w)
-    rows = row_bucket(len(arrs))
+    # the rows the call holds, to the next power of two: the program's
+    # time is its row count's (PERF.md section 5), and the served caller
+    # (RecordBatch.recompressed) holds one body. The fetch verify keeps
+    # row_bucket's default floor; its shapes are the benchmark's to move
+    rows = row_bucket(len(arrs), floor=1)
     batch = np.zeros((rows, width), np.uint8)
     body_len = np.zeros(rows, np.int32)
     for i, (p, a) in enumerate(zip(prefixes, arrs)):
@@ -215,16 +223,17 @@ def _fused_entry(prefixes, bodies, kernel, bound_fn, preamble_fn):
         batch[i, :PREFIX] = np.frombuffer(p, np.uint8)
         batch[i, PREFIX : PREFIX + a.size] = a
         body_len[i] = a.size
+    # which program ran, on the span the caller has open (the served
+    # path's `produce.recompress`)
+    trace.tag_current(rows=rows, n=n)
     devplane.count_transfer(batch.nbytes + body_len.nbytes, "h2d")
-    crc, out, out_len = kernel(
-        jnp.asarray(batch), jnp.asarray(body_len), n
-    )
-    crc = np.asarray(crc)[: len(arrs)]
-    out = np.asarray(out)
-    out_len = np.asarray(out_len)
-    devplane.count_transfer(
-        crc.nbytes + out.nbytes + out_len.nbytes, "d2h"
-    )
+    # the dispatch takes both numpy arrays up with it (a jnp.asarray
+    # each is a crossing of its own), and one array comes back
+    got = np.asarray(kernel(batch, body_len, n))
+    devplane.count_transfer(got.nbytes, "d2h")
+    out = got[:, :-8]
+    tail = np.ascontiguousarray(got[: len(arrs), -8:]).view("<u4")
+    out_len, crc = tail[:, 0].astype(np.int64), tail[:, 1]
     assert int(out_len.max()) <= bound_fn(n)
     blocks = []
     for i in range(len(arrs)):

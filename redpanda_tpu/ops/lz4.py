@@ -12,21 +12,31 @@ transliteration. Instead the parse is re-shaped into fixed C-byte
 "cells" with one decision per cell — everything becomes dense
 vector/matrix work over [N]-shaped tensors:
 
-  1. match discovery: hash every 4-gram, sort (hash, pos) keys, and
-     read each position's predecessor in sort order — the most recent
-     earlier occurrence of the same gram (a vectorized exact hash
-     chain of depth 1).
-  2. verification: gather both 32-byte windows and compare — a match
-     is kept only if it runs from its in-cell start to the cell end,
-     so every cell emits AT MOST ONE sequence: (literals | match to
-     cell end). Cells without a match contribute their bytes to the
-     next sequence's literal run (an exclusive cummax gives each
-     sequence its literal-run start without any sequential pass).
+  1. match discovery: hash every 4-gram, sort (hash, pos) keys; the
+     position k places back in sort order with the same hash is the
+     k-th most recent earlier occurrence of the same gram (an exact
+     hash chain, walked 3 deep).
+  2. verification: the sort carries each position's 16 bytes along as
+     four words, so a candidate's bytes are a shift in sort order, and
+     a masked word compare keeps a match only if it runs from its
+     in-cell start to the cell end, so every cell emits AT MOST ONE
+     sequence: (literals | match to cell end). Cells without a match
+     contribute their bytes to the next sequence's literal run (an
+     exclusive cummax gives each sequence its literal-run start
+     without any sequential pass).
   3. emission: per-cell sequence sizes (token + extended literal
      lengths + literals + offset + extended match length) prefix-sum
-     into output positions; each output byte then computes its
-     (sequence, role) via searchsorted and gathers its value. The
-     byte-granular "copy" is one big gather from the input.
+     into output positions; each output byte then finds its sequence
+     by a count (ones scattered at the sequences' starts, prefix-
+     summed), fetches what it needs of it as one gathered row, and
+     computes its value. The byte-granular "copy" is one gather of
+     the input; the last literal run is a slice.
+
+On the TPU a gather or scatter of single elements is what costs (8.6 ns
+an element on the v5e, where a sort of 65,536 keys with four payload
+words takes 0.1 ms and a prefix sum of 87,109 takes 4 us: PERF.md
+section 5), so steps 1-3 are written to sort, shift and scan, and to
+gather only rows (ops/cellparse.py).
 
 The resulting blocks trade ratio for parallelism (matches cannot cross
 cell boundaries) but are bit-valid LZ4; ratio on redpanda-like payloads
@@ -46,7 +56,7 @@ import numpy as np
 
 from ..observability import devplane
 from ..utils import compileguard
-from .cellparse import CELL, cell_parse
+from .cellparse import CELL, cell_of_output, cell_parse, take_rows
 from .shapes import row_bucket
 
 
@@ -60,7 +70,6 @@ def out_bound(n: int) -> int:
 def _compress_chunks(data: jax.Array, valid: jax.Array, n: int):
     """data: uint8[B, n + CELL] (zero-padded), valid: int32[B].
     Returns (out: uint8[B, out_bound(n)], out_len: int32[B])."""
-    nc = n // CELL
     m = out_bound(n)
 
     def one(d: jax.Array, v: jax.Array):
@@ -90,24 +99,23 @@ def _compress_chunks(data: jax.Array, valid: jax.Array, n: int):
         out_len = total + f_size
 
         # ---- emission: every output byte finds its (cell, role) ----
+        # what a byte needs of its cell comes as one gathered row, and
+        # the extra-length count is computed again, not fetched
+        # (cellparse.take_rows: a gather costs the chip by the index)
         o = jnp.arange(m, dtype=jnp.int32)
-        s = jnp.clip(
-            jnp.searchsorted(starts, o, side="right").astype(jnp.int32) - 1,
-            0,
-            nc - 1,
+        start_s, lit_len_s, lit_start_s, mlen_s, offs_s = take_rows(
+            [starts, lit_len, lit_start, mlen, offs], cell_of_output(starts, m)
         )
-        r = o - starts[s]
-        lit_len_s = lit_len[s]
-        nk_s = nk[s]
-        mlen_s = mlen[s]
+        r = o - start_s
         token = (
             (jnp.minimum(lit_len_s, 15) << 4)
             | jnp.minimum(jnp.maximum(mlen_s - 4, 0), 15)
         )
-        a1 = 1 + nk_s
+        a1 = 1 + n_extra(lit_len_s)
         a2 = a1 + lit_len_s
-        lit_byte = d[jnp.clip(lit_start[s] + (r - a1), 0, n - 1)]
-        offs_s = offs[s]
+        (lit_byte,) = take_rows(
+            [d[:n]], jnp.clip(lit_start_s + (r - a1), 0, n - 1)
+        )
         val = jnp.where(
             r == 0,
             token,
@@ -130,10 +138,14 @@ def _compress_chunks(data: jax.Array, valid: jax.Array, n: int):
             ),
         )
 
+        # the last literals are one run of the input, f_a1 bytes on from
+        # `total`: a slice at a computed offset, not a gather
         fo = o - total
         f_token = jnp.minimum(f_lit_len, 15) << 4
         f_a1 = 1 + f_nk
-        f_lit_byte = d[jnp.clip(f_lit_start + fo - f_a1, 0, n - 1)]
+        f_lit_byte = jax.lax.dynamic_slice(
+            jnp.pad(d, (m, m)), (m + f_lit_start - total - f_a1,), (m,)
+        )
         f_val = jnp.where(
             fo == 0,
             f_token,

@@ -32,7 +32,7 @@ import numpy as np
 
 from ..observability import devplane
 from ..utils import compileguard
-from .cellparse import CELL, cell_parse
+from .cellparse import CELL, cell_of_output, cell_parse, take_rows
 from .shapes import row_bucket
 
 
@@ -54,7 +54,6 @@ def _compress_chunks(data: jax.Array, valid: jax.Array, n: int):
     """data: uint8[B, n + CELL] (zero-padded), valid: int32[B].
     Returns (out: uint8[B, out_bound(n)] WITHOUT the length preamble,
     out_len: int32[B])."""
-    nc = n // CELL
     m = out_bound(n)
 
     def one(d: jax.Array, v: jax.Array):
@@ -77,7 +76,7 @@ def _compress_chunks(data: jax.Array, valid: jax.Array, n: int):
         f_size = jnp.where(f_lit_len > 0, 1 + f_ex + f_lit_len, 0)
         out_len = total + f_size
 
-        def lit_byte_val(length, ex, start, r):
+        def lit_byte_val(length, ex, data_b, r):
             # r == 0 → tag; r-1 < ex → length byte i; else literal data
             tag = jnp.where(
                 ex == 0,
@@ -85,26 +84,30 @@ def _compress_chunks(data: jax.Array, valid: jax.Array, n: int):
                 jnp.where(ex == 1, 60 << 2, 61 << 2),
             )
             len_b = ((length - 1) >> (8 * jnp.maximum(r - 1, 0))) & 255
-            data_b = d[jnp.clip(start + r - 1 - ex, 0, n - 1)]
             return jnp.where(
                 r == 0, tag, jnp.where(r - 1 < ex, len_b, data_b)
             )
 
         # ---- emission: every output byte finds its (cell, role) ----
+        # what a byte needs of its cell comes as one gathered row, and
+        # the literal header's size is computed again, not fetched
+        # (cellparse.take_rows: a gather costs the chip by the index)
         o = jnp.arange(m, dtype=jnp.int32)
-        s = jnp.clip(
-            jnp.searchsorted(starts, o, side="right").astype(jnp.int32) - 1,
-            0,
-            nc - 1,
+        start_s, lit_len_s, lit_start_s, mlen_s, off_s = take_rows(
+            [starts, lit_len, lit_start, mlen, offs], cell_of_output(starts, m)
         )
-        r = o - starts[s]
-        in_lit = r < litsz[s]
-        lit_v = lit_byte_val(lit_len[s], lit_ex[s], lit_start[s], r)
-        c = r - litsz[s]
+        r = o - start_s
+        lit_ex_s = _lit_extra(lit_len_s)
+        litsz_s = jnp.where(lit_len_s > 0, 1 + lit_ex_s + lit_len_s, 0)
+        in_lit = r < litsz_s
+        (lit_data,) = take_rows(
+            [d[:n]], jnp.clip(lit_start_s + r - 1 - lit_ex_s, 0, n - 1)
+        )
+        lit_v = lit_byte_val(lit_len_s, lit_ex_s, lit_data, r)
+        c = r - litsz_s
         ci = c // 3
         role = c % 3
-        clen = jnp.clip(mlen[s] - 64 * ci, 1, 64)
-        off_s = offs[s]
+        clen = jnp.clip(mlen_s - 64 * ci, 1, 64)
         copy_v = jnp.where(
             role == 0,
             2 | ((clen - 1) << 2),
@@ -112,8 +115,13 @@ def _compress_chunks(data: jax.Array, valid: jax.Array, n: int):
         )
         val = jnp.where(in_lit, lit_v, copy_v)
 
+        # the last literals are one run of the input: a slice at a
+        # computed offset, not a gather
         fo = o - total
-        f_val = lit_byte_val(f_lit_len, f_ex, f_lit_start, fo)
+        f_data = jax.lax.dynamic_slice(
+            jnp.pad(d, (m, m)), (m + f_lit_start - total - 1 - f_ex,), (m,)
+        )
+        f_val = lit_byte_val(f_lit_len, f_ex, f_data, fo)
 
         out = jnp.where(
             o < total, val, jnp.where(o < out_len, f_val, 0)

@@ -56,6 +56,64 @@ def test_fused_frame_assembly_interops_with_frame_decoder():
         assert lz4_codec.decompress_frame(frame) == body
 
 
+@pytest.mark.parametrize("bodies,rows", [(1, 1), (2, 2), (3, 4), (9, 16)])
+def test_fused_dispatches_the_rows_the_call_holds(monkeypatch, bodies, rows):
+    """A dispatch has the rows of its call, to the next power of two (the
+    program's device time is its row count's: PERF.md section 5), one
+    compile a bucket, and every body's CRC and block are those of a call
+    with that body alone."""
+    from redpanda_tpu.ops import fused
+    from redpanda_tpu.utils import compileguard
+
+    rng = np.random.default_rng(33 + bodies)
+    payloads = _payloads(rng, bodies, max_len=500)
+    prefixes = [bytes(rng.integers(0, 256, 40, np.uint8)) for _ in payloads]
+    shapes = []
+    kernel = fused._fused
+
+    def recording(data, body_len, n):
+        shapes.append((data.shape, body_len.shape, n))
+        return kernel(data, body_len, n)
+
+    monkeypatch.setattr(fused, "_fused", recording)
+    crcs, blocks = crc_lz4_fused(prefixes, payloads)
+    assert shapes == [((rows, 1024), (rows,), 512)]
+    compiled = compileguard.compile_counts()["fused.crc_lz4"]
+    again_crcs, again_blocks = crc_lz4_fused(prefixes, payloads)
+    assert compileguard.compile_counts()["fused.crc_lz4"] == compiled
+    assert list(again_crcs) == list(crcs) and again_blocks == blocks
+    for p, b, c, blk in zip(prefixes, payloads, crcs, blocks):
+        alone_crc, alone_blk = crc_lz4_fused([p], [b])
+        assert shapes[-1] == ((1, 1024), (1,), 512)
+        assert int(alone_crc[0]) == int(c) == host_crc.crc32c(b, host_crc.crc32c(p))
+        assert alone_blk == [blk]
+    # one program a row bucket and no more, however many calls
+    assert compileguard.compile_counts()["fused.crc_lz4"] <= compiled + 1
+
+
+def test_fetch_verify_keeps_its_row_floor(monkeypatch):
+    """`row_bucket`'s default floor, which the fetch verify's
+    `crc32c_batch_device` stages by and the benchmark's CRC warmer pins
+    (`opsbytes.ROW_FLOOR`), is still 8."""
+    from benchmark import opsbytes
+    from redpanda_tpu.ops import crc32c, shapes
+
+    assert shapes.row_bucket(1) == opsbytes.ROW_FLOOR == 8
+    assert shapes.row_bucket(1, floor=1) == 1
+    seen = []
+    kernel = crc32c.crc32c_device
+
+    def recording(data, lens):
+        seen.append((data.shape, lens.shape))
+        return kernel(data, lens)
+
+    monkeypatch.setattr(crc32c, "crc32c_device", recording)
+    body = np.arange(700, dtype=np.uint8)[None, :]
+    got = crc32c.crc32c_batch_device(body, np.array([700]))
+    assert seen == [((8, 1024 // 4), (8,))]
+    assert [int(c) for c in got] == [host_crc.crc32c(body[0].tobytes())]
+
+
 def test_recompressed_batch_device_and_host_agree(monkeypatch):
     b = RecordBatchBuilder(base_offset=7)
     for i in range(50):
@@ -336,10 +394,13 @@ def test_recompressing_topic_agrees_with_the_plain_reference(tmp_path, monkeypat
 
     by_id = {s[4]: s for s in spans}
     recompress = [s for s in spans if s[0] == "produce.recompress"]
+    # the device path also says which program it dispatched: the one row
+    # that holds the batch, at the width bucket of 5 records of 1 KB
+    shape = {"rows": 1, "n": 8192} if path == "device" else {}
     said = [
         {"path": path, "codec": int(CompressionType.lz4),
          "bytes_in": len(t.wire) - ref.RECORDS_AT,
-         "bytes_out": len(stored) - ref.RECORDS_AT}
+         "bytes_out": len(stored) - ref.RECORDS_AT, **shape}
         for t, (_base, stored) in zip(rewritten, fetched)
     ]
     # one a batch the broker rewrote (a produce that was retried on a
