@@ -98,7 +98,8 @@ async def run(spec: dict, say) -> dict:
     fetched: dict[tuple[str, int, int], tuple[float, int, int]] = {}
     fetch_errors: list[str] = []
     fetches = requests = 0
-    late: list[float] = []
+    late: list[float] = []    # first send after the batch closed: the sender's queue too
+    handed: list[float] = []  # handed to the sender after it closed: the generator's own
     # acknowledged base offsets no fetch has returned yet, by partition:
     # acks of one partition reach different producers in any order
     want: dict[tuple[str, int], set] = {}
@@ -216,6 +217,7 @@ async def run(spec: dict, say) -> dict:
             wait = t_due + linger - time.monotonic()
             if wait > 0:
                 await asyncio.sleep(wait)
+            handed.append(time.monotonic() - (t_due + linger))
             tp = work[order[k]]
             row = [tp[0], tp[1], (k + i) % len(tpl), -1, float(t_due), 0.0,
                    None, 0.0, 0, 0]
@@ -341,7 +343,9 @@ async def run(spec: dict, say) -> dict:
     say(f"window_start {t0!r}")
     cons = [asyncio.ensure_future(consumer(j)) for j in range(n_cons)]
     prods = [asyncio.ensure_future(producer(i)) for i in range(n_prod)]
+    cpu0 = time.process_time()
     await asyncio.sleep(max(0.0, t0 + seconds - time.monotonic()))
+    cpu_share = (time.process_time() - cpu0) / seconds
     say(f"window_end {time.monotonic()!r}")
     # what is in flight is waited for: late is late, not wrong
     _done, unsent = await asyncio.wait(prods, timeout=drain)
@@ -377,6 +381,10 @@ async def run(spec: dict, say) -> dict:
         "fetch_errors": fetch_errors[:50],
         "fetch_error_count": len(fetch_errors),
         "late_s": sorted(late),
+        "handed_late_s": sorted(handed),
+        # this process's CPU seconds over the window's: at 1 the generator,
+        # one thread, is what the window measured
+        "generator_cpu_share": cpu_share,
         "payload_bytes": tpl[0].payload_bytes,
         "clients": {"producers": n_prod, "consumers": n_cons,
                     "max_in_flight": max_in_flight},

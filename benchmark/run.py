@@ -77,28 +77,49 @@ def load_traffic(path: str) -> dict:
     return traffic
 
 
+def laid_over(manifest: dict, own: dict) -> dict:
+    """A manifest of the builder's own over BENCHMARK.json: a list of
+    entries is merged by `name` (an entry takes the place of the one of
+    its name, a new one is appended), any other key is replaced. So a
+    manifest that holds a cell, the end-to-end entries that name their
+    cells for it and its per-layer entries is what a later PR adds to
+    BENCHMARK.json, as it stands (benchmark/queued/)."""
+    out = {**manifest}
+    for key, value in own.items():
+        if isinstance(value, list) and isinstance(manifest.get(key), list) \
+                and all(isinstance(e, dict) and "name" in e for e in value):
+            names = {e["name"] for e in value}
+            out[key] = [e for e in manifest[key] if e["name"] not in names] + value
+        else:
+            out[key] = value
+    return out
+
+
 def load_cell(
     workload: str, traffic_file: str | None = None, manifest_file: str | None = None
 ) -> dict:
     """The cell's entry with its configuration, traffic and per-layer
     metric files, all found by the names in BENCHMARK.json. With a
-    manifest of the builder's own (`--manifest`), its keys are laid
-    over BENCHMARK.json's (`workloads` at the least), and the cell's
-    configuration and traffic files are the ones beside it."""
+    manifest of the builder's own (`--manifest`), its entries are laid
+    over BENCHMARK.json's by name (`laid_over`), and the cell's
+    configuration and traffic files are the ones beside it, or the
+    benchmark's own where none is."""
     manifest = load_json(ROOT, "BENCHMARK.json")
-    configs = os.path.join(HERE, "configs")
-    traffics = os.path.join(HERE, "traffic")
+    beside = None
     if manifest_file:
-        manifest = {**manifest, **load_json(manifest_file)}
-        configs = traffics = os.path.dirname(os.path.abspath(manifest_file))
+        manifest = laid_over(manifest, load_json(manifest_file))
+        beside = os.path.dirname(os.path.abspath(manifest_file))
+
+    def find(kind: str, name: str) -> str:
+        own = beside and os.path.join(beside, name + ".json")
+        return own if own and os.path.exists(own) else os.path.join(HERE, kind, name + ".json")
+
     cell = next((w for w in manifest["workloads"] if w["name"] == workload), None)
     if cell is None:
         raise KeyError(
             f"no workload {workload!r} in {manifest_file or 'BENCHMARK.json'}")
-    config = load_json(configs, cell["config"] + ".json")
-    traffic = load_traffic(
-        traffic_file or os.path.join(traffics, cell["traffic"] + ".json")
-    )
+    config = load_json(find("configs", cell["config"]))
+    traffic = load_traffic(traffic_file or find("traffic", cell["traffic"]))
 
     def reports(m: dict) -> bool:
         return workload in m["workloads"] if "workloads" in m else True
@@ -192,7 +213,7 @@ def reduce_records(rec: dict, drain_s: float) -> dict:
     missed_ms = drain_s * 1e3
     produce_ms, e2e_ms = [], []
     acked_bytes = 0
-    acked = failed = 0
+    acked = failed = in_window = in_requests = fetched_in_window = 0
     for row in rec["rows"]:
         ok = row[BASE] >= 0 and row[ERR] is None
         produce_ms.append((row[T_ACK] - row[T_DUE]) * 1e3 if ok else missed_ms)
@@ -200,10 +221,13 @@ def reduce_records(rec: dict, drain_s: float) -> dict:
             failed += 1
             continue
         acked += 1
+        in_requests += row[IN_REQUEST]
         if row[T_ACK] <= t1:
+            in_window += 1
             acked_bytes += rec["payload_bytes"]
         if row[GOT] == row[TPL]:
             e2e_ms.append((row[T_FETCH] - row[T_DUE]) * 1e3)
+            fetched_in_window += row[T_FETCH] <= t1
         else:
             e2e_ms.append(missed_ms)
             failed += 1
@@ -213,10 +237,33 @@ def reduce_records(rec: dict, drain_s: float) -> dict:
         "acked": acked,
         "acked_payload_bytes": acked_bytes,
         "metrics": {},
+        # where the window stood against what was offered: a cell under
+        # its knee reads a share of 1 less what was in flight at the
+        # close, a cell over saturation the share it completes
+        "load": {
+            "offered_batches_per_s": round(len(rec["rows"]) / seconds, 3),
+            "acked_share_in_window": round(in_window / max(1, len(rec["rows"])), 4),
+            "due_not_acked_at_close": len(rec["rows"]) - in_window,
+            "batches_a_request": round(in_requests / max(1, acked), 3),
+            # what the consumers had in their hands at the close: over
+            # saturation they trail the producers, and a change that
+            # starves fetches would read as a gain in acks alone
+            "fetched_mb_s_in_window": round(
+                fetched_in_window * rec["payload_bytes"] / 1e6 / seconds, 4),
+            "fetched_share_of_acked_in_window": round(
+                fetched_in_window / max(1, in_window), 4),
+        },
     }
     if acked:
+        mb_s = acked_bytes / 1e6 / seconds
         out["metrics"] = {
-            "produce_mb_s": acked_bytes / 1e6 / seconds,
+            # one reading under two names: `produce_mb_s` where the cell
+            # offers less than the system completes and the rate comes
+            # back as offered, `sustained_mb_s` where it offers more and
+            # the rate is what the system completed (the manifest says
+            # which a cell reports)
+            "produce_mb_s": mb_s,
+            "sustained_mb_s": mb_s,
             "produce_p50_ms": percentile(produce_ms, 0.5),
             "e2e_p50_ms": percentile(e2e_ms, 0.5),
         }
@@ -376,6 +423,7 @@ async def run_cell(args, loaded: dict, device: dict) -> dict:
                 window["flush"].on_ack(*json.loads(rest))
             elif word == "window_start":
                 devplane.reset()
+                window["reset_at"] = time.monotonic()
                 window["t0"] = float(rest)
                 window["elections0"] = cluster.elections(brokers)
                 if args.trace:
@@ -383,6 +431,8 @@ async def run_cell(args, loaded: dict, device: dict) -> dict:
                         traced(window["t0"], args.seconds))
             elif word == "window_end":
                 window["devplane"] = devplane.status()
+                # the seconds the span store and the counters cover
+                window["devplane_s"] = time.monotonic() - window["reset_at"]
                 window["elections1"] = cluster.elections(brokers)
                 window["memory_peak_bytes"] = memory_peak_bytes()
 
@@ -429,6 +479,8 @@ async def run_cell(args, loaded: dict, device: dict) -> dict:
 def build_result(args, loaded: dict, device: dict, facts: dict) -> dict:
     """The run's last line: the contract's keys first, then `detail` for
     whoever reads a run by hand, then `checks`, last."""
+    from benchmark.readers import hostspans
+
     window, rec, reduced = facts["window"], facts["rec"], facts["reduced"]
     elections = window["elections1"] - window["elections0"]
     result = {
@@ -460,6 +512,11 @@ def build_result(args, loaded: dict, device: dict, facts: dict) -> dict:
         "fetches": rec["fetches"], "fetch_errors": rec["fetch_errors"][:5],
         "retried": sum(1 for r in rec["rows"] if r[8] > 1),
         **reduced.get("tails", {}),
+        # the medians of every cell, also of one whose `metrics` leave
+        # them out (over saturation they are the queue's length)
+        **{k: round(v, 4) for k, v in reduced["metrics"].items()
+           if k.endswith("_p50_ms")},
+        **reduced["load"],
         # a rate the system sustains reads alike in every quarter
         "produce_p50_ms_by_quarter": quarters(rec),
         "never_fetched": [
@@ -467,14 +524,29 @@ def build_result(args, loaded: dict, device: dict, facts: dict) -> dict:
              round(r[T_ACK] - rec["t0"], 3), r[8]]
             for r in rec["rows"] if r[BASE] >= 0 and r[ERR] is None and r[GOT] == -2
         ][:5],
+        # how late a closed batch was first sent (over saturation: the
+        # wait for one of the connection's requests in flight), how late
+        # the generator handed it to its sender, and the generator's CPU
         "generator_late_p95_ms": round(
             1e3 * percentile(rec["late_s"] or [0.0], 0.95), 3),
+        "generator_handed_late_p95_ms": round(
+            1e3 * percentile(rec.get("handed_late_s") or [0.0], 0.95), 3),
+        "generator_cpu_share": round(rec.get("generator_cpu_share", 0.0), 3),
         "sampled_dispatches": {
             k: v["count"] for k, v in window["devplane"].get("kernels", {}).items()},
         "dispatch_p50_ms": {
             k: v["p50_ms"] for k, v in window["devplane"].get("kernels", {}).items()
             if v["count"]},
         "sample_every": window["devplane"].get("sample_every"),
+        "compiles_in_window": hostspans.compiles_in_window(
+            {"devplane": window["devplane"]}, {}),
+        "spans_dropped": window["devplane"].get("spans_dropped"),
+        # the span store's aggregates, which every run keeps: seconds of
+        # self time by span name and kind over the window (the timed run's
+        # are the loop's shares without the probe waiting on a dispatch)
+        "span_self_s": {
+            name: [a["kind"], a["count"], round(a["self_s"], 4)]
+            for name, a in (window["devplane"].get("host") or {}).items()},
         "transfer_bytes": window["devplane"].get("transfer_bytes"),
         "clients": rec["clients"],
         # a batch as sent, and as the median fetch returned it: apart
@@ -516,6 +588,7 @@ def read_layers(args, loaded: dict, device: dict, facts: dict, result: dict) -> 
     t_a, t_b = window.get("traced", (0.0, 0.0))
     ctx = {
         "devplane": window["devplane"],
+        "devplane_s": window["devplane_s"],
         "acked_payload_bytes": facts["reduced"]["acked_payload_bytes"],
         "elections_in_window": window["elections1"] - window["elections0"],
         "trace": traced,

@@ -16,6 +16,12 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 E2E = {m["name"] for m in MANIFEST["end_to_end"]}
 LAYERS = {m["name"] for m in MANIFEST["per_layer"]}
+#: the cell over saturation, in its queued manifest (PR 34): judged on
+#: what it completes, no median
+OVER = ("omb_100.smoke_over", "--manifest",
+        os.path.join(ROOT, "benchmark", "queued", "omb_100.smoke_over.json"))
+with open(OVER[2]) as _f:
+    OVER_LAYERS = {m["name"] for m in json.load(_f)["per_layer"]}
 
 
 def _well_formed(line: dict, trace: int) -> None:
@@ -25,9 +31,21 @@ def _well_formed(line: dict, trace: int) -> None:
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(line["device"])
     assert line["attempted"] > 0
     for name, m in line["metrics"].items():
-        assert set(m) == {"value", "unit"} and name in (LAYERS if trace else E2E)
+        assert set(m) == {"value", "unit"}
+        assert name in (LAYERS | OVER_LAYERS if trace else E2E | {"sustained_mb_s"})
     for c in line["checks"].values():
         assert set(c) == {"value", "limit"}
+
+
+def _says_where_it_stood(line: dict) -> None:
+    """Every cell prints its medians and where the window stood against
+    its load under `detail`, whatever its `metrics` hold."""
+    assert {"produce_p50_ms", "e2e_p50_ms", "produce_p50_ms_by_quarter",
+            "offered_batches_per_s", "acked_share_in_window",
+            "due_not_acked_at_close", "batches_a_request",
+            "fetched_mb_s_in_window", "fetched_share_of_acked_in_window",
+            "generator_handed_late_p95_ms", "generator_cpu_share",
+            "compiles_in_window", "spans_dropped", "span_self_s"} <= set(line["detail"])
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -37,6 +55,7 @@ def test_untraced_run_reports_every_end_to_end_metric(cell):
     assert line["correct"] is True and line["failed"] == 0
     assert set(line["metrics"]) == E2E
     assert all(m["value"] > 0 for m in line["metrics"].values())
+    _says_where_it_stood(line)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -49,7 +68,36 @@ def test_traced_run_reports_per_layer_metrics(cell):
     assert {"tick_dispatch_ms", "h2d_bytes_per_acked_byte",
             "elections_in_window"} <= set(line["metrics"])
     assert not {"tick_roofline", "crc_roofline", "device_idle_pct"} & set(line["metrics"])
+    assert not OVER_LAYERS & set(line["metrics"])
     assert {"busy_s", "window_s"} <= set(line["device"])
+
+
+def test_the_queued_cell_over_saturation_reports_what_it_completes():
+    line = dry_run(*OVER, seed=2**31 + 19)
+    _well_formed(line, 0)
+    assert line["correct"] is True and line["failed"] == 0
+    assert set(line["metrics"]) == {"sustained_mb_s", "setup_s"}
+    assert all(m["value"] > 0 for m in line["metrics"].values())
+    _says_where_it_stood(line)
+
+
+def test_the_queued_cell_over_saturation_reads_the_loop_s_shares():
+    line = dry_run(*OVER, seed=2**31 + 20, trace=1)
+    _well_formed(line, 1)
+    assert line["correct"] is True
+    # what a batch costs the loop, from the span store's aggregates
+    got = {n: m["value"] for n, m in line["metrics"].items()}
+    shares = {n for n in OVER_LAYERS if n.endswith("_run_ms_per_batch")}
+    assert shares | {"loop_unspanned_pct"} <= set(got) and not set(got) & LAYERS
+    parts = sum(got[n] for n in shares - {"loop_run_ms_per_batch"})
+    assert 0 < parts <= got["loop_run_ms_per_batch"] * (1 + 1e-9)
+    assert 0 < got["loop_unspanned_pct"] < 100
+    # the older readings under names that move `sustained_mb_s`: the
+    # counters read on any platform, the trace's readers not on the CPU
+    assert {"h2d_bytes_per_acked_byte.over", "elections_in_window.over",
+            "compiles_in_window.over"} <= set(got)
+    assert not {"tick_roofline.over", "crc_roofline.over",
+                "device_idle_pct.over"} & set(got)
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -1,4 +1,6 @@
-"""BENCHMARK.json and every data file parse and cross-reference by name."""
+"""BENCHMARK.json and every data file parse and cross-reference by name;
+so does BENCHMARK.json with each manifest of benchmark/queued/ laid over
+it, which is what it becomes when a later PR brings that manifest's cell."""
 
 import json
 import os
@@ -14,10 +16,24 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
+QUEUED = os.path.join(ROOT, "benchmark", "queued")
+LAID_OVER = [None, *sorted(os.listdir(QUEUED))]
+
+
+@pytest.fixture(scope="module", params=LAID_OVER, ids=lambda q: q or "BENCHMARK.json")
+def queued(request):
+    """None, then each queued manifest's path: what `--manifest` takes."""
+    return request.param and os.path.join(QUEUED, request.param)
+
+
 @pytest.fixture(scope="module")
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+def manifest(queued):
+    found = run.load_json(ROOT, "BENCHMARK.json")
+    if queued:
+        own = run.load_json(queued)
+        own.pop("what")
+        found = run.laid_over(found, own)
+    return found
 
 
 def test_top_level_keys(manifest):
@@ -49,7 +65,7 @@ def test_configs(manifest):
         assert all(NAME.match(k) for k in c["reduced"])
 
 
-def test_workloads(manifest):
+def test_workloads(manifest, queued):
     configs = {c["name"] for c in manifest["configs"]}
     seen = set()
     for w in manifest["workloads"]:
@@ -59,7 +75,7 @@ def test_workloads(manifest):
         assert (w["config"], w["traffic"]) not in seen
         seen.add((w["config"], w["traffic"]))
         assert 1 <= len(w["why"]) <= 200
-        loaded = run.load_cell(w["name"])
+        loaded = run.load_cell(w["name"], manifest_file=queued)
         traffic = loaded["traffic"]
         assert callable(run.resolve(traffic["generator"], "generators"))
         assert callable(run.resolve(traffic["templates"]["maker"], "templates"))
@@ -78,11 +94,15 @@ def test_metrics(manifest):
                                            "source"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
+        assert set(m.get("workloads", cells)) <= cells and m.get("workloads", cells)
     for m in manifest["per_layer"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
                                            "layer", "moves"}
         assert m["moves"] in e2e and m["source"] in SOURCES
         assert set(m.get("workloads", cells)) <= cells
+        # every cell a metric lists reports the end-to-end metric it moves
+        moved = next(e for e in manifest["end_to_end"] if e["name"] == m["moves"])
+        assert set(m.get("workloads", [])) <= set(moved.get("workloads", cells))
         with open(os.path.join(ROOT, "benchmark", "metrics",
                                m["name"] + ".json")) as f:
             spec = json.load(f)
@@ -95,8 +115,10 @@ def test_metrics(manifest):
         names.add(m["name"])
 
 
-def test_every_metric_file_is_listed(manifest):
-    listed = {m["name"] for m in manifest["per_layer"]}
+def test_every_metric_file_is_listed():
+    listed = {m["name"] for m in run.load_json(ROOT, "BENCHMARK.json")["per_layer"]}
+    for queued in LAID_OVER[1:]:
+        listed |= {m["name"] for m in run.load_json(QUEUED, queued)["per_layer"]}
     on_disk = {
         f[:-5] for f in os.listdir(os.path.join(ROOT, "benchmark", "metrics"))
     }
