@@ -20,6 +20,7 @@ from ..models.record import (
     RecordBatchType,
     WireSpan,
 )
+from ..observability import devplane, trace
 from ..raft.consensus import Consensus, NotLeaderError  # noqa: F401 (re-export)
 from ..raft.offset_translator import OffsetTranslator
 from ..raft.replicate_batcher import ReplicateStages, consume_exc
@@ -28,6 +29,7 @@ from ..utils import serde
 from .archival_stm import ArchivalState
 from .producer_state import (
     DuplicateSequence,
+    OutOfOrderSequence,
     ProducerFenced,
     ProducerStateTable,
 )
@@ -163,6 +165,7 @@ class Partition:
                     h.producer_epoch,
                     kind == COMMIT_MARKER,
                     kbase,
+                    high_watermark=self.high_watermark(),
                 )
             return
         if h.base_sequence >= 0:
@@ -473,12 +476,14 @@ class Partition:
         return self.translator.to_kafka(commit) + 1
 
     def last_stable_offset(self) -> int:
-        """HW bounded by the earliest open transaction (rm_stm LSO):
-        READ_COMMITTED consumers must not observe offsets at or past an
-        undecided transaction's first record."""
+        """HW bounded by the earliest transaction that is open or
+        whose marker is not committed yet (rm_stm LSO): READ_COMMITTED
+        consumers must not observe offsets at or past an undecided
+        transaction's first record, and a transaction is decided on
+        this partition once its marker is below the high watermark."""
         hw = self.high_watermark()
-        first_open = self.tx.first_open_offset()
-        return hw if first_open is None else min(first_open, hw)
+        first = self.tx.first_unstable_offset(hw)
+        return hw if first is None else min(first, hw)
 
     def aborted_in(self, start: int, end: int) -> list[tuple[int, int]]:
         """(producer_id, first_offset) aborted-tx entries overlapping
@@ -525,21 +530,30 @@ class Partition:
             pid, epoch = h.producer_id, h.producer_epoch
             last_seq = h.base_sequence + h.record_count - 1
             key = (pid, epoch, h.base_sequence, last_seq)
+            devplane.count_sequence("checked")
             inflight = self._inflight.get(key)
             if inflight is not None:
+                devplane.count_sequence("duplicate")
                 return inflight
             horizon = self._inflight_seq.get(pid)
-            self.producers.check(
-                pid,
-                epoch,
-                h.base_sequence,
-                last_seq,
-                inflight_last_seq=(
-                    horizon[1]
-                    if horizon is not None and horizon[0] == epoch
-                    else None
-                ),
-            )
+            try:
+                self.producers.check(
+                    pid,
+                    epoch,
+                    h.base_sequence,
+                    last_seq,
+                    inflight_last_seq=(
+                        horizon[1]
+                        if horizon is not None and horizon[0] == epoch
+                        else None
+                    ),
+                )
+            except DuplicateSequence:
+                devplane.count_sequence("duplicate")
+                raise
+            except OutOfOrderSequence:
+                devplane.count_sequence("out_of_order")
+                raise
         ps = ReplicateStages()
         if key is not None:
             # register BEFORE any await so a concurrent retry aliases
@@ -644,14 +658,18 @@ class Partition:
             # late old-epoch batch would open an orphan tx that pins
             # the LSO forever; rm_stm writes its fence unconditionally.)
             return
-        b = RecordBatchBuilder(
-            producer_id=pid,
-            producer_epoch=epoch,
-            transactional=True,
-            control=True,
-        )
-        b.add(value=b"", key=control_record_key(commit))
-        await self.replicate(b.build(), acks=-1, timeout=timeout)
+        # the leader's own share of a marker: the control batch made;
+        # its replication runs under the caller's wait (tx.markers)
+        with trace.span("tx.marker_append", commit=int(commit)):
+            b = RecordBatchBuilder(
+                producer_id=pid,
+                producer_epoch=epoch,
+                transactional=True,
+                control=True,
+            )
+            b.add(value=b"", key=control_record_key(commit))
+            marker = b.build()
+        await self.replicate(marker, acks=-1, timeout=timeout)
 
     # -- read --------------------------------------------------------
     def read_kafka(

@@ -33,6 +33,7 @@ from typing import TYPE_CHECKING, Optional
 
 from ..models.fundamental import KAFKA_INTERNAL_NS, NTP, TopicNamespace
 from ..models.record import RecordBatch, RecordBatchBuilder, RecordBatchType
+from ..observability import trace
 from ..raft.consensus import NotLeaderError, ReplicateTimeout
 from ..rpc.server import Service, method
 from ..utils import serde
@@ -450,14 +451,16 @@ class TxCoordinator:
         In-memory state mutates only after the EMPTY record is durable
         — a failed persist must leave memory matching the log."""
         deadline = asyncio.get_event_loop().time() + 10.0
-        for ntp in sorted(meta.partitions, key=str):
-            await self._marker_to_partition(
-                ntp, meta.pid, meta.epoch, commit, deadline
-            )
-        for group in sorted(meta.groups):
-            await self._marker_to_group(
-                group, meta.pid, meta.epoch, commit, deadline
-            )
+        # markers sent -> every partition and group answered
+        with trace.span("tx.markers", "wait"):
+            for ntp in sorted(meta.partitions, key=str):
+                await self._marker_to_partition(
+                    ntp, meta.pid, meta.epoch, commit, deadline
+                )
+            for group in sorted(meta.groups):
+                await self._marker_to_group(
+                    group, meta.pid, meta.epoch, commit, deadline
+                )
         done = dataclasses.replace(
             meta,
             status=TX_EMPTY,
@@ -465,7 +468,8 @@ class TxCoordinator:
             groups=set(),
             update_ms=int(time.time() * 1000),
         )
-        await self._persist(done)
+        with trace.span("tx.complete", "wait"):
+            await self._persist(done)
         meta.status = TX_EMPTY
         meta.partitions = set()
         meta.groups = set()
@@ -598,6 +602,13 @@ class TxCoordinator:
     async def add_partitions(
         self, tx_id: str, pid: int, epoch: int, ntps: list[NTP]
     ) -> int:
+        # root: the request as its coordinator serves it
+        with trace.span("tx.add_partitions", "wait", partitions=len(ntps)):
+            return await self._add_partitions(tx_id, pid, epoch, ntps)
+
+    async def _add_partitions(
+        self, tx_id: str, pid: int, epoch: int, ntps: list[NTP]
+    ) -> int:
         shard = await self._shard_for(tx_id)
         if shard is None:
             return int(_E.not_coordinator)
@@ -661,6 +672,13 @@ class TxCoordinator:
     async def end_txn(
         self, tx_id: str, pid: int, epoch: int, commit: bool
     ) -> int:
+        # root: children tx.prepare, tx.markers, tx.complete
+        with trace.span("tx.end", "wait", commit=int(commit)):
+            return await self._end_txn(tx_id, pid, epoch, commit)
+
+    async def _end_txn(
+        self, tx_id: str, pid: int, epoch: int, commit: bool
+    ) -> int:
         shard = await self._shard_for(tx_id)
         if shard is None:
             return int(_E.not_coordinator)
@@ -694,8 +712,10 @@ class TxCoordinator:
                 status=TX_PREPARING_COMMIT if commit else TX_PREPARING_ABORT,
                 update_ms=int(time.time() * 1000),
             )
+            trace.tag_current(partitions=len(meta.partitions))
             try:
-                await self._persist(candidate)
+                with trace.span("tx.prepare", "wait"):
+                    await self._persist(candidate)
                 meta.status = candidate.status
                 meta.update_ms = candidate.update_ms
                 await self._complete(meta, commit)

@@ -57,6 +57,13 @@ class TxTracker:
         self.aborted: list[tuple[int, int, int]] = []
         # pid -> highest epoch ever observed (fence)
         self.fences: dict[int, int] = {}
+        # (first_kafka, marker_kafka) of transactions whose marker is
+        # appended and may not be committed yet: observation runs at
+        # append, rm_stm applies a marker when it commits, and until
+        # then the transaction still bounds the LSO (Kafka's
+        # unreplicatedTxns). Not in the snapshot: what a snapshot
+        # covers is committed
+        self.closing: list[tuple[int, int]] = []
 
     # -- log observation (leader append, follower append, replay) ----
     def observe_data(self, pid: int, epoch: int, first_kafka: int) -> None:
@@ -72,7 +79,12 @@ class TxTracker:
             self.open[pid] = (epoch, first_kafka)
 
     def observe_marker(
-        self, pid: int, epoch: int, commit: bool, marker_kafka: int
+        self,
+        pid: int,
+        epoch: int,
+        commit: bool,
+        marker_kafka: int,
+        high_watermark: int = 0,
     ) -> None:
         if epoch > self.fences.get(pid, -1):
             self.fences[pid] = epoch
@@ -80,6 +92,10 @@ class TxTracker:
         if cur is None or cur[0] > epoch:
             return  # stale duplicate marker
         del self.open[pid]
+        # a follower is never asked for its LSO: what it keeps is pruned
+        # here, by the high watermark it had when this marker arrived
+        self._prune_closing(high_watermark)
+        self.closing.append((cur[1], marker_kafka))
         if not commit:
             self.aborted.append((pid, cur[1], marker_kafka))
 
@@ -87,10 +103,21 @@ class TxTracker:
     def fence_epoch(self, pid: int) -> int:
         return self.fences.get(pid, -1)
 
-    def first_open_offset(self) -> int | None:
-        if not self.open:
-            return None
-        return min(first for _e, first in self.open.values())
+    def first_unstable_offset(self, high_watermark: int) -> int | None:
+        """First offset of the earliest transaction that is open or
+        whose marker lies at or past `high_watermark` (appended, not
+        yet committed); None when every transaction is decided below
+        it."""
+        self._prune_closing(high_watermark)
+        firsts = [first for first, _marker in self.closing]
+        firsts.extend(first for _e, first in self.open.values())
+        return min(firsts, default=None)
+
+    def _prune_closing(self, high_watermark: int) -> None:
+        if self.closing and self.closing[0][1] < high_watermark:
+            self.closing = [
+                c for c in self.closing if c[1] >= high_watermark
+            ]
 
     def has_open(self, pid: int, epoch: int) -> bool:
         """An open tx a marker at `epoch` would close: same epoch, or a
@@ -119,6 +146,7 @@ class TxTracker:
         self.open.clear()
         self.aborted.clear()
         self.fences.clear()
+        self.closing.clear()
 
     # -- snapshot -----------------------------------------------------
     def encode(self) -> bytes:

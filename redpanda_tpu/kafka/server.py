@@ -1736,7 +1736,14 @@ class KafkaServer:
                         records=wire if wire else None,
                     )
 
+        # a read_committed pass that found the high watermark past a
+        # partition's fetch offset and the LSO not: data is there and
+        # an open transaction holds it back
+        lso_blocked = False
+
         def read_all() -> tuple[list[Msg], int, bool]:
+            nonlocal lso_blocked
+            lso_blocked = False
             total = 0
             has_error = False
             out = []
@@ -1890,6 +1897,8 @@ class KafkaServer:
                             )
                         )
                         continue
+                    if read_committed and lso <= p.fetch_offset < hw:
+                        lso_blocked = True
                     wire, fetch_end = read_fetch_rows(
                         partition,
                         p.fetch_offset,
@@ -1928,11 +1937,14 @@ class KafkaServer:
 
         # long-poll: debounced re-read until min_bytes or max_wait
         # (fetch.cc:432 over_min_bytes, :546 debounce)
+        reads = 0
+        lso_wait_ns = 0  # when this fetch first parked behind the LSO
         while True:
             if shard_router is not None:
                 await shard_prepass()
             with trace.span("fetch.read"):
                 responses, total, has_error = read_all()
+            reads += 1
             # error partitions complete the fetch immediately — holding
             # the long-poll would stall the client's metadata refresh
             if has_error or total >= min_bytes:
@@ -1940,7 +1952,15 @@ class KafkaServer:
             now = asyncio.get_event_loop().time()
             if now >= deadline:
                 break
+            if lso_blocked and not lso_wait_ns:
+                lso_wait_ns = time.monotonic_ns()
             await asyncio.sleep(min(0.005, deadline - now))
+        # the read_all passes the long-poll made before it answered
+        trace.tag_current(reads=reads)
+        if lso_wait_ns:
+            trace.record(
+                "fetch.lso_wait", "wait", lso_wait_ns, time.monotonic_ns()
+            )
 
         if fetch_verify_enabled():
             with trace.span("fetch.verify"):

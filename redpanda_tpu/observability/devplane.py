@@ -110,6 +110,13 @@ _STATE_SEEDS = registry.counter(
     "prewarm, capacity doubling or backend change; any more means the "
     "tick is re-uploading its lanes",
 )
+_SEQUENCES = registry.counter(
+    "devplane_producer_sequences_total",
+    "idempotent-producer sequence checks on the produce path (rm_stm "
+    "dedupe), by result: checked (every batch that carries a producer "
+    "id and a sequence), duplicate (a resent batch answered with its "
+    "first offset), out_of_order (refused)",
+)
 _TICK_TRANSFERS = registry.counter(
     "devplane_tick_transfers_total",
     "device transfers/dispatches observed on the steady tick path "
@@ -134,6 +141,7 @@ FRAMES_FAMILY = _FRAMES.name
 FOLDS_FAMILY = _FOLDS.name
 TRANSFER_FAMILY = _TRANSFER_BYTES.name
 STATE_SEEDS_FAMILY = _STATE_SEEDS.name
+SEQUENCES_FAMILY = _SEQUENCES.name
 TICK_TRANSFER_FAMILY = _TICK_TRANSFERS.name
 COMPILES_FAMILY = _COMPILES.name
 COMPILE_SECS_FAMILY = _COMPILE_SECS.name
@@ -355,6 +363,15 @@ def count_transfer(nbytes: int, direction: str) -> None:
         _TICK_TRANSFERS.inc(kind="transfer")
 
 
+def count_sequence(result: str) -> None:
+    """One producer sequence check (`result` from the static set
+    checked|duplicate|out_of_order; cluster/partition.py, beside
+    ProducerStateTable.check). A pass-through produce, producer id -1,
+    never gets here."""
+    if ENABLED:
+        _SEQUENCES.inc(result=result)
+
+
 def count_state_seed() -> None:
     """The tick had no resident device state left and uploaded its
     lanes whole (ShardGroupArrays._fold_on_device)."""
@@ -521,6 +538,7 @@ def merged_status(snaps: list) -> dict:
     folds = 0.0
     transfers: dict[str, float] = {}
     state_seeds = 0.0
+    sequences: dict[str, float] = {}
     tick_violations = 0.0
     compiles: dict[str, dict] = {}
     jit_cache: dict[str, float] = {}
@@ -541,6 +559,9 @@ def merged_status(snaps: list) -> dict:
                     transfers[d] = transfers.get(d, 0.0) + s.value
                 elif fam.name == STATE_SEEDS_FAMILY:
                     state_seeds += s.value
+                elif fam.name == SEQUENCES_FAMILY and "result" in lab:
+                    r = lab["result"]
+                    sequences[r] = sequences.get(r, 0.0) + s.value
                 elif fam.name == TICK_TRANSFER_FAMILY:
                     tick_violations += s.value
                 elif fam.name == COMPILES_FAMILY and "kernel" in lab:
@@ -597,6 +618,9 @@ def merged_status(snaps: list) -> dict:
             k: int(v) for k, v in sorted(transfers.items())
         },
         "state_seeds": int(state_seeds),
+        "producer_sequences": {
+            k: int(v) for k, v in sorted(sequences.items())
+        },
         "tick_violations": int(tick_violations),
         "frame_ms": {
             k: _hist_digest(c) for k, c in sorted(frame_hist.items())

@@ -203,7 +203,8 @@ class ReplicateBatcher:
                 parent=it.span,
             )
         with trace.span(
-            "raft.append", parent=items[0].span, items=len(items)
+            "raft.append", parent=items[0].span, items=len(items),
+            batches=len(items),
         ).begin(append_ns):
             for it in items:
                 it.base, it.last = c.log.append(it.batch, term=term)

@@ -140,9 +140,10 @@ def test_the_traffic_is_data_over_omb_client(loaded):
 
 def test_the_sweeps_lay_over_the_cell_s_traffic():
     tools = os.path.join(run.HERE, "tools")
-    # `sweep_omb_100_lz4*` are the codec deployment's (test_benchmark_omb_100_lz4.py)
+    # `sweep_omb_100_lz4*` are the codec deployment's (test_benchmark_omb_100_lz4.py),
+    # `sweep_omb_100_tx*` the exactly-once one's (test_benchmark_omb_100_tx.py)
     sweeps = sorted(f for f in os.listdir(tools) if f.startswith("sweep_omb_100")
-                    and not f.startswith("sweep_omb_100_lz4"))
+                    and not f.startswith(("sweep_omb_100_lz4", "sweep_omb_100_tx")))
     assert len(sweeps) >= 2
     for name in sweeps:
         sweep = run.load_traffic(os.path.join(tools, name))
@@ -163,10 +164,13 @@ def test_both_new_metrics_are_read_in_every_cell(cell):
         ("produce_open_mean", "Kafka front end", OPEN),
     ):
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == CELLS
+        # at least these cells: a later `benchmark` PR may list more
+        assert set(CELLS) <= set(entry["workloads"])
         assert (entry["source"], entry["moves"], entry["layer"]) == (
             "program_span", "produce_p50_ms", layer)
         assert callable(run.resolve(by_name[name]["reader"], "readers"))
         assert by_name[name]["params"] == params
-    # `follower_rtt_ms` lists rf3_1k alone and this PR may not edit it
-    assert ("follower_rtt_ms" in by_name) == (cell == "rf3_1k.smoke_24")
+    # `follower_rtt_ms` is read on rf3_1k at the least
+    rtt = next(m for m in manifest["per_layer"] if m["name"] == "follower_rtt_ms")
+    assert "rf3_1k.smoke_24" in rtt["workloads"]
+    assert ("follower_rtt_ms" in by_name) == (cell in rtt["workloads"])

@@ -298,3 +298,29 @@ async def _tx_timeout_abort(tmp_path):
 
 def test_tx_timeout_abort(tmp_path):
     asyncio.run(_tx_timeout_abort(tmp_path))
+
+
+def test_a_transaction_bounds_the_lso_until_its_marker_is_committed():
+    """rm_stm applies a marker when it commits; the tracker observes at
+    append, so it keeps a closed transaction under the LSO until the
+    high watermark has passed its marker (Kafka's unreplicatedTxns)."""
+    from redpanda_tpu.cluster.tx_state import TxTracker
+
+    t = TxTracker()
+    t.observe_data(7, 0, 10)
+    assert t.first_unstable_offset(11) == 10           # open
+    t.observe_marker(7, 0, True, 11, high_watermark=11)
+    assert t.first_unstable_offset(11) == 10           # marker appended, not committed
+    assert t.first_unstable_offset(12) is None         # the marker is below the high watermark
+    assert t.closing == []
+    # an aborted range is reported from the append on, as before
+    t.observe_data(8, 0, 12)
+    t.observe_marker(8, 0, False, 13, high_watermark=12)
+    assert t.aborted_in(12, 14) == [(8, 12)] and t.first_unstable_offset(13) == 12
+    # a follower is never asked: it prunes by the high watermark each marker arrives under
+    for i in range(50):
+        t.observe_data(9, 0, 20 + 2 * i)
+        t.observe_marker(9, 0, True, 21 + 2 * i, high_watermark=20 + 2 * i)
+    assert len(t.closing) == 1
+    t.clear()
+    assert t.closing == [] and t.first_unstable_offset(0) is None

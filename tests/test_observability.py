@@ -1068,3 +1068,122 @@ def test_produce_is_one_tree_across_the_layer_boundaries(tmp_path, window):
 def test_span_count_does_not_grow_with_records(tmp_path, window):
     one, many = asyncio.run(_one_broker_produces(tmp_path, window, [1, 500]))
     assert sorted(s[0] for s in one) == sorted(s[0] for s in many)
+
+
+# -- the transaction path's spans and counters (PR 35) -------------------
+
+
+async def _one_broker_transacts(tmp_path, window):
+    """A commit, a read_committed fetch parked behind an open
+    transaction, its abort, and a batch sent twice, through a
+    one-broker cluster; returns the raw spans and the devplane digest."""
+    from redpanda_tpu.kafka.client import TransactionalProducer
+    from redpanda_tpu.observability import devplane
+
+    async with cluster(tmp_path, n=1) as (_net, brokers):
+        client = KafkaClient([brokers[0].kafka_advertised])
+        try:
+            await client.create_topic("tx-spans", partitions=1, replication_factor=1)
+            tx = TransactionalProducer(client, "tx-spans-1")
+            await tx.init()
+            devplane.reset()   # also says whether raw records are kept
+            window.keep_raw = True
+            window.reset()
+            tx.begin()
+            await tx.produce("tx-spans", 0, [(b"k", b"committed")])
+            await tx.commit()
+            tx.begin()
+            await tx.produce("tx-spans", 0, [(b"k", b"aborted")])
+            # offsets 0 and 1 are the commit and its marker; the high
+            # watermark is past offset 2, the LSO is not: parks
+            parked = asyncio.ensure_future(client.fetch(
+                "tx-spans", 0, 2, read_committed=True, max_wait_ms=400))
+            await asyncio.sleep(0.06)
+            await tx.abort()
+            assert await parked == []   # the aborted record is filtered
+            # the same sequence again: answered with its first offset
+            tx._seqs[("tx-spans", 0)] -= 1
+            tx.begin()
+            again = await tx.produce("tx-spans", 0, [(b"k", b"aborted")])
+            await tx.abort()
+            assert again == 2
+            await asyncio.sleep(0.02)
+            return window.status()["spans"], devplane.merged_status(
+                [devplane.snapshot()])
+        finally:
+            await client.close()
+
+
+@needs_trace
+def test_the_transaction_path_s_spans_and_counters(tmp_path, window, monkeypatch):
+    from redpanda_tpu.observability import devplane
+
+    monkeypatch.setattr(devplane, "ENABLED", True)
+    rows, digest = asyncio.run(_one_broker_transacts(tmp_path, window))
+    devplane.reset()
+    named = {}
+    for s in rows:
+        named.setdefault(s[0], []).append(s)
+    by_id = {s[4]: s for s in rows}
+
+    def parent_of(s):
+        return by_id[s[5]][0] if s[5] in by_id else None
+
+    kinds = {name: {s[1] for s in spans} for name, spans in named.items()}
+    for name in ("tx.add_partitions", "tx.end", "tx.prepare", "tx.markers",
+                 "tx.complete", "fetch.lso_wait"):
+        assert kinds[name] == {"wait"}, name
+    assert kinds["tx.marker_append"] == {"run"}
+    # roots at the coordinator, with what the request was about
+    assert all(s[5] == 0 for s in named["tx.add_partitions"] + named["tx.end"])
+    assert all(s[7]["partitions"] == 1 for s in named["tx.add_partitions"])
+    assert sorted(s[7]["commit"] for s in named["tx.end"]) == [0, 0, 1]
+    assert all(s[7]["partitions"] == 1 for s in named["tx.end"])
+    for name in ("tx.prepare", "tx.markers", "tx.complete"):
+        assert len(named[name]) == 3
+        assert {parent_of(s) for s in named[name]} == {"tx.end"}, name
+    # one broker: the marker is written by a local call, under the wait
+    assert {parent_of(s) for s in named["tx.marker_append"]} == {"tx.markers"}
+    # the third transaction stored nothing (its batch was a duplicate):
+    # there is nothing open for its marker to close, and none is written
+    assert sorted(s[7]["commit"] for s in named["tx.marker_append"]) == [0, 1]
+    # a coordinator write replicates under its stage
+    under = {parent_of(s) for s in named["raft.append"]}
+    assert {"tx.add_partitions", "tx.prepare", "tx.markers", "tx.complete",
+            "produce.ack_wait"} <= under
+    assert all(s[7]["batches"] == s[7]["items"] for s in named["raft.append"])
+    # the parked fetch: re-read every 5 ms until the abort's marker
+    (wait,) = named["fetch.lso_wait"]
+    fetch = by_id[wait[5]]
+    assert fetch[0] == "kafka.fetch" and fetch[7]["reads"] > 3
+    assert wait[3] > 20e6 and wait[2] >= fetch[2]
+    assert all("reads" in s[7] for s in named["kafka.fetch"])
+    # rm_stm's check: two batches and the one sent twice (a marker and
+    # a coordinator's write carry no sequence)
+    assert digest["producer_sequences"] == {"checked": 3, "duplicate": 1}
+
+
+@needs_trace
+def test_a_pass_through_produce_and_fetch_open_no_transaction_span(tmp_path, window):
+    from redpanda_tpu.observability import devplane
+
+    async def drive():
+        async with cluster(tmp_path, n=1) as (_net, brokers):
+            client = KafkaClient([brokers[0].kafka_advertised])
+            try:
+                await client.create_topic("plain", partitions=1, replication_factor=1)
+                window.reset()
+                await client.produce("plain", 0, [(None, b"v")])
+                assert len(await client.fetch("plain", 0, 0)) == 1
+                await asyncio.sleep(0.02)
+                return window.status()["spans"]
+            finally:
+                await client.close()
+
+    rows = asyncio.run(drive())
+    names = {s[0] for s in rows}
+    assert not {n for n in names if n.startswith("tx.")} and "fetch.lso_wait" not in names
+    # a fetch that finds its bytes answers from its first pass
+    assert [s[7]["reads"] for s in rows if s[0] == "kafka.fetch"] == [1]
+    assert "producer_sequences" in devplane.merged_status([]) \
+        and devplane.merged_status([])["producer_sequences"] == {}
