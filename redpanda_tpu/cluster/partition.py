@@ -485,6 +485,16 @@ class Partition:
         first = self.tx.first_unstable_offset(hw)
         return hw if first is None else min(first, hw)
 
+    def add_commit_listener(self, cb) -> None:
+        """Run `cb()` inline whenever this replica's commit index moves
+        or its group steps down or stops (Consensus._notify_commit):
+        the one place both offsets above are seen to move, since the
+        LSO moves only when the high watermark does."""
+        self.consensus.add_commit_listener(cb)
+
+    def remove_commit_listener(self, cb) -> None:
+        self.consensus.remove_commit_listener(cb)
+
     def aborted_in(self, start: int, end: int) -> list[tuple[int, int]]:
         """(producer_id, first_offset) aborted-tx entries overlapping
         the fetch range (fetch response AbortedTransaction rows)."""

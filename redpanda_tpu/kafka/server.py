@@ -24,6 +24,7 @@ from __future__ import annotations
 import asyncio
 import contextvars
 import dataclasses
+import functools
 import logging
 import os
 import struct
@@ -1740,10 +1741,15 @@ class KafkaServer:
         # partition's fetch offset and the LSO not: data is there and
         # an open transaction holds it back
         lso_blocked = False
+        # (partition, end of what the pass read of it) for each row a
+        # pass read from this shard's own log: where the fetch parks
+        local_reads: list[tuple] = []
+        parked = _ParkedFetch(self.broker.partition_manager, read_committed)
 
         def read_all() -> tuple[list[Msg], int, bool]:
             nonlocal lso_blocked
             lso_blocked = False
+            local_reads.clear()
             total = 0
             has_error = False
             out = []
@@ -1866,6 +1872,7 @@ class KafkaServer:
                             # never out_of_range, or a redirected
                             # rack consumer crashes on data the
                             # cluster definitely has (KIP-392)
+                            local_reads.append((partition, p.fetch_offset))
                             parts.append(
                                 Msg(
                                     partition_index=p.partition,
@@ -1908,6 +1915,12 @@ class KafkaServer:
                         upto_kafka=lso if read_committed else None,
                     )
                     total += len(wire)
+                    local_reads.append(
+                        (
+                            partition,
+                            p.fetch_offset if fetch_end is None else fetch_end,
+                        )
+                    )
                     if wire:
                         self.probe.note_fetch(
                             f"{DEFAULT_NS}/{t.topic}/{p.partition}",
@@ -1935,31 +1948,51 @@ class KafkaServer:
                 out.append(Msg(topic=t.topic, partitions=parts))
             return out, total, has_error
 
-        # long-poll: debounced re-read until min_bytes or max_wait
-        # (fetch.cc:432 over_min_bytes, :546 debounce)
+        # long-poll (fetch.cc:432 over_min_bytes): a fetch that finds
+        # under min_bytes parks on the partitions it read and their
+        # commit notification wakes it; the deadline is the only timer
         reads = 0
-        lso_wait_ns = 0  # when this fetch first parked behind the LSO
-        while True:
-            if shard_router is not None:
-                await shard_prepass()
-            with trace.span("fetch.read"):
-                responses, total, has_error = read_all()
-            reads += 1
-            # error partitions complete the fetch immediately — holding
-            # the long-poll would stall the client's metadata refresh
-            if has_error or total >= min_bytes:
-                break
-            now = asyncio.get_event_loop().time()
-            if now >= deadline:
-                break
-            if lso_blocked and not lso_wait_ns:
-                lso_wait_ns = time.monotonic_ns()
-            await asyncio.sleep(min(0.005, deadline - now))
-        # the read_all passes the long-poll made before it answered
-        trace.tag_current(reads=reads)
-        if lso_wait_ns:
+        expired = False
+        try:
+            while True:
+                if shard_router is not None:
+                    await shard_prepass()
+                with trace.span("fetch.read"):
+                    responses, total, has_error = read_all()
+                reads += 1
+                # error partitions complete the fetch immediately —
+                # holding the long-poll would stall the client's
+                # metadata refresh
+                if has_error or total >= min_bytes or expired:
+                    break
+                now = asyncio.get_event_loop().time()
+                if now >= deadline:
+                    break
+                if lso_blocked:
+                    parked.note_lso_wait()
+                if shard_rows:
+                    # a row another shard serves moves where no
+                    # listener of this shard sees it: such a fetch, and
+                    # only such a fetch, re-reads on a timer
+                    await asyncio.sleep(min(0.005, deadline - now))
+                    continue
+                # woken by what read_all would answer differently: data
+                # the fetch may be served past what this pass read, a
+                # leadership that changed, a partition that went. Not by
+                # a log start that DeleteRecords moved under the parked
+                # fetch: that waits for the deadline, as Kafka's own
+                # delayed fetch does
+                parked.park(local_reads)
+                expired = not await parked.wait(deadline)
+        finally:
+            parked.unpark()
+        # the read_all passes the long-poll made before it answered,
+        # and how many of them a listener's wake-up brought
+        trace.tag_current(reads=reads, wakes=parked.wakes)
+        if parked.lso_wait_ns:
             trace.record(
-                "fetch.lso_wait", "wait", lso_wait_ns, time.monotonic_ns()
+                "fetch.lso_wait", "wait", parked.lso_wait_ns,
+                time.monotonic_ns(),
             )
 
         if fetch_verify_enabled():
@@ -2204,6 +2237,79 @@ def fetch_verify_enabled() -> bool:
     one ops/crc32c dispatch per fetch response. Stand-down (default)
     is the trust-append-time behavior."""
     return os.environ.get("RP_FETCH_VERIFY", "0") == "1"
+
+
+class _ParkedFetch:
+    """A fetch that found under min_bytes, waiting on the partitions it
+    read (fetch.cc's delayed fetch): one Event a fetch and one listener
+    a partition, run inline by the partition's commit notification. A
+    listener wakes the fetch when what the fetch may be served — the
+    high watermark, or the LSO for read_committed and never the high
+    watermark alone — stands past what its last pass read, when the
+    replica's leadership changed, or when the partition left this
+    shard; read_all then answers with the data or the error row.
+    Several notifications before the fetch's task runs are one wake-up
+    (the debounce upstream keeps at fetch.cc:546)."""
+
+    __slots__ = (
+        "_partitions", "_read_committed", "_event", "_listeners",
+        "wakes", "lso_wait_ns",
+    )
+
+    def __init__(self, partition_manager, read_committed: bool) -> None:
+        self._partitions = partition_manager
+        self._read_committed = read_committed
+        self._event = asyncio.Event()
+        self._listeners: list[tuple] = []
+        self.wakes = 0
+        # when the fetch first stood behind the LSO with the high
+        # watermark past it (fetch.lso_wait starts here)
+        self.lso_wait_ns = 0
+
+    def note_lso_wait(self) -> None:
+        if not self.lso_wait_ns:
+            self.lso_wait_ns = time.monotonic_ns()
+
+    def park(self, reads: list[tuple]) -> None:
+        """Listen on each (partition, end of what the last pass read)."""
+        self.unpark()
+        self._event.clear()
+        for partition, upto in reads:
+            cb = functools.partial(
+                self._on_commit, partition, upto, partition.is_leader
+            )
+            partition.add_commit_listener(cb)
+            self._listeners.append((partition, cb))
+
+    def unpark(self) -> None:
+        for partition, cb in self._listeners:
+            partition.remove_commit_listener(cb)
+        self._listeners.clear()
+
+    async def wait(self, deadline: float) -> bool:
+        """True once a listener woke the fetch, False at the deadline
+        (the event loop's clock)."""
+        try:
+            async with asyncio.timeout_at(deadline):
+                await self._event.wait()
+        except TimeoutError:
+            return False
+        return True
+
+    def _on_commit(self, partition, upto: int, led: bool) -> None:
+        if self._event.is_set():
+            return
+        if (
+            partition.is_leader == led
+            and self._partitions.get(partition.ntp) is partition
+        ):
+            if partition.high_watermark() <= upto:
+                return
+            if self._read_committed and partition.last_stable_offset() <= upto:
+                self.note_lso_wait()
+                return
+        self.wakes += 1
+        self._event.set()
 
 
 def read_fetch_rows(

@@ -188,6 +188,13 @@ class Consensus:
         self._quorum_waiters: list[tuple] = []
         self._qw_seq = itertools.count()
         self._qw_timer: Optional[asyncio.TimerHandle] = None
+        # what a parked fetch left here (kafka/server.py _ParkedFetch):
+        # plain callables, run INLINE by _notify_commit for the quorum
+        # waiters' reason — no task and no Event a partition a wait.
+        # Each decides for itself whether what moved concerns it and
+        # must not touch this set; with no fetch parked a commit pays
+        # one truth test
+        self._commit_listeners: set = set()
         # persistent per-peer catch-up fibers, kicked by event instead
         # of a task spawn per flush round (replicate_entries_stm
         # dispatch fibers, ref replicate_entries_stm.cc:143)
@@ -539,6 +546,7 @@ class Consensus:
         if self._observe_prefix_truncate in self.log.on_prefix_truncate:
             self.log.on_prefix_truncate.remove(self._observe_prefix_truncate)
         self._notify_commit()  # release waiters
+        self._commit_listeners.clear()  # told once; nothing moves again
         self._fail_quorum_waiters(lambda: ReplicateTimeout("node stopped"))
 
     # ------------------------------------------------- live-move quiesce
@@ -1271,6 +1279,25 @@ class Consensus:
             while qw and qw[0][0] <= ci:
                 _, _, term, items, _ = heapq.heappop(qw)
                 self._resolve_quorum_items(term, items)
+        if self._commit_listeners:
+            for cb in self._commit_listeners:
+                try:
+                    cb()
+                except Exception:
+                    # a reader's fault must not reach the commit path
+                    logger.exception(
+                        "g%d: commit listener failed", self.group_id
+                    )
+
+    def add_commit_listener(self, cb) -> None:
+        """Run `cb()` inline on every _notify_commit: a commit index
+        that advanced (leader or follower), a step-down, a snapshot
+        install, this group's stop. Several per loop pass are several
+        calls; the listener keeps its own debounce."""
+        self._commit_listeners.add(cb)
+
+    def remove_commit_listener(self, cb) -> None:
+        self._commit_listeners.discard(cb)
 
     # -- offset-keyed quorum waiters (replicate_batcher acks=-1) ------
     def add_quorum_waiter(

@@ -1304,25 +1304,33 @@ class ShardGroupArrays:
         the way."""
         empty = np.array([], np.int64)
         backend = self._backend()
+        # a fold here has nobody to tell which rows' commit advanced
+        # (Consensus._notify_commit runs off the tick frame's and the
+        # heartbeat manager's folds): rows that wait for a recompute
+        # are set aside, and the next live fold moves and reports them
+        pending = self.quorum_dirty.copy()
+        self.quorum_dirty[:] = False
         # declared-warmup region: compiles here are the point of the
         # call (capacity doubling / backend bring-up), so the compile
         # guard must not count them against the steady window
-        with compileguard.warmup("prewarm at capacity %d" % self._cap):
-            if backend == "mesh":
-                # compile the sharded frame + health programs at the
-                # current capacity (also folds any pending dirty rows,
-                # matching the host/device prewarm semantics)
-                self._mesh_full_frame(empty, empty, empty, empty, empty)
-                self.health_refresh()
-                return
-            self._resident = None
-            self.device_tick(empty, empty, empty, empty, empty)
-            if backend == "device":
-                bucket = 8
-                while True:
-                    # all padding: nothing is scattered or folded and
-                    # nothing read back is kept
-                    self._fold_on_device(_EMPTY_ROWS, (empty,) * 5, bucket)
-                    if bucket >= max(self._cap, max_replies):
-                        break
-                    bucket *= 2
+        try:
+            with compileguard.warmup("prewarm at capacity %d" % self._cap):
+                if backend == "mesh":
+                    # compile the sharded frame + health programs at
+                    # the current capacity
+                    self._mesh_full_frame(empty, empty, empty, empty, empty)
+                    self.health_refresh()
+                    return
+                self._resident = None
+                self.device_tick(empty, empty, empty, empty, empty)
+                if backend == "device":
+                    bucket = 8
+                    while True:
+                        # all padding: nothing is scattered or folded
+                        # and nothing read back is kept
+                        self._fold_on_device(_EMPTY_ROWS, (empty,) * 5, bucket)
+                        if bucket >= max(self._cap, max_replies):
+                            break
+                        bucket *= 2
+        finally:
+            self.quorum_dirty |= pending

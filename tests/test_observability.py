@@ -1096,11 +1096,17 @@ async def _one_broker_transacts(tmp_path, window):
             await tx.produce("tx-spans", 0, [(b"k", b"aborted")])
             # offsets 0 and 1 are the commit and its marker; the high
             # watermark is past offset 2, the LSO is not: parks
-            parked = asyncio.ensure_future(client.fetch(
-                "tx-spans", 0, 2, read_committed=True, max_wait_ms=400))
-            await asyncio.sleep(0.06)
-            await tx.abort()
-            assert await parked == []   # the aborted record is filtered
+            # (on a connection of its own: a connection's requests are
+            # served in turn, and the abort would queue behind the fetch)
+            consumer = KafkaClient([brokers[0].kafka_advertised])
+            try:
+                parked = asyncio.ensure_future(consumer.fetch(
+                    "tx-spans", 0, 2, read_committed=True, max_wait_ms=4000))
+                await asyncio.sleep(0.06)
+                await tx.abort()
+                assert await parked == []   # the aborted record is filtered
+            finally:
+                await consumer.close()
             # the same sequence again: answered with its first offset
             tx._seqs[("tx-spans", 0)] -= 1
             tx.begin()
@@ -1152,12 +1158,14 @@ def test_the_transaction_path_s_spans_and_counters(tmp_path, window, monkeypatch
     assert {"tx.add_partitions", "tx.prepare", "tx.markers", "tx.complete",
             "produce.ack_wait"} <= under
     assert all(s[7]["batches"] == s[7]["items"] for s in named["raft.append"])
-    # the parked fetch: re-read every 5 ms until the abort's marker
+    # the parked fetch: one pass that parks it, one after the abort's
+    # marker woke it
     (wait,) = named["fetch.lso_wait"]
     fetch = by_id[wait[5]]
-    assert fetch[0] == "kafka.fetch" and fetch[7]["reads"] > 3
+    assert fetch[0] == "kafka.fetch"
+    assert (fetch[7]["reads"], fetch[7]["wakes"]) == (2, 1)
     assert wait[3] > 20e6 and wait[2] >= fetch[2]
-    assert all("reads" in s[7] for s in named["kafka.fetch"])
+    assert all("reads" in s[7] and "wakes" in s[7] for s in named["kafka.fetch"])
     # rm_stm's check: two batches and the one sent twice (a marker and
     # a coordinator's write carry no sequence)
     assert digest["producer_sequences"] == {"checked": 3, "duplicate": 1}
