@@ -643,8 +643,11 @@ class KafkaServer:
         # hot single-topic/single-partition shape; all the gates below
         # still run on the returned header, so SASL/session/version
         # semantics are unchanged.
+        # the handler starts: what went before since the frame's
+        # arrival (t_req) was a wait for the loop, not decode work
+        t_start = time.monotonic()
         if t_req is None:  # callers without an rx stamp
-            t_req = time.monotonic()
+            t_req = t_start
         req = None
         native_path = False
         if produce_fast.native_ready():
@@ -653,7 +656,7 @@ class KafkaServer:
                 hdr, req = nat
                 native_path = True
                 t_decoded = time.monotonic()
-                self.probe.decode[(0, True)](t_decoded - t_req)
+                self.probe.decode[(0, True)](t_decoded - t_start)
         if req is None:
             r = Reader(frame)
             hdr = decode_request_header(r)
@@ -720,11 +723,11 @@ class KafkaServer:
                 if req is None:
                     req = api.decode_request(body_mv, hdr.api_version)
                 t_decoded = time.monotonic()
-                self.probe.decode[(0, False)](t_decoded - t_req)
+                self.probe.decode[(0, False)](t_decoded - t_start)
             else:
                 req = api.decode_request(body_mv, hdr.api_version)
                 if hdr.api_key == 1:
-                    self.probe.decode[(1, False)](time.monotonic() - t_req)
+                    self.probe.decode[(1, False)](time.monotonic() - t_start)
         probe_key = (
             (hdr.api_key, native_path) if hdr.api_key in (0, 1) else None
         )
@@ -758,8 +761,12 @@ class KafkaServer:
                     root.tag(open=self._produce_open)
                     self._produce_open += 1
                     ctx.produce_open += 1
+                    t_start_ns = int(t_start * 1e9)
                     trace.record(
-                        "produce.decode", "run", root.start_ns,
+                        "produce.rx_wait", "wait", root.start_ns, t_start_ns
+                    )
+                    trace.record(
+                        "produce.decode", "run", t_start_ns,
                         int(t_decoded * 1e9),
                     )
                     # the replicate stages run under the wait for the

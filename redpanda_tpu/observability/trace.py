@@ -22,9 +22,11 @@ on something else: a future, an RPC, a thread's fsync).
 
 Besides its tree, every finished span feeds the process-global
 `WINDOW` store: per span name a count, total and self time and a
-latency histogram; a loop-lag histogram from one `LoopLagProbe` per
-event loop; and, while the device plane runs at full fidelity
-(`RP_DEVPLANE_SAMPLE=1`), the raw span records. The store rides
+latency histogram; from one `LoopLagProbe` per event loop a loop-lag
+histogram and what the loop's selector saw (passes, seconds awake and
+asleep, how late a timer woke it); and, while the device plane runs at
+full fidelity (`RP_DEVPLANE_SAMPLE=1`), the raw span records and the
+loop's sleep intervals. The store rides
 `devplane.reset()` / `devplane.status()` (keys `host`, `loop`, `spans`,
 `spans_dropped`), which is how a benchmark window reads it.
 
@@ -39,8 +41,10 @@ from __future__ import annotations
 
 import asyncio
 import itertools
+import math
 import os
 import time
+from array import array
 from collections import deque
 from contextvars import ContextVar
 from typing import Optional
@@ -517,6 +521,8 @@ class WindowStore:
     and one device)."""
 
     RAW_CAP = 1 << 18
+    # sleep intervals a traced window keeps (two stamps each)
+    SLEEPS_CAP = 1 << 19
 
     def __init__(self) -> None:
         # raw records are kept only at the device plane's full
@@ -531,6 +537,20 @@ class WindowStore:
         self.dropped = 0
         self._lag = HistogramChild()
         self._lag_max = 0.0
+        # the loop probe's selector hook (LoopLagProbe._hook) adds here:
+        # passes, nanoseconds outside and inside a sleeping select, how
+        # late a timer's wake-up came, and in a traced window every
+        # sleep as [start_ns, end_ns] (never among the span records)
+        self.passes = 0
+        self.awake_ns = 0
+        self.asleep_ns = 0
+        self.wake_late = HistogramChild()
+        # the same wake-ups past the timeout rounded up to a whole
+        # millisecond, as epoll waits: what its rounding does not explain
+        self.wake_late_rest = HistogramChild()
+        self.sleeps = array("q")
+        self.sleeps_dropped = 0
+        self.loop_mark = time.monotonic_ns()
 
     def add(self, s: Span, self_ns: int) -> None:
         a = self._agg.get(s.name)
@@ -555,6 +575,22 @@ class WindowStore:
             self._lag_max = seconds
 
     def status(self) -> dict:
+        loop = {
+            "samples": self._lag._count,
+            "lag_p50_ms": self._lag.quantile(0.50) * 1e3,
+            "lag_p99_ms": self._lag.quantile(0.99) * 1e3,
+            "lag_max_ms": self._lag_max * 1e3,
+            "passes": self.passes,
+            "awake_s": self.awake_ns / 1e9,
+            "asleep_s": self.asleep_ns / 1e9,
+            "wake_late_p50_ms": self.wake_late.quantile(0.50) * 1e3,
+            "wake_late_p99_ms": self.wake_late.quantile(0.99) * 1e3,
+            "wake_late_count": self.wake_late._count,
+            "wake_late_rest_p50_ms": self.wake_late_rest.quantile(0.50) * 1e3,
+            "sleeps_dropped": self.sleeps_dropped,
+        }
+        if self.keep_raw:
+            loop["sleeps"] = self.sleeps.tolist()
         return {
             "host": {
                 name: {
@@ -567,12 +603,7 @@ class WindowStore:
                 }
                 for name, (kind, n, total, own, h) in sorted(self._agg.items())
             },
-            "loop": {
-                "samples": self._lag._count,
-                "lag_p50_ms": self._lag.quantile(0.50) * 1e3,
-                "lag_p99_ms": self._lag.quantile(0.99) * 1e3,
-                "lag_max_ms": self._lag_max * 1e3,
-            },
+            "loop": loop,
             "spans": list(self._raw),
             "spans_dropped": self.dropped,
         }
@@ -584,8 +615,10 @@ WINDOW = WindowStore()
 class LoopLagProbe:
     """How long ready work waits for the one thread everything shares:
     a timer due every 10 ms records how late it ran (Seastar's reactor
-    stall detector, as a histogram). One per event loop, refcounted
-    across the brokers that share the loop."""
+    stall detector, as a histogram). And the loop traced from inside:
+    its selector's `select` is shadowed so that each pass of the loop
+    says whether it slept and for how long (`_hook`). One per event
+    loop, refcounted across the brokers that share the loop."""
 
     INTERVAL_S = 0.010
     _by_loop: dict = {}
@@ -595,6 +628,7 @@ class LoopLagProbe:
         self._refs = 0
         self._handle: Optional[asyncio.TimerHandle] = None
         self._due = 0.0
+        self._selector = None
 
     @classmethod
     def acquire(cls) -> None:
@@ -613,6 +647,7 @@ class LoopLagProbe:
         probe._refs += 1
         if probe._refs == 1:
             probe._arm()
+            probe._hook()
 
     @classmethod
     def release(cls) -> None:
@@ -626,7 +661,57 @@ class LoopLagProbe:
         if probe._refs <= 0:
             if probe._handle is not None:
                 probe._handle.cancel()
+            probe._unhook()
             del cls._by_loop[loop]
+
+    def _hook(self) -> None:
+        """Shadow the selector's bound `select` on the instance. Per
+        pass: the time since the last `select` returned is awake; the
+        time inside a `select` with a timeout other than 0 is asleep (a
+        `select(0)` is a poll, and counts as awake); a sleep that ended
+        with no event was a timer's wake-up, and how far past the
+        timeout asyncio asked for (before epoll rounds it up to a whole
+        millisecond) it returned goes to `wake_late`, how far past the
+        timeout rounded up to a whole millisecond to `wake_late_rest`.
+        The clock is the spans' own, so a reader aligns both with one
+        offset."""
+        sel = getattr(self._loop, "_selector", None)
+        if sel is None:
+            return  # not a selector loop: nothing to shadow
+        self._selector = sel
+        inner = sel.select
+        w, clock, ceil = WINDOW, time.monotonic_ns, math.ceil
+        cap = 2 * WindowStore.SLEEPS_CAP
+
+        def select(timeout=None):
+            t0 = clock()
+            w.awake_ns += t0 - w.loop_mark
+            w.passes += 1
+            events = inner(timeout)
+            if timeout == 0:
+                w.loop_mark = t0
+                return events
+            t1 = w.loop_mark = clock()
+            w.asleep_ns += t1 - t0
+            if not events and timeout is not None:
+                slept = (t1 - t0) / 1e9
+                w.wake_late.observe(slept - timeout)
+                w.wake_late_rest.observe(slept - ceil(timeout * 1e3) / 1e3)
+            if w.keep_raw:
+                if len(w.sleeps) < cap:
+                    w.sleeps.append(t0)
+                    w.sleeps.append(t1)
+                else:
+                    w.sleeps_dropped += 1
+            return events
+
+        w.loop_mark = clock()
+        sel.select = select
+
+    def _unhook(self) -> None:
+        sel, self._selector = self._selector, None
+        if sel is not None:
+            del sel.select  # the class's bound method again
 
     def _arm(self) -> None:
         self._due = self._loop.time() + self.INTERVAL_S

@@ -18,22 +18,27 @@ from __future__ import annotations
 
 import asyncio
 import os
+import time
 from typing import Optional
+
+from ..observability import trace
 
 
 def _fsync_all(
     fds: list[int],
-) -> list[tuple[Optional[BaseException], float]]:
-    import time
-
-    out: list[tuple[Optional[BaseException], float]] = []
+) -> list[tuple[Optional[BaseException], int, int]]:
+    """On the executor's thread: fsync each fd, with the monotonic_ns
+    stamps around each syscall. The loop records them as the round's
+    `storage.fsync` span once the await resumes (the span store is the
+    loop's alone) and feeds the EWMA from the same pair."""
+    out: list[tuple[Optional[BaseException], int, int]] = []
     for fd in fds:
-        t0 = time.perf_counter()
+        t0 = time.monotonic_ns()
         try:
             os.fsync(fd)
-            out.append((None, time.perf_counter() - t0))
+            out.append((None, t0, time.monotonic_ns()))
         except BaseException as e:  # per-fd isolation
-            out.append((e, time.perf_counter() - t0))
+            out.append((e, t0, time.monotonic_ns()))
     return out
 
 
@@ -69,17 +74,16 @@ class FlushCoalescer:
     _ewma_s = 0.0
 
     async def fsync(self, fd: int) -> None:
-        import time
-
         if FlushCoalescer._ewma_s < self.INLINE_THRESHOLD_S:
-            t0 = time.perf_counter()
+            t0 = time.monotonic_ns()
             os.fsync(fd)
-            dt = time.perf_counter() - t0
-            FlushCoalescer._ewma_s += 0.2 * (dt - FlushCoalescer._ewma_s)
+            t1 = time.monotonic_ns()
+            trace.record("storage.fsync", "run", t0, t1, path="inline", fds=1)
+            FlushCoalescer._ewma_s += 0.2 * ((t1 - t0) / 1e9 - FlushCoalescer._ewma_s)
             return
         loop = asyncio.get_event_loop()
         fut = loop.create_future()
-        self._pending.append((fd, fut))
+        self._pending.append((fd, fut, trace.current_span()))
         if not self._running:
             self._running = True
             asyncio.ensure_future(self._run())
@@ -93,7 +97,7 @@ class FlushCoalescer:
                 # dedupe: several waiters on one fd need one fsync
                 order: list[int] = []
                 seen: set[int] = set()
-                for fd, _ in batch:
+                for fd, _, _ in batch:
                     if fd not in seen:
                         seen.add(fd)
                         order.append(fd)
@@ -101,19 +105,26 @@ class FlushCoalescer:
                     results = await loop.run_in_executor(
                         None, _fsync_all, order
                     )
-                    by_fd = dict(zip(order, results))
-                    for _, dt in results:
+                    by_fd = {fd: err for fd, (err, _, _) in zip(order, results)}
+                    for _, t0, t1 in results:
                         FlushCoalescer._ewma_s += 0.2 * (
-                            dt - FlushCoalescer._ewma_s
+                            (t1 - t0) / 1e9 - FlushCoalescer._ewma_s
                         )
+                    # back on the loop: the round's syscalls as one span,
+                    # under the first waiter's span (still open: its
+                    # future is settled below)
+                    trace.record(
+                        "storage.fsync", "wait", results[0][1], results[-1][2],
+                        parent=batch[0][2], path="executor", fds=len(order),
+                    )
                 except asyncio.CancelledError:
                     raise  # teardown must propagate, not land in futures
                 except BaseException as e:  # executor itself failed
-                    by_fd = {fd: (e, 0.0) for fd in order}
-                for fd, fut in batch:
+                    by_fd = {fd: e for fd in order}
+                for fd, fut, _ in batch:
                     if fut.done():
                         continue
-                    err, _dt = by_fd.get(fd, (None, 0.0))
+                    err = by_fd.get(fd)
                     if err is None:
                         fut.set_result(None)
                     else:

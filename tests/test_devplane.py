@@ -409,8 +409,13 @@ d = st["host"]["device.dispatch"]
 assert d["count"] == st["kernels"]["t.kern"]["count"] == TIMED, d
 assert d["kind"] == "run" and d["total_s"] > 0
 assert st["host"]["outer"]["self_s"] <= st["host"]["outer"]["total_s"]
+# the lag timer's digest, the selector hook's counters and, in a traced
+# window, the loop's sleep intervals
 assert set(st["loop"]) == {
-    "samples", "lag_p50_ms", "lag_p99_ms", "lag_max_ms"}
+    "samples", "lag_p50_ms", "lag_p99_ms", "lag_max_ms",
+    "passes", "awake_s", "asleep_s", "wake_late_p50_ms", "wake_late_p99_ms",
+    "wake_late_count", "wake_late_rest_p50_ms", "sleeps_dropped",
+} | ({"sleeps"} if SAMPLE == 1 else set()), sorted(st["loop"])
 assert st["spans_dropped"] == 0
 if SAMPLE == 1:
     rows = [s for s in st["spans"] if s[0] == "device.dispatch"]

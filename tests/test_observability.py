@@ -1005,9 +1005,235 @@ def test_loop_lag_probe_sees_a_blocked_loop_and_is_shared(tmp_path, window):
     assert loop not in trace.LoopLagProbe._by_loop and probe._refs == 0
 
 
-async def _one_broker_produces(tmp_path, window, counts):
+# -- the loop traced from inside: the probe's selector hook (PR 37) -------
+
+
+def _quiet_probe(loop):
+    """Acquire the loop's probe and cancel its own 10 ms timer, so that
+    the only timers a case sees are its own."""
+    trace.LoopLagProbe.acquire()
+    trace.LoopLagProbe._by_loop[loop]._handle.cancel()
+
+
+@needs_trace
+def test_loop_probe_counts_work_as_awake_and_a_sleep_as_asleep(window):
+    import time as _time
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        _quiet_probe(loop)
+        try:
+            window.reset()
+            loop.call_soon(_time.sleep, 0.02)  # a callback holds the loop
+            await asyncio.sleep(0.001)
+            held = dict(window.status()["loop"])
+            window.reset()
+            await asyncio.sleep(0.05)
+            await asyncio.sleep(0)  # one more pass: its select's entry
+            return held, window.status()["loop"]
+        finally:
+            trace.LoopLagProbe.release()
+
+    held, slept = asyncio.run(main())
+    assert held["awake_s"] >= 0.02 and held["asleep_s"] < held["awake_s"]
+    assert slept["asleep_s"] >= 0.05 and slept["awake_s"] < slept["asleep_s"]
+    assert held["passes"] >= 1 and slept["passes"] >= 1
+
+
+@needs_trace
+@pytest.mark.skipif(not hasattr(__import__("selectors"), "EpollSelector"),
+                    reason="epoll is Linux's")
+def test_a_timer_under_a_millisecond_wakes_a_whole_millisecond_late_under_epoll(window):
+    import selectors
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        assert isinstance(loop._selector, selectors.EpollSelector)
+        _quiet_probe(loop)
+        try:
+            window.reset()
+            for _ in range(5):
+                due = loop.create_future()
+                loop.call_later(0.0003, due.set_result, None)
+                await due
+            return window.status()["loop"]
+        finally:
+            trace.LoopLagProbe.release()
+
+    with asyncio.Runner(loop_factory=lambda: asyncio.SelectorEventLoop(
+            selectors.EpollSelector())) as runner:
+        got = runner.run(main())
+    # epoll waits whole milliseconds: 0.3 ms asked for is 1 ms slept
+    assert got["wake_late_count"] >= 1
+    assert got["wake_late_p50_ms"] >= 0.6
+    # what is past the whole millisecond is the rest of the lateness
+    assert got["wake_late_rest_p50_ms"] <= got["wake_late_p50_ms"]
+
+
+@needs_trace
+def test_the_probe_shadows_select_once_and_the_last_release_restores_it(window):
+    async def main():
+        sel = asyncio.get_running_loop()._selector
+        original = sel.select
+        trace.LoopLagProbe.acquire()
+        trace.LoopLagProbe.acquire()  # two brokers on one loop: one hook
+        hooked = sel.select
+        assert "select" in vars(sel) and hooked != original
+        trace.LoopLagProbe.release()
+        assert sel.select is hooked
+        trace.LoopLagProbe.release()
+        assert "select" not in vars(sel) and sel.select == original
+
+    asyncio.run(main())
+
+
+def test_rp_trace_off_installs_no_selector_hook(monkeypatch):
+    monkeypatch.setattr(trace, "ENABLED", False)
+
+    async def main():
+        sel = asyncio.get_running_loop()._selector
+        before = trace.WINDOW.status()["loop"]["passes"]
+        trace.LoopLagProbe.acquire()
+        assert "select" not in vars(sel)
+        await asyncio.sleep(0.01)
+        assert trace.WINDOW.status()["loop"]["passes"] == before
+        trace.LoopLagProbe.release()
+
+    asyncio.run(main())
+
+
+@needs_trace
+def test_sleeps_are_kept_in_a_traced_window_alone_and_never_as_spans(window, monkeypatch):
+    async def main():
+        _quiet_probe(asyncio.get_running_loop())
+        try:
+            window.keep_raw = False
+            window.reset()
+            await asyncio.sleep(0.02)
+            untraced = window.status()
+            window.keep_raw = True
+            window.reset()
+            with span("t.request", "wait"):
+                await asyncio.sleep(0.02)
+            return untraced, window.status()
+        finally:
+            trace.LoopLagProbe.release()
+
+    untraced, traced = asyncio.run(main())
+    assert "sleeps" not in untraced["loop"] and untraced["spans"] == []
+    assert untraced["loop"]["asleep_s"] >= 0.015
+    loop = traced["loop"]
+    pairs = list(zip(loop["sleeps"][0::2], loop["sleeps"][1::2]))
+    assert pairs and loop["sleeps_dropped"] == 0
+    assert all(a <= b for a, b in pairs)
+    assert sum(b - a for a, b in pairs) == round(loop["asleep_s"] * 1e9)
+    # on the spans' clock, and never among them
+    (req,) = traced["spans"]
+    assert req[0] == "t.request"
+    assert any(req[2] <= a and b <= req[2] + req[3] for a, b in pairs)
+    json.dumps(traced)
+
+    # past the cap a traced window counts what it dropped
+    monkeypatch.setattr(trace.WindowStore, "SLEEPS_CAP", 1)
+
+    async def capped():
+        _quiet_probe(asyncio.get_running_loop())
+        try:
+            window.reset()
+            for _ in range(3):
+                await asyncio.sleep(0.002)
+            return window.status()["loop"]
+        finally:
+            trace.LoopLagProbe.release()
+
+    loop = asyncio.run(capped())
+    assert len(loop["sleeps"]) == 2 and loop["sleeps_dropped"] >= 2
+
+
+@needs_trace
+def test_a_produce_that_waits_for_a_held_loop_waits_under_rx_wait(
+        tmp_path, window, monkeypatch):
+    """The frame's bytes are stamped on arrival, then another callback
+    holds the loop 50 ms before the connection's reader runs: that is
+    `produce.rx_wait`, and `produce.decode` is the decode alone."""
+    import time as _time
+
+    from redpanda_tpu.kafka import server as kserver
+
+    armed = []
+    inner = kserver._RxStampProtocol.data_received
+
+    def data_received(self, data):
+        if armed and data[4:6] == b"\x00\x00":  # a Produce request
+            armed.clear()
+            asyncio.get_running_loop().call_soon(_time.sleep, 0.05)
+        inner(self, data)
+
+    monkeypatch.setattr(kserver._RxStampProtocol, "data_received", data_received)
+    (rows,) = asyncio.run(_one_broker_produces(
+        tmp_path, window, [1], before=lambda: armed.append(1)))
+    by_name = {s[0]: s for s in rows}
+    root, rx = by_name["kafka.produce"], by_name["produce.rx_wait"]
+    decode = by_name["produce.decode"]
+    assert not armed
+    assert rx[1] == "wait" and decode[1] == "run"
+    assert rx[3] >= 50e6 and decode[3] < 5e6
+    # together they cover what the old decode span did: arrival to decoded
+    assert rx[2] == root[2] and decode[2] == rx[2] + rx[3]
+
+
+@needs_trace
+@pytest.mark.parametrize("path", ["inline", "executor"])
+def test_storage_fsync_is_recorded_on_the_loop_on_both_paths(
+        tmp_path, window, monkeypatch, path):
+    import threading
+
+    from redpanda_tpu.storage.flush_coalescer import FlushCoalescer
+
+    monkeypatch.setattr(FlushCoalescer, "INLINE_THRESHOLD_S",
+                        1.0 if path == "inline" else 0.0)
+    monkeypatch.setattr(FlushCoalescer, "_ewma_s", 0.0)
+    threads = []
+    add = window.add
+
+    def spy(s, self_ns):
+        if s.name == "storage.fsync":
+            threads.append(threading.get_ident())
+        add(s, self_ns)
+
+    monkeypatch.setattr(window, "add", spy)
+
+    async def main():
+        fds = [os.open(tmp_path / f"seg{i}", os.O_CREAT | os.O_WRONLY)
+               for i in range(2)]
+        try:
+            with span("t.flush", "wait") as parent:
+                await asyncio.gather(
+                    *(FlushCoalescer.get().fsync(fd) for fd in fds))
+            return parent, threading.get_ident()
+        finally:
+            for fd in fds:
+                os.close(fd)
+
+    parent, loop_thread = asyncio.run(main())
+    rows = _raw(window, "storage.fsync")
+    if path == "inline":
+        assert [(s[1], s[7]) for s in rows] == [
+            ("run", {"path": "inline", "fds": 1})] * 2
+    else:  # one executor round for both descriptors
+        assert [(s[1], s[7]) for s in rows] == [
+            ("wait", {"path": "executor", "fds": 2})]
+    assert all(s[5] == parent.span_id and s[3] >= 0 for s in rows)
+    # the span store is touched from the loop's thread alone
+    assert threads == [loop_thread] * len(rows)
+    # the EWMA that picks the path reads the same stamps
+    assert FlushCoalescer._ewma_s > 0.0
+
+
+async def _one_broker_produces(tmp_path, window, counts, before=None):
     """The raw spans of one produce each of `counts` records through a
-    one-broker cluster, by trace id."""
+    one-broker cluster, by trace id (`before()` is called ahead of
+    each)."""
     from redpanda_tpu.models.record import RecordBatchBuilder
 
     out = []
@@ -1022,6 +1248,8 @@ async def _one_broker_produces(tmp_path, window, counts):
                     b.add(b"v" * 64, key=b"k%d" % i)
                 wire = b.build().to_kafka_wire()
                 window.reset()
+                if before is not None:
+                    before()
                 await client.produce_wire("spans", 0, wire, acks=-1)
                 await asyncio.sleep(0.02)  # on_written runs after the ack
                 rows = window.status()["spans"]
@@ -1041,6 +1269,7 @@ def test_produce_is_one_tree_across_the_layer_boundaries(tmp_path, window):
               for s in rows}
     assert parent == {
         "kafka.produce": None,
+        "produce.rx_wait": "kafka.produce",
         "produce.decode": "kafka.produce",
         "produce.dispatch": "kafka.produce",
         "produce.ack_wait": "kafka.produce",
@@ -1048,18 +1277,26 @@ def test_produce_is_one_tree_across_the_layer_boundaries(tmp_path, window):
         "raft.append": "produce.ack_wait",
         "storage.append": "raft.append",
         "raft.flush": "produce.ack_wait",
+        "storage.fsync": "raft.flush",
         "storage.flush": "raft.flush",
         "raft.quorum_wait": "produce.ack_wait",
     }
     assert len({s[6] for s in rows}) == 1  # one trace id
     kinds = {s[0]: s[1] for s in rows}
+    # the fsync holds the loop where it runs inline, else it is a wait
+    fsync = by_name["storage.fsync"]
+    assert kinds.pop("storage.fsync") == (
+        "run" if fsync[7]["path"] == "inline" else "wait")
     assert [n for n, k in kinds.items() if k == "wait"] == [
         n for n in kinds if n in (
-            "kafka.produce", "produce.ack_wait", "raft.coalesce",
-            "raft.flush", "storage.flush", "raft.quorum_wait")]
-    # the root runs from the frame's arrival: decode starts with it
+            "kafka.produce", "produce.rx_wait", "produce.ack_wait",
+            "raft.coalesce", "raft.flush", "storage.flush",
+            "raft.quorum_wait")]
+    # the root runs from the frame's arrival: the wait for the loop
+    # starts with it and the decode where the handler starts
     root = by_name["kafka.produce"]
-    assert by_name["produce.decode"][2] == root[2]
+    rx, decode = by_name["produce.rx_wait"], by_name["produce.decode"]
+    assert rx[2] == root[2] and decode[2] == rx[2] + rx[3]
     for s in rows:
         assert s[2] >= root[2] and s[2] + s[3] <= root[2] + root[3]
 
