@@ -458,9 +458,11 @@ class TxCoordinator:
                     ntp, meta.pid, meta.epoch, commit, deadline
                 )
             for group in sorted(meta.groups):
-                await self._marker_to_group(
-                    group, meta.pid, meta.epoch, commit, deadline
-                )
+                # sent -> the group coordinator's write acknowledged
+                with trace.span("tx.group_marker", "wait"):
+                    await self._marker_to_group(
+                        group, meta.pid, meta.epoch, commit, deadline
+                    )
         done = dataclasses.replace(
             meta,
             status=TX_EMPTY,
@@ -639,6 +641,13 @@ class TxCoordinator:
             return 0
 
     async def add_offsets(
+        self, tx_id: str, pid: int, epoch: int, group: str
+    ) -> int:
+        # root: the request as its coordinator serves it
+        with trace.span("tx.add_offsets", "wait", group=group):
+            return await self._add_offsets(tx_id, pid, epoch, group)
+
+    async def _add_offsets(
         self, tx_id: str, pid: int, epoch: int, group: str
     ) -> int:
         shard = await self._shard_for(tx_id)

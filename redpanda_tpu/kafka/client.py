@@ -991,6 +991,7 @@ class GroupClient:
         self.group_id = group_id
         self.member_id = ""
         self.generation = -1
+        self.group_instance_id: str | None = None
         self._coord: Optional[BrokerConnection] = None
 
     async def coordinator(self, refresh: bool = False) -> BrokerConnection:
@@ -1098,6 +1099,7 @@ class GroupClient:
         # consumer state machine, never raced on one GroupClient
         self.member_id = resp.member_id  # rplint: disable=RPL015
         self.generation = resp.generation_id
+        self.group_instance_id = group_instance_id
         return resp
 
     async def sync(self, assignments: list[tuple[str, bytes]]) -> bytes:
@@ -1202,12 +1204,25 @@ class GroupClient:
                     )
 
     async def fetch_offsets(
-        self, topics: dict[str, list[int]] | None = None
+        self,
+        topics: dict[str, list[int]] | None = None,
+        require_stable: bool = False,
     ) -> dict[tuple[str, int], int]:
+        """The group's committed offsets. With `require_stable`
+        (OffsetFetch v7, KIP-447: how an exactly-once member resumes) a
+        partition that a transaction has staged offsets for, not yet
+        settled by its marker, raises UNSTABLE_OFFSET_COMMIT for the
+        caller to ask again, where a plain fetch would hand back the
+        offset committed before them."""
         from .protocol.group_apis import OFFSET_FETCH
 
         conn = await self.coordinator()
-        v = conn.pick_version(OFFSET_FETCH, 3)
+        v = conn.pick_version(OFFSET_FETCH, 7 if require_stable else 3)
+        if require_stable and v < 7:
+            raise KafkaClientError(
+                int(ErrorCode.unsupported_version),
+                "broker too old for require_stable (OffsetFetch v7)",
+            )
         req = Msg(
             group_id=self.group_id,
             topics=(
@@ -1218,6 +1233,7 @@ class GroupClient:
                     for t, ps in topics.items()
                 ]
             ),
+            require_stable=require_stable,
         )
         resp = await self._coord_request(OFFSET_FETCH, req, v)
         if getattr(resp, "error_code", 0) != 0:
@@ -1225,6 +1241,11 @@ class GroupClient:
         out = {}
         for t in resp.topics:
             for p in t.partitions:
+                if p.error_code:
+                    raise KafkaClientError(
+                        p.error_code,
+                        f"offset_fetch {self.group_id} {t.name}/{p.partition_index}",
+                    )
                 if p.committed_offset >= 0:
                     out[(t.name, p.partition_index)] = p.committed_offset
         return out
@@ -1293,6 +1314,9 @@ class TransactionalProducer:
         self._seqs: dict[tuple[str, int], int] = {}
         self._in_tx: set[tuple[str, int]] = set()
         self._coord: Optional[BrokerConnection] = None
+        # one per group id, so its coordinator is found once and not
+        # again on every transaction
+        self._groups: dict[str, GroupClient] = {}
 
     async def _coordinator(self, refresh: bool = False) -> BrokerConnection:
         from .protocol.group_apis import FIND_COORDINATOR
@@ -1446,10 +1470,18 @@ class TransactionalProducer:
         )
 
     async def send_offsets(
-        self, group_id: str, offsets: dict[tuple[str, int], int]
+        self,
+        group_id: str,
+        offsets: dict[tuple[str, int], int],
+        member: "GroupClient | None" = None,
     ) -> None:
         """Commit consumer offsets within the transaction
-        (AddOffsetsToTxn + TxnOffsetCommit to the group coordinator)."""
+        (AddOffsetsToTxn + TxnOffsetCommit to the group coordinator).
+        `member` is the consumer's membership of the group (KIP-447's
+        group metadata): its generation, member id and instance id go
+        out in TxnOffsetCommit v3, so that the coordinator refuses the
+        offsets of a member a rebalance has left behind. Without it the
+        request is v2, which the coordinator does not fence by member."""
         from .protocol.tx_apis import ADD_OFFSETS_TO_TXN, TXN_OFFSET_COMMIT
 
         conn = await self._coordinator()
@@ -1467,9 +1499,16 @@ class TransactionalProducer:
         if resp.error_code != 0:
             raise KafkaClientError(resp.error_code, "add_offsets_to_txn")
         # stage the offsets at the GROUP coordinator
-        gc = GroupClient(self.client, group_id)
+        gc = self._groups.get(group_id)
+        if gc is None:
+            gc = self._groups[group_id] = GroupClient(self.client, group_id)
         gconn = await gc.coordinator()
-        v = gconn.pick_version(TXN_OFFSET_COMMIT, 2)
+        v = gconn.pick_version(TXN_OFFSET_COMMIT, 2 if member is None else 3)
+        if member is not None and v < 3:
+            raise KafkaClientError(
+                int(ErrorCode.unsupported_version),
+                "broker too old for the group's metadata (TxnOffsetCommit v3)",
+            )
         by_topic: dict[str, list[Msg]] = {}
         for (topic, part), off in offsets.items():
             by_topic.setdefault(topic, []).append(
@@ -1484,6 +1523,9 @@ class TransactionalProducer:
             group_id=group_id,
             producer_id=self.pid,
             producer_epoch=self.epoch,
+            generation_id=-1 if member is None else member.generation,
+            member_id="" if member is None else member.member_id,
+            group_instance_id=None if member is None else member.group_instance_id,
             topics=[Msg(name=t, partitions=ps) for t, ps in by_topic.items()],
         )
         deadline = asyncio.get_event_loop().time() + 10.0

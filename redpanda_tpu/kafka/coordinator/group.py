@@ -20,6 +20,7 @@ import time
 import uuid
 from typing import Optional
 
+from ...observability import devplane
 from ..protocol import ErrorCode
 
 
@@ -356,6 +357,7 @@ class Group:
     def _complete_rebalance(self) -> None:
         if self.state != GroupState.PREPARING_REBALANCE:
             return
+        devplane.count_group("rebalances")
         if not self.members:
             self.state = GroupState.EMPTY
             self.generation += 1

@@ -18,6 +18,7 @@ from typing import TYPE_CHECKING, Optional
 
 from ...models.fundamental import DEFAULT_NS, NTP
 from ...models.record import RecordBatch, RecordBatchBuilder, RecordBatchType
+from ...observability import devplane, trace
 from ...raft.consensus import NotLeaderError, ReplicateTimeout
 from ...utils import serde
 from ...utils.locks import LockMap
@@ -36,6 +37,19 @@ _KIND_GROUP_META = 0
 _KIND_OFFSET = 1
 _KIND_TX_OFFSET = 2  # staged, invisible until the tx commits
 _KIND_TX_MARKER = 3  # commit/abort decision for a pid's staged offsets
+
+
+#: what a TxnOffsetCommit is refused for when it comes from a member or
+#: a producer that is no longer the live one
+_FENCED = frozenset(
+    int(c)
+    for c in (
+        ErrorCode.fenced_instance_id,
+        ErrorCode.unknown_member_id,
+        ErrorCode.illegal_generation,
+        ErrorCode.invalid_producer_epoch,
+    )
+)
 
 
 class CoordinatorLoading(Exception):
@@ -596,16 +610,62 @@ class GroupCoordinator:
         pid: int,
         epoch: int,
         items: list[tuple[str, int, int, str | None]],  # topic, part, off, md
+        generation: int = -1,
+        member_id: str = "",
+        group_instance_id: str | None = None,
     ) -> int:
         """Stage transactional offsets (group.cc store_txn_offsets):
         replicated so failover keeps them, but invisible to OffsetFetch
         until the tx coordinator delivers a commit marker at the same
-        producer epoch. Zombie epochs are fenced."""
+        producer epoch. Zombie epochs are fenced, and so (TxnOffsetCommit
+        v3, KIP-447) is a member the group no longer has: the default
+        generation -1, member "" and no instance id of v0-2 skip those
+        checks, as Kafka's coordinator does."""
+        # root: the request as the group coordinator serves it
+        with trace.span(
+            "group.txn_offset_commit", "wait",
+            partitions=len(items), generation=generation,
+        ):
+            code = await self._txn_commit_offsets(
+                g, pid, epoch, items, generation, member_id, group_instance_id
+            )
+        if code in _FENCED:
+            devplane.count_group("txn_offset_commits_fenced")
+        return code
+
+    def _member_fence(
+        self, g: Group, generation: int, member_id: str, instance: str | None
+    ) -> int:
+        """Kafka's order: a static member's instance id held by another
+        member, a member id the group does not have, a generation that
+        is not the group's."""
+        fenced = g.check_static(instance, member_id)
+        if fenced:
+            return fenced
+        if member_id and member_id not in g.members:
+            return int(ErrorCode.unknown_member_id)
+        if generation >= 0 and generation != g.generation:
+            return int(ErrorCode.illegal_generation)
+        return 0
+
+    async def _txn_commit_offsets(
+        self,
+        g: Group,
+        pid: int,
+        epoch: int,
+        items: list[tuple[str, int, int, str | None]],
+        generation: int,
+        member_id: str,
+        group_instance_id: str | None,
+    ) -> int:
         import time as _time
 
         p = self._local_partition(g.group_id)
         if p is None:
             return int(ErrorCode.not_coordinator)
+        code = self._member_fence(g, generation, member_id, group_instance_id)
+        if code:
+            return code
         if epoch < g.tx_fences.get(pid, -1):
             return int(ErrorCode.invalid_producer_epoch)
         cur = g.pending_tx.get(pid)
@@ -631,8 +691,13 @@ class GroupCoordinator:
             return int(ErrorCode.not_coordinator)
         except ReplicateTimeout:
             return int(ErrorCode.request_timed_out)
+        cur = g.pending_tx.get(pid)
+        if cur is not None and cur[0] < epoch:
+            # a newer epoch supersedes what an older one staged
+            devplane.count_group("tx_offsets_dropped", len(cur[1]))
         for topic, part, off, md in items:
             _stage_tx_offset(g, pid, epoch, (topic, part), (off, md, now))
+        devplane.count_group("tx_offsets_staged", len(items))
         return 0
 
     async def complete_tx(
@@ -670,6 +735,13 @@ class GroupCoordinator:
             return int(ErrorCode.not_coordinator)
         except ReplicateTimeout:
             return int(ErrorCode.request_timed_out)
+        cur = g.pending_tx.get(pid)
+        if cur is not None and cur[0] <= epoch:
+            kept = commit and cur[0] == epoch
+            devplane.count_group(
+                "tx_offsets_committed" if kept else "tx_offsets_dropped",
+                len(cur[1]),
+            )
         _apply_tx_marker(g, pid, epoch, commit)
         return 0
 

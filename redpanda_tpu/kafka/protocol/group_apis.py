@@ -286,8 +286,8 @@ OFFSET_FETCH = register(
     Api(
         key=9,
         name="offset_fetch",
-        versions=(0, 5),
-        flex_since=None,  # flex at v6
+        versions=(0, 7),
+        flex_since=6,
         request=[
             F("group_id", "string"),
             F(
@@ -301,6 +301,10 @@ OFFSET_FETCH = register(
                 nullable=(2, None),
                 default=None,  # null (v2+) = all topics with offsets
             ),
+            # v7 (KIP-447): a partition with transactional offsets still
+            # pending answers UNSTABLE_OFFSET_COMMIT instead of the
+            # offset committed before them
+            F("require_stable", "bool", versions=(7, None), default=False),
         ],
         response=[
             F("throttle_time_ms", "int32", versions=(3, None)),

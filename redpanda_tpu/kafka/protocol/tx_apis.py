@@ -96,8 +96,10 @@ TXN_OFFSET_COMMIT = register(
     Api(
         key=28,
         name="txn_offset_commit",
-        versions=(0, 2),
-        flex_since=None,  # flex at v3
+        # v3 (KIP-447): the consumer group's metadata, so the group
+        # coordinator fences a member of an older generation
+        versions=(0, 3),
+        flex_since=3,
         request=[
             F("transactional_id", "string"),
             F("group_id", "string"),
@@ -105,6 +107,13 @@ TXN_OFFSET_COMMIT = register(
             F("producer_epoch", "int16"),
             F("generation_id", "int32", versions=(3, None), default=-1),
             F("member_id", "string", versions=(3, None), default=""),
+            F(
+                "group_instance_id",
+                "string",
+                versions=(3, None),
+                nullable=(3, None),
+                default=None,
+            ),
             F(
                 "topics",
                 Array(

@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import TYPE_CHECKING
 
 from ..models.fundamental import DEFAULT_NS
+from ..observability import devplane, trace
 from .protocol import ErrorCode, Msg
 from .protocol.group_apis import (
     DELETE_GROUPS,
@@ -128,20 +129,24 @@ class GroupHandlers:
         g, code = await self.coordinator.get_group(req.group_id, create=True)
         if code:
             return err(code)
-        res = await g.join(
-            member_id=req.member_id,
-            client_id=hdr.client_id or "",
-            group_instance_id=getattr(req, "group_instance_id", None),
-            client_host="",
-            session_timeout_ms=req.session_timeout_ms,
-            rebalance_timeout_ms=(
-                req.rebalance_timeout_ms
-                if req.rebalance_timeout_ms > 0
-                else req.session_timeout_ms
-            ),
-            protocol_type=req.protocol_type,
-            protocols=[(p.name, bytes(p.metadata)) for p in req.protocols],
-        )
+        # arrival to the generation's answer: mostly the wait for the
+        # other members and the rebalance timer
+        with trace.span("group.join", "wait") as sp:
+            res = await g.join(
+                member_id=req.member_id,
+                client_id=hdr.client_id or "",
+                group_instance_id=getattr(req, "group_instance_id", None),
+                client_host="",
+                session_timeout_ms=req.session_timeout_ms,
+                rebalance_timeout_ms=(
+                    req.rebalance_timeout_ms
+                    if req.rebalance_timeout_ms > 0
+                    else req.session_timeout_ms
+                ),
+                protocol_type=req.protocol_type,
+                protocols=[(p.name, bytes(p.metadata)) for p in req.protocols],
+            )
+            sp.tag(generation=res.generation, error=res.error)
         if res.error:
             return err(res.error)
         return Msg(
@@ -180,19 +185,28 @@ class GroupHandlers:
         )
         if fence:
             return Msg(throttle_time_ms=0, error_code=fence, assignment=b"")
-        res = await g.sync(
-            member_id=req.member_id,
-            generation=req.generation_id,
-            assignments=[
-                (a.member_id, bytes(a.assignment)) for a in req.assignments
-            ],
-        )
-        if res.error == 0 and g.dirty:
-            # persist the stable generation + assignments (the
-            # reference writes the group metadata batch on sync)
-            code = await self.coordinator.checkpoint_group(g)
-            if code:
-                return Msg(throttle_time_ms=0, error_code=code, assignment=b"")
+        # arrival to the assignment: a follower waits for the leader's
+        # sync; the leader's covers the group's metadata write
+        with trace.span(
+            "group.sync", "wait", generation=req.generation_id
+        ) as sp:
+            res = await g.sync(
+                member_id=req.member_id,
+                generation=req.generation_id,
+                assignments=[
+                    (a.member_id, bytes(a.assignment)) for a in req.assignments
+                ],
+            )
+            if res.error == 0 and g.dirty:
+                # persist the stable generation + assignments (the
+                # reference writes the group metadata batch on sync)
+                code = await self.coordinator.checkpoint_group(g)
+                if code:
+                    sp.tag(error=code)
+                    return Msg(
+                        throttle_time_ms=0, error_code=code, assignment=b""
+                    )
+            sp.tag(error=res.error)
         return Msg(
             throttle_time_ms=0, error_code=res.error, assignment=res.assignment
         )
@@ -310,20 +324,49 @@ class GroupHandlers:
             # retriable: the client must NOT interpret this as "no
             # committed offsets" and reset to its auto-offset policy
             return Msg(throttle_time_ms=0, topics=[], error_code=code)
+        with trace.span("group.offset_fetch") as sp:
+            topics, unstable = self._offsets_of(g, req)
+            sp.tag(unstable=unstable)
+        if unstable:
+            devplane.count_group("unstable_offset_fetches")
+        return Msg(throttle_time_ms=0, topics=topics, error_code=0)
+
+    @staticmethod
+    def _offsets_of(g, req) -> tuple[list[Msg], int]:
+        """The answer's topics, and how many of its partitions answered
+        UNSTABLE_OFFSET_COMMIT: with `require_stable` (v7, KIP-447) a
+        partition for which a transaction has staged offsets that no
+        marker has settled yet, so that a member does not resume from
+        the offset committed before them."""
         offsets = g.offsets if g is not None else {}
+        pending: set[tuple[str, int]] = set()
+        if g is not None and getattr(req, "require_stable", False):
+            for _epoch, staged in g.pending_tx.values():
+                pending.update(staged)
         if req.topics is None:
             by_topic: dict[str, list[int]] = {}
-            for topic, part in sorted(offsets):
+            for topic, part in sorted(set(offsets) | pending):
                 by_topic.setdefault(topic, []).append(part)
             wanted = [(t, ps) for t, ps in by_topic.items()]
         else:
             wanted = [(t.name, list(t.partition_indexes)) for t in req.topics]
         topics = []
+        unstable = 0
         for topic, parts in wanted:
             rows = []
             for part in parts:
                 entry = offsets.get((topic, part))
-                if entry is None:
+                if (topic, part) in pending:
+                    unstable += 1
+                    rows.append(
+                        Msg(
+                            partition_index=part,
+                            committed_offset=-1,
+                            metadata=None,
+                            error_code=int(ErrorCode.unstable_offset_commit),
+                        )
+                    )
+                elif entry is None:
                     rows.append(
                         Msg(
                             partition_index=part,
@@ -343,7 +386,7 @@ class GroupHandlers:
                         )
                     )
             topics.append(Msg(name=topic, partitions=rows))
-        return Msg(throttle_time_ms=0, topics=topics, error_code=0)
+        return topics, unstable
 
     async def describe_groups(self, hdr, req) -> Msg:
         from ..security.acl import AclOperation

@@ -134,7 +134,13 @@ class TxHandlers:
             for p in t.partitions
         ]
         code = await coordinator.txn_commit_offsets(
-            g, req.producer_id, req.producer_epoch, items
+            g,
+            req.producer_id,
+            req.producer_epoch,
+            items,
+            generation=getattr(req, "generation_id", -1),
+            member_id=getattr(req, "member_id", ""),
+            group_instance_id=getattr(req, "group_instance_id", None),
         )
         return all_errors(code)
 

@@ -117,6 +117,16 @@ _SEQUENCES = registry.counter(
     "id and a sequence), duplicate (a resent batch answered with its "
     "first offset), out_of_order (refused)",
 )
+_GROUPS = registry.counter(
+    "devplane_group_coordinator_total",
+    "group coordinator events, by event: rebalances (generation bumps), "
+    "tx_offsets_staged, tx_offsets_committed and tx_offsets_dropped "
+    "(transactional offsets, by partition), txn_offset_commits_fenced "
+    "(TxnOffsetCommit requests refused by the member, generation, "
+    "instance or producer-epoch fence), unstable_offset_fetches "
+    "(OffsetFetch requests that answered a partition "
+    "UNSTABLE_OFFSET_COMMIT)",
+)
 _TICK_TRANSFERS = registry.counter(
     "devplane_tick_transfers_total",
     "device transfers/dispatches observed on the steady tick path "
@@ -142,6 +152,7 @@ FOLDS_FAMILY = _FOLDS.name
 TRANSFER_FAMILY = _TRANSFER_BYTES.name
 STATE_SEEDS_FAMILY = _STATE_SEEDS.name
 SEQUENCES_FAMILY = _SEQUENCES.name
+GROUPS_FAMILY = _GROUPS.name
 TICK_TRANSFER_FAMILY = _TICK_TRANSFERS.name
 COMPILES_FAMILY = _COMPILES.name
 COMPILE_SECS_FAMILY = _COMPILE_SECS.name
@@ -372,6 +383,22 @@ def count_sequence(result: str) -> None:
         _SEQUENCES.inc(result=result)
 
 
+#: the events `count_group` takes, each always served (0 until it moves)
+GROUP_EVENTS = (
+    "rebalances", "tx_offsets_staged", "tx_offsets_committed",
+    "tx_offsets_dropped", "txn_offset_commits_fenced",
+    "unstable_offset_fetches",
+)
+
+
+def count_group(event: str, n: int = 1) -> None:
+    """`n` group coordinator events (`event` from GROUP_EVENTS;
+    kafka/coordinator). A produce or a transaction that names no group
+    never gets here."""
+    if ENABLED and n:
+        _GROUPS.inc(float(n), event=event)
+
+
 def count_state_seed() -> None:
     """The tick had no resident device state left and uploaded its
     lanes whole (ShardGroupArrays._fold_on_device)."""
@@ -539,6 +566,7 @@ def merged_status(snaps: list) -> dict:
     transfers: dict[str, float] = {}
     state_seeds = 0.0
     sequences: dict[str, float] = {}
+    groups: dict[str, float] = dict.fromkeys(GROUP_EVENTS, 0.0)
     tick_violations = 0.0
     compiles: dict[str, dict] = {}
     jit_cache: dict[str, float] = {}
@@ -562,6 +590,9 @@ def merged_status(snaps: list) -> dict:
                 elif fam.name == SEQUENCES_FAMILY and "result" in lab:
                     r = lab["result"]
                     sequences[r] = sequences.get(r, 0.0) + s.value
+                elif fam.name == GROUPS_FAMILY and "event" in lab:
+                    e = lab["event"]
+                    groups[e] = groups.get(e, 0.0) + s.value
                 elif fam.name == TICK_TRANSFER_FAMILY:
                     tick_violations += s.value
                 elif fam.name == COMPILES_FAMILY and "kernel" in lab:
@@ -621,6 +652,7 @@ def merged_status(snaps: list) -> dict:
         "producer_sequences": {
             k: int(v) for k, v in sorted(sequences.items())
         },
+        "group_coordinator": {k: int(v) for k, v in sorted(groups.items())},
         "tick_violations": int(tick_violations),
         "frame_ms": {
             k: _hist_digest(c) for k, c in sorted(frame_hist.items())

@@ -141,9 +141,11 @@ def test_the_traffic_is_data_over_omb_client(loaded):
 def test_the_sweeps_lay_over_the_cell_s_traffic():
     tools = os.path.join(run.HERE, "tools")
     # `sweep_omb_100_lz4*` are the codec deployment's (test_benchmark_omb_100_lz4.py),
-    # `sweep_omb_100_tx*` the exactly-once one's (test_benchmark_omb_100_tx.py)
+    # `sweep_omb_100_tx*` the exactly-once one's (test_benchmark_omb_100_tx.py),
+    # `sweep_omb_100_ctp*` the pipeline's (test_benchmark_omb_100_ctp.py)
     sweeps = sorted(f for f in os.listdir(tools) if f.startswith("sweep_omb_100")
-                    and not f.startswith(("sweep_omb_100_lz4", "sweep_omb_100_tx")))
+                    and not f.startswith(("sweep_omb_100_lz4", "sweep_omb_100_tx",
+                                          "sweep_omb_100_ctp")))
     assert len(sweeps) >= 2
     for name in sweeps:
         sweep = run.load_traffic(os.path.join(tools, name))

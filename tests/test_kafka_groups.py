@@ -412,3 +412,78 @@ def test_offset_expiration_for_empty_group(tmp_path):
                 ), "dead group never collected"
 
     asyncio.run(run())
+
+
+# -- KIP-447: OffsetFetch v7 `require_stable` ------------------------------
+
+
+async def _offset_fetch(member, version, require_stable=False):
+    """The raw answer for src/0 and src/1: (code, offset) each."""
+    from redpanda_tpu.kafka.protocol import Msg
+    from redpanda_tpu.kafka.protocol.group_apis import OFFSET_FETCH
+
+    conn = await member.coordinator()
+    resp = await conn.request(OFFSET_FETCH, Msg(
+        group_id=member.group_id,
+        topics=[Msg(name="src", partition_indexes=[0, 1])],
+        require_stable=require_stable), version)
+    assert resp.error_code == 0
+    return [(p.error_code, p.committed_offset) for t in resp.topics for p in t.partitions]
+
+
+async def _pending_then_settled(tmp_path, commit, versions):
+    from redpanda_tpu.kafka.client import TransactionalProducer
+
+    async with broker_cluster(tmp_path, 1) as brokers:
+        async with client_for(brokers) as client:
+            await client.create_topic("src", partitions=2)
+            member = client.group("g-stable")
+            await member.join(PROTO)
+            await member.sync([(member.member_id, b"")])
+            await member.commit_offsets({("src", 0): 4, ("src", 1): 8})
+            tx = TransactionalProducer(client, "tx-stable")
+            await tx.init()
+            tx.begin()
+            await tx.send_offsets("g-stable", {("src", 0): 9}, member=member)
+            pending = {v: await _offset_fetch(member, v, v >= 7) for v in versions}
+            with pytest.raises(KafkaClientError) as ei:
+                await member.fetch_offsets({"src": [0, 1]}, require_stable=True)
+            assert ei.value.code == int(ErrorCode.unstable_offset_commit)
+            await (tx.commit() if commit else tx.abort())
+            settled = {v: await _offset_fetch(member, v, v >= 7) for v in versions}
+            return pending, settled
+
+
+UNSTABLE = int(ErrorCode.unstable_offset_commit)
+
+
+@pytest.mark.parametrize("commit", [True, False], ids=["commit", "abort"])
+def test_offset_fetch_v7_require_stable_answers_unstable_until_the_marker(tmp_path, commit):
+    pending, settled = asyncio.run(_pending_then_settled(tmp_path, commit, [7]))
+    # the partition the open transaction staged is unstable, the other not
+    assert pending[7] == [(UNSTABLE, -1), (0, 8)]
+    # the marker settles it: the staged offset, or the one from before
+    assert settled[7] == [(0, 9 if commit else 4), (0, 8)]
+
+
+@pytest.mark.parametrize("version", [1, 2, 3, 4, 5, 6])
+def test_offset_fetch_below_v7_answers_as_before(tmp_path, version):
+    """No `require_stable` below v7: the offset committed before the
+    open transaction's, with no error, as before v7 existed."""
+    pending, settled = asyncio.run(_pending_then_settled(tmp_path, True, [version]))
+    assert pending[version] == [(0, 4), (0, 8)]
+    assert settled[version] == [(0, 9), (0, 8)]
+
+
+def test_offset_fetch_v7_without_require_stable_answers_as_before(tmp_path):
+    async def run():
+        async with broker_cluster(tmp_path, 1) as brokers:
+            async with client_for(brokers) as client:
+                await client.create_topic("src", partitions=2)
+                member = client.group("g-v7")
+                await member.join(PROTO)
+                await member.sync([(member.member_id, b"")])
+                await member.commit_offsets({("src", 0): 4})
+                return await _offset_fetch(member, 7, False)
+
+    assert asyncio.run(run()) == [(0, 4), (0, -1)]

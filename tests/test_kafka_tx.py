@@ -324,3 +324,145 @@ def test_a_transaction_bounds_the_lso_until_its_marker_is_committed():
     assert len(t.closing) == 1
     t.clear()
     assert t.closing == [] and t.first_unstable_offset(0) is None
+
+
+# -- KIP-447: the group's metadata in TxnOffsetCommit v3 -----------------
+
+
+def _group_of(brokers, group_id):
+    """The group as its coordinator holds it."""
+    for b in brokers:
+        gc = b.group_coordinator
+        for shard in gc._groups.values():
+            if group_id in shard:
+                return shard[group_id]
+    return None
+
+
+async def _a_member_and_a_producer(client, group_id, tx_id, instance=None):
+    from redpanda_tpu.kafka.client import GroupClient
+
+    member = GroupClient(client, group_id)
+    await member.join([("range", b"")], group_instance_id=instance)
+    await member.sync([(member.member_id, b"")])
+    tx = TransactionalProducer(client, tx_id)
+    await tx.init()
+    tx.begin()
+    return member, tx
+
+
+async def _v3_fenced(tmp_path, case):
+    import types
+
+    async with broker_cluster(tmp_path, 1) as brokers:
+        async with client_for(brokers) as client:
+            await client.create_topic("src", partitions=1, replication_factor=1)
+            member, tx = await _a_member_and_a_producer(
+                client, "g-447", "tx-447",
+                instance="i-1" if case == "fenced_instance" else None)
+            sent = {
+                "stale_generation": types.SimpleNamespace(
+                    generation=member.generation - 1, member_id=member.member_id,
+                    group_instance_id=None),
+                "unknown_member": types.SimpleNamespace(
+                    generation=member.generation, member_id="gone-member",
+                    group_instance_id=None),
+                "fenced_instance": types.SimpleNamespace(
+                    generation=member.generation, member_id="zombie-member",
+                    group_instance_id="i-1"),
+            }[case]
+            with pytest.raises(KafkaClientError) as ei:
+                await tx.send_offsets("g-447", {("src", 0): 5}, member=sent)
+            g = _group_of(brokers, "g-447")
+            # refused before anything was staged
+            assert g.pending_tx == {}
+            await tx.commit()
+            assert await member.fetch_offsets({"src": [0]}, require_stable=True) == {}
+            # the live member's own metadata is taken
+            tx.begin()
+            await tx.send_offsets("g-447", {("src", 0): 5}, member=member)
+            assert list(g.pending_tx) == [tx.pid]
+            await tx.commit()
+            assert await member.fetch_offsets({"src": [0]}, require_stable=True) == {
+                ("src", 0): 5}
+            return ei.value.code
+
+
+@pytest.mark.parametrize("case, code", [
+    ("stale_generation", ErrorCode.illegal_generation),
+    ("unknown_member", ErrorCode.unknown_member_id),
+    ("fenced_instance", ErrorCode.fenced_instance_id),
+])
+def test_txn_offset_commit_v3_fences_a_member_the_group_no_longer_has(tmp_path, case, code):
+    assert asyncio.run(_v3_fenced(tmp_path, case)) == int(code)
+
+
+async def _older_versions_are_not_fenced_by_member(tmp_path, version):
+    """v0-2 carry no group metadata: a producer outside the group stages
+    offsets into a group with a live member, as before v3 existed."""
+    from redpanda_tpu.kafka.protocol import Msg
+    from redpanda_tpu.kafka.protocol.tx_apis import ADD_OFFSETS_TO_TXN, TXN_OFFSET_COMMIT
+
+    async with broker_cluster(tmp_path, 1) as brokers:
+        async with client_for(brokers) as client:
+            await client.create_topic("src", partitions=1, replication_factor=1)
+            member, tx = await _a_member_and_a_producer(client, "g-old", "tx-old")
+            who = dict(transactional_id="tx-old", producer_id=tx.pid,
+                       producer_epoch=tx.epoch)
+            conn = await tx._coordinator()
+            resp = await conn.request(
+                ADD_OFFSETS_TO_TXN, Msg(**who, group_id="g-old"), 1)
+            assert resp.error_code == 0
+            gconn = await member.coordinator()
+            resp = await gconn.request(TXN_OFFSET_COMMIT, Msg(
+                **who, group_id="g-old",
+                topics=[Msg(name="src", partitions=[Msg(
+                    partition_index=0, committed_offset=3,
+                    committed_metadata=None)])]), version)
+            assert [p.error_code for t in resp.topics for p in t.partitions] == [0]
+            await tx.commit()
+            return await member.fetch_offsets({"src": [0]})
+
+
+@pytest.mark.parametrize("version", [0, 1, 2])
+def test_txn_offset_commit_below_v3_answers_as_before(tmp_path, version):
+    got = asyncio.run(_older_versions_are_not_fenced_by_member(tmp_path, version))
+    assert got == {("src", 0): 3}
+
+
+async def _find_coordinator_once(tmp_path, monkeypatch):
+    from redpanda_tpu.kafka import client as client_mod
+    from redpanda_tpu.kafka.protocol.group_apis import FIND_COORDINATOR
+
+    asked = []
+    request = client_mod.BrokerConnection.request
+
+    async def counted(self, api, req, version):
+        if api.key == FIND_COORDINATOR.key:
+            asked.append(req.key_type)
+        return await request(self, api, req, version)
+
+    monkeypatch.setattr(client_mod.BrokerConnection, "request", counted)
+    async with broker_cluster(tmp_path, 1) as brokers:
+        async with client_for(brokers) as client:
+            await client.create_topic("src", partitions=1, replication_factor=1)
+            tx = TransactionalProducer(client, "tx-once")
+            await tx.init()
+            # both coordinator topics made and led: from here on a
+            # lookup is answered at its first asking
+            await client.group("g-once").coordinator()
+            asked.clear()
+            for i in range(6):
+                tx.begin()
+                await tx.send_offsets("g-once", {("src", 0): i})
+                await tx.commit()
+            found = list(asked)
+            assert await client.group("g-once").fetch_offsets({"src": [0]}) == {
+                ("src", 0): 5}
+    return found
+
+
+def test_send_offsets_finds_the_group_s_coordinator_once(tmp_path, monkeypatch):
+    # six transactions: one lookup of the group's coordinator (key type
+    # 0), and none of the transaction coordinator's, which init found
+    assert asyncio.run(_find_coordinator_once(tmp_path, monkeypatch)) == [0]

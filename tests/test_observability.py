@@ -1432,3 +1432,127 @@ def test_a_pass_through_produce_and_fetch_open_no_transaction_span(tmp_path, win
     assert [s[7]["reads"] for s in rows if s[0] == "kafka.fetch"] == [1]
     assert "producer_sequences" in devplane.merged_status([]) \
         and devplane.merged_status([])["producer_sequences"] == {}
+
+
+# -- the group coordinator's spans and counters ---------------------------
+
+GROUP_SPANS = ("tx.add_offsets", "group.txn_offset_commit", "tx.group_marker",
+               "group.offset_fetch", "group.join", "group.sync")
+
+
+async def _one_broker_copies(tmp_path, window):
+    """A member joins and syncs, copies under a transaction that commits
+    its input offset, copies again under one that aborts, is refused a
+    stale generation's offsets, reads the pending offset as unstable and
+    rewinds; returns the raw spans and the devplane digest."""
+    import types
+
+    from redpanda_tpu.kafka.client import TransactionalProducer
+    from redpanda_tpu.observability import devplane
+
+    async with cluster(tmp_path, n=1) as (_net, brokers):
+        client = KafkaClient([brokers[0].kafka_advertised])
+        try:
+            await client.create_topic("src", partitions=1, replication_factor=1)
+            await client.create_topic("dst", partitions=1, replication_factor=1)
+            tx = TransactionalProducer(client, "ctp-1")
+            await tx.init()
+            devplane.reset()
+            window.keep_raw = True
+            window.reset()
+            member = client.group("ctp")
+            await member.join([("range", b"")])
+            await member.sync([(member.member_id, b"")])
+            tx.begin()
+            await tx.produce("dst", 0, [(b"k", b"copy")])
+            await tx.send_offsets("ctp", {("src", 0): 1}, member=member)
+            await tx.commit()
+            tx.begin()
+            await tx.produce("dst", 0, [(b"k", b"aborted copy")])
+            await tx.send_offsets("ctp", {("src", 0): 2}, member=member)
+            stale = types.SimpleNamespace(generation=member.generation - 1,
+                                          member_id=member.member_id,
+                                          group_instance_id=None)
+            with pytest.raises(Exception):
+                await tx.send_offsets("ctp", {("src", 0): 2}, member=stale)
+            with pytest.raises(Exception):
+                await member.fetch_offsets({"src": [0]}, require_stable=True)
+            await tx.abort()
+            assert await member.fetch_offsets({"src": [0]}, require_stable=True) == {
+                ("src", 0): 1}
+            await asyncio.sleep(0.02)
+            return window.status()["spans"], devplane.merged_status(
+                [devplane.snapshot()])
+        finally:
+            await client.close()
+
+
+@needs_trace
+def test_the_group_path_s_spans_and_counters(tmp_path, window, monkeypatch):
+    from redpanda_tpu.observability import devplane
+
+    monkeypatch.setattr(devplane, "ENABLED", True)
+    rows, digest = asyncio.run(_one_broker_copies(tmp_path, window))
+    named = {}
+    for s in rows:
+        named.setdefault(s[0], []).append(s)
+    by_id = {s[4]: s for s in rows}
+
+    def parent_of(s):
+        return by_id[s[5]][0] if s[5] in by_id else None
+
+    for name in ("tx.add_offsets", "group.txn_offset_commit", "tx.group_marker",
+                 "group.join", "group.sync"):
+        assert {s[1] for s in named[name]} == {"wait"}, name
+    assert {s[1] for s in named["group.offset_fetch"]} == {"run"}
+    # roots at their coordinators, with what the request was about
+    # (the refused send_offsets was added to its transaction first)
+    assert [s[7]["group"] for s in named["tx.add_offsets"]] == ["ctp"] * 3
+    assert all(s[5] == 0 for s in named["tx.add_offsets"] + named["group.txn_offset_commit"])
+    commits = named["group.txn_offset_commit"]
+    assert [s[7]["partitions"] for s in commits] == [1, 1, 1]
+    generation = named["group.join"][0][7]["generation"]
+    assert [s[7]["generation"] for s in commits] == [generation, generation, generation - 1]
+    # one group marker a transaction, under its markers
+    assert len(named["tx.group_marker"]) == 2
+    assert {parent_of(s) for s in named["tx.group_marker"]} == {"tx.markers"}
+    # the pending offset read as unstable once; the rewind read it settled
+    assert [s[7]["unstable"] for s in named["group.offset_fetch"]] == [1, 0]
+    assert len(named["group.join"]) == len(named["group.sync"]) == 1
+    assert digest["group_coordinator"] == {
+        "rebalances": 1, "tx_offsets_staged": 2, "tx_offsets_committed": 1,
+        "tx_offsets_dropped": 1, "txn_offset_commits_fenced": 1,
+        "unstable_offset_fetches": 1}
+    # zeroed in place by the window's reset
+    devplane.reset()
+    assert set(devplane.merged_status([devplane.snapshot()])["group_coordinator"].values()) \
+        == {0}
+
+
+@needs_trace
+def test_a_transaction_with_no_group_and_a_plain_produce_open_no_group_span(
+        tmp_path, window, monkeypatch):
+    """omb_100_tx's transaction (one partition, no offsets) and a plain
+    produce pay nothing of the group path."""
+    from redpanda_tpu.observability import devplane
+
+    monkeypatch.setattr(devplane, "ENABLED", True)
+    rows, digest = asyncio.run(_one_broker_transacts(tmp_path, window))
+
+    async def plain():
+        async with cluster(tmp_path / "plain", n=1) as (_net, brokers):
+            client = KafkaClient([brokers[0].kafka_advertised])
+            try:
+                await client.create_topic("plain", partitions=1, replication_factor=1)
+                window.reset()
+                await client.produce("plain", 0, [(None, b"v")])
+                await asyncio.sleep(0.02)
+                return window.status()["spans"]
+            finally:
+                await client.close()
+
+    rows += asyncio.run(plain())
+    devplane.reset()
+    assert "tx.markers" in {s[0] for s in rows}
+    assert not {s[0] for s in rows} & set(GROUP_SPANS)
+    assert set(digest["group_coordinator"].values()) == {0}
