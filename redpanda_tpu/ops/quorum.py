@@ -35,10 +35,12 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..models.consensus_state import SELF_SLOT, GroupState
 from ..observability import devplane
 from ..utils import compileguard
+from .health import health_reduce
 
 _I64_MIN = jnp.iinfo(jnp.int64).min
 _I64_MAX = jnp.iinfo(jnp.int64).max
@@ -258,29 +260,55 @@ TICK_READBACK_LANES = (
     "flushed_index",
     "last_seq",
 )
+# after them, one column each, the health lanes (ops.health) of the same
+# rows as the fold leaves them, under the names the host keeps them by
+TICK_HEALTH_LANES = ("health_max_lag", "health_under", "health_leaderless")
+
+
+def words_to_i64(words: jax.Array) -> jax.Array:
+    """uint32 [B, 2N] → int64 [B, N], bit for bit: each int64 is the
+    pair of 32-bit words a little-endian host's `view(np.uint32)` of it
+    gives, low word first."""
+    b = words.shape[0]
+    return lax.bitcast_convert_type(words.reshape(b, -1, 2), jnp.int64)
+
+
+def i64_to_words(lanes: jax.Array) -> jax.Array:
+    """int64 [B, N] → uint32 [B · 2N], the inverse of words_to_i64, flat:
+    the host reads it back with `view(np.int64).reshape(B, N)`. Flat,
+    because the v5e hands a 2-D readback to the host in whatever order
+    its layout keeps (a [1024, 58] one arrived with its last axis not
+    contiguous, which `view` refuses), and a 1-D one in order."""
+    return lax.bitcast_convert_type(lanes, jnp.uint32).reshape(-1)
 
 
 def resident_tick(
-    state: GroupState, packed: jax.Array
+    state: GroupState, words: jax.Array
 ) -> tuple[GroupState, jax.Array]:
     """The tick against a state that stays on the device: scatter the
     rows this fold touches into it, fold the reply window, step every
     group's commit at full width, gather what the host reads back.
 
-    `packed` is the fold's one upload, int64 [B, 11 + 5R] (bools
-    widened): column 0 the row index (an index past the last group is
-    padding and its row is dropped, not scattered), then every
+    `words` is the fold's one upload, handed to the jit as the host's
+    numpy buffer so that it crosses inside the dispatch: an int64
+    [B, 13 + 5R] buffer (bools widened) as uint32 [B, 2(13 + 5R)]
+    (`words_to_i64`): column 0 the row index (an index past the last
+    group is padding and its row is dropped, not scattered), then every
     GroupState lane of that row in field order (one column a group
-    lane, R a slot lane), then the reply window's five columns (group,
-    slot, last_dirty, last_flushed, seq; padding carries seq = i64 min,
+    lane, R a slot lane), then whether the row knows its leader and
+    whether it is allocated (read by the health reduction alone, not
+    kept), then the reply window's five columns (group, slot,
+    last_dirty, last_flushed, seq; padding carries seq = i64 min,
     which fold_replies drops). Returns the state, donated and so
-    updated in place, and the fold's one readback, int64 [B, 2 + 3R]:
-    TICK_READBACK_LANES at the same rows (a padding row reads the last
+    updated in place, and the fold's one readback, int64 [B, 5 + 3R]
+    as flat uint32 words (`i64_to_words`): TICK_READBACK_LANES and
+    TICK_HEALTH_LANES at the same rows (a padding row reads the last
     group's; the host drops it).
 
     Only the scattered rows of the result mean anything: every other
     row holds whatever its last fold left (ShardGroupArrays.device_tick
     says why that is enough)."""
+    packed = words_to_i64(words)
     b = packed.shape[0]
     rows = packed[:, 0]
     lanes, col = [], 1
@@ -289,12 +317,24 @@ def resident_tick(
         fresh = packed[:, col : col + width].reshape((b,) + lane.shape[1:])
         lanes.append(lane.at[rows].set(fresh.astype(lane.dtype), mode="drop"))
         col += width
+    leader_known, active = packed[:, col] != 0, packed[:, col + 1] != 0
     state = heartbeat_tick(
-        GroupState(*lanes), *(packed[:, col + i] for i in range(5))
+        GroupState(*lanes), *(packed[:, col + 2 + i] for i in range(5))
     )
     at = jnp.minimum(rows, state.num_groups - 1)
     back = [getattr(state, name)[at].reshape(b, -1) for name in TICK_READBACK_LANES]
-    return state, jnp.concatenate(back, axis=1)
+    health = health_reduce(
+        back[2],
+        back[0][:, 0],
+        state.is_voter[at],
+        state.is_voter_old[at],
+        state.is_leader[at],
+        leader_known,
+        active,
+    )
+    back += [health[k].astype(jnp.int64)[:, None] for k in
+             ("max_lag", "under_replicated", "leaderless")]
+    return state, i64_to_words(jnp.concatenate(back, axis=1))
 
 
 # the benchmark's device metrics find this program by the name of its

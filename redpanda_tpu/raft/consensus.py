@@ -433,7 +433,7 @@ class Consensus:
         self.arrays.voter_epoch += 1
         # a config change alters quorum shape: force the incremental
         # sweep to recompute this row even if no offsets move
-        self.arrays.quorum_dirty[row] = True
+        self.arrays.mark_quorum_dirty(row)
         self._notify_topology()
 
     def _load_snapshot(self) -> None:

@@ -125,7 +125,10 @@ class ShardGroupArrays:
         # configuration changed since the last sweep, and the SELF-slot
         # values the sweep last folded (detects local append/fsync
         # progress between ticks — the flush-clamp release)
+        # (set through mark_quorum_dirty, which raises _dirty_pending
+        # too: the device fold scans the lane only while that is up)
         self.quorum_dirty = np.zeros(g, bool)
+        self._dirty_pending = False
         self._folded_self_m = np.full(g, I64_MIN, np.int64)
         self._folded_self_f = np.full(g, I64_MIN, np.int64)
         # coarse mutation epoch over the lanes that feed heartbeat
@@ -199,10 +202,21 @@ class ShardGroupArrays:
         # device backend: the GroupState that stays on the device
         # between folds (device_tick); None until a fold seeds it
         self._resident: "GroupState | None" = None
+        # the fold's packed layout (_fold_layout) and its upload
+        # buffers, one a bucket, written again by every fold
+        self._layout: tuple | None = None
+        self._fold_bufs: dict[int, np.ndarray] = {}
 
     def touch(self) -> None:
         """Invalidate armed SAME-frame heartbeat state (see mut_epoch)."""
         self.mut_epoch += 1
+
+    def mark_quorum_dirty(self, rows) -> None:
+        """Ask the next fold to recompute `rows` (a row or an index
+        array) although no offset of theirs moves: a configuration
+        change, a row reset or moved. The one way to set the lane."""
+        self.quorum_dirty[rows] = True
+        self._dirty_pending = True
 
     # SAME-frame lanes whose writers MUST call touch(); the debug
     # fingerprint (RP_SAME_DEBUG=1) checksums exactly these, so a
@@ -269,7 +283,7 @@ class ShardGroupArrays:
         self.leader_id[row] = -1
         self.is_follower[row] = False
         self.voter_epoch += 1
-        self.quorum_dirty[row] = True
+        self.mark_quorum_dirty(row)
         self._folded_self_m[row] = I64_MIN
         self._folded_self_f[row] = I64_MIN
         self.hb_suppress[row] = 0
@@ -630,7 +644,7 @@ class ShardGroupArrays:
             arr[dst] = arr[src]
         # force a quorum recompute at dst and refresh every epoch a
         # row rewrite can invalidate (same set reset_row bumps)
-        self.quorum_dirty[dst] = True
+        self.mark_quorum_dirty(dst)
         self._folded_self_m[dst] = I64_MIN
         self._folded_self_f[dst] = I64_MIN
         self.tb_epoch += 1
@@ -1152,7 +1166,12 @@ class ShardGroupArrays:
         # is forced, and no config changed, fold only the seq guard
         # host-side and skip the device round-trip entirely
         forced = force_rows is not None and len(force_rows) > 0
-        if len(group_rows) and not forced and not self.quorum_dirty.any():
+        dirty = self._dirty_pending
+        if dirty and not self.quorum_dirty.any():
+            # every dirty row was cleared by another fold (host_tick,
+            # the mesh's) or by hand
+            dirty = self._dirty_pending = False
+        if len(group_rows) and not forced and not dirty:
             fresh = seqs > self.last_seq[group_rows, replica_slots]
             may_move = (
                 last_dirty[fresh]
@@ -1179,15 +1198,17 @@ class ShardGroupArrays:
         # config-dirtied rows plus forced rows, exactly the set
         # host_tick recomputes — the two backends must advance
         # IDENTICAL row sets (the differential tests pin this)
-        dirty_rows = np.flatnonzero(self.quorum_dirty)
-        parts = [group_rows, dirty_rows]
+        parts = [group_rows] if len(group_rows) else []
+        if dirty:
+            parts.append(np.flatnonzero(self.quorum_dirty))
         if forced:
             parts.append(np.asarray(force_rows, np.int64))
-        touched = (
-            np.unique(np.concatenate(parts))
-            if any(len(p) for p in parts)
-            else _EMPTY_ROWS
-        )
+        if not parts:
+            touched = _EMPTY_ROWS
+        elif len(parts) == 1 and len(parts[0]) == 1:
+            touched = parts[0]  # the write fold's one row
+        else:
+            touched = np.unique(np.concatenate(parts))
         before = self.commit_index[touched]
         m = max(len(group_rows), len(touched))
         bucket = 8
@@ -1203,8 +1224,9 @@ class ShardGroupArrays:
         self.touch()
         self._folded_self_m[touched] = self.match_index[touched, SELF_SLOT]
         self._folded_self_f[touched] = self.flushed_index[touched, SELF_SLOT]
-        self.quorum_dirty[:] = False
-        self._health_np_rows(touched)
+        if dirty:
+            self.quorum_dirty[:] = False
+            self._dirty_pending = False
         return touched[self.commit_index[touched] > before]
 
     def _fold_on_device(
@@ -1213,13 +1235,15 @@ class ShardGroupArrays:
         """One fold's exchange with the resident device state
         (ops.quorum.resident_tick has the two layouts): every lane of
         the `touched` rows and the reply `window`'s five columns go up
-        in one packed buffer of `bucket` rows, the five lanes a fold
-        changes come back at the same rows in one, and are written
-        into the mirrors in place. The whole lanes go up first only
-        where no resident state is left (`seed`)."""
-        import jax.numpy as jnp
-
-        from ..ops.quorum import TICK_READBACK_LANES, heartbeat_tick_jit
+        in one packed buffer of `bucket` rows, handed to the jit as
+        32-bit words so that the upload crosses inside the dispatch;
+        the five lanes a fold changes and the rows' health lanes come
+        back at the same rows in one buffer of words, and are written
+        into the mirrors in place (so the device fold leaves no health
+        refresh to the host: `_health_np_rows` is host_tick's).
+        The whole lanes go up first only where no resident state is
+        left (`seed`)."""
+        from ..ops.quorum import heartbeat_tick_jit
 
         t_up = time.monotonic_ns()
         # donated to the call: ours again only when it has returned
@@ -1228,40 +1252,84 @@ class ShardGroupArrays:
         if seed:
             state = self.to_device_state()
             devplane.count_state_seed()
-        t, r = len(touched), self.replica_slots
-        packed = np.zeros((bucket, 11 + 5 * r), np.int64)
-        packed[:t, 0] = touched
-        packed[t:, 0] = self._cap
-        col = 1
-        for name in GroupState._fields:
-            lane = getattr(self, name)
-            width = 1 if lane.ndim == 1 else r
-            packed[:t, col : col + width] = lane[touched].reshape(t, width)
-            col += width
-        m = len(window[0])
-        packed[m:, col + 2 :] = I64_MIN
-        for i, column in enumerate(window):
-            packed[:m, col + i] = column
-        up = jnp.asarray(packed)
-        devplane.count_transfer(packed.nbytes, "h2d")
+        words = self._pack_fold(touched, window, bucket)
+        devplane.count_transfer(words.nbytes, "h2d")
         trace.record(
             "tick.upload", "run", t_up, time.monotonic_ns(),
-            seed=int(seed), rows=t, replies=m, bucket=bucket,
+            seed=int(seed), rows=len(touched), replies=len(window[0]),
+            bucket=bucket,
         )
-        self._resident, back = heartbeat_tick_jit(state, up)
+        self._resident, back = heartbeat_tick_jit(state, words)
         t_back = time.monotonic_ns()
         # the fold's one readback, after its one kernel
         out = np.asarray(back)  # rplint: disable=RPL002
-        col = 0
-        for name in TICK_READBACK_LANES:
-            lane = getattr(self, name)
-            width = 1 if lane.ndim == 1 else r
-            lane[touched] = out[:t, col : col + width].reshape(
-                (t,) + lane.shape[1:]
-            )
-            col += width
+        self._unpack_fold(touched, out)
         trace.record("tick.readback", "run", t_back, time.monotonic_ns())
         devplane.count_transfer(out.nbytes, "d2h")
+
+    def _fold_layout(self) -> tuple:
+        """(upload width, ((lane, first column, width), ...) of the
+        upload, readback width, the same of the readback) in int64
+        columns: the packed layouts of ops.quorum.resident_tick at this
+        many replica slots. The upload's lanes end two columns before
+        the window, where the leader-known and allocated flags go."""
+        layout = self._layout
+        if layout is None:
+            from ..ops.quorum import TICK_HEALTH_LANES, TICK_READBACK_LANES
+
+            r = self.replica_slots
+
+            def columns(names, col):
+                out = []
+                for name in names:
+                    width = 1 if getattr(self, name).ndim == 1 else r
+                    out.append((name, col, width))
+                    col += width
+                return tuple(out), col
+
+            up, width = columns(GroupState._fields, 1)
+            back, back_width = columns(TICK_READBACK_LANES + TICK_HEALTH_LANES, 0)
+            layout = self._layout = (width + 7, up, back_width, back)
+        return layout
+
+    def _pack_fold(
+        self, touched: np.ndarray, window: tuple, bucket: int
+    ) -> np.ndarray:
+        """The fold's upload as uint32 words: the int64 rows of
+        ops.quorum.resident_tick, written into a buffer kept for the
+        bucket (the jit has copied it to the device by the time the
+        fold's readback returns, so the next fold may write it again)."""
+        width, up, _, _ = self._fold_layout()
+        packed = self._fold_bufs.get(bucket)
+        if packed is None:
+            packed = self._fold_bufs[bucket] = np.empty((bucket, width), np.int64)
+        t = len(touched)
+        packed[:t, 0] = touched
+        packed[t:, 0] = self._cap
+        for name, col, w in up:
+            packed[:t, col : col + w] = getattr(self, name)[touched].reshape(t, w)
+        col = width - 7
+        packed[:t, col] = self.leader_id[touched] >= 0
+        packed[:t, col + 1] = self.row_active[touched]
+        col += 2
+        m = len(window[0])
+        if m:
+            for i, column in enumerate(window):
+                packed[:m, col + i] = column
+        packed[m:, col : col + 2] = 0
+        packed[m:, col + 2 :] = I64_MIN
+        return packed.view(np.uint32)
+
+    def _unpack_fold(self, touched: np.ndarray, out: np.ndarray) -> None:
+        """Write the readback's words (uint32 [B · 2(5 + 3R)], flat)
+        into the mirrors at the `touched` rows, one assignment a lane:
+        the five a fold changes and the three health lanes."""
+        _, _, width, lanes = self._fold_layout()
+        t = len(touched)
+        back = out.view(np.int64).reshape(-1, width)
+        for name, col, w in lanes:
+            lane = getattr(self, name)
+            lane[touched] = back[:t, col : col + w].reshape((t,) + lane.shape[1:])
 
     def frame_tick(  # rplint: hot
         self,
@@ -1333,4 +1401,5 @@ class ShardGroupArrays:
                             break
                         bucket *= 2
         finally:
-            self.quorum_dirty |= pending
+            if pending.any():
+                self.mark_quorum_dirty(pending)

@@ -512,7 +512,7 @@ def test_prewarm_moves_no_commit_index_it_cannot_report(monkeypatch, backend):
     arrays.voter_epoch += 1
     arrays.match_index[row, :3] = 7
     arrays.flushed_index[row, :3] = 7
-    arrays.quorum_dirty[row] = True   # as a configuration change leaves it
+    arrays.mark_quorum_dirty(row)   # as a configuration change leaves it
     before = int(arrays.commit_index[row])
     arrays.prewarm()
     assert int(arrays.commit_index[row]) == before and arrays.quorum_dirty[row]

@@ -476,7 +476,7 @@ def test_shard_arrays_scalar_vs_device_differential():
         # recompute the scalar loop performs.
         for g in range(n_groups):
             a.scalar_commit_update(g)
-        b.quorum_dirty[:] = True
+        b.mark_quorum_dirty(slice(None))
         empty = np.array([], np.int64)
         b.device_tick(empty, empty, empty, empty, empty)
         assert np.array_equal(a.commit_index, b.commit_index), (

@@ -168,9 +168,11 @@ class TestDifferential:
                 arrays.commit_index[rows], _oracle_commits(arrays, rows)
             )
 
-    def test_host_device_frame_identical(self, monkeypatch):
+    @pytest.mark.parametrize("n_forced", [1, 8, 9, 40, 96])
+    def test_host_device_frame_identical(self, monkeypatch, n_forced):
         """Backend parity for frame_tick: byte-identical advanced set,
-        commit_index and last_visible host vs device."""
+        commit_index, last_visible and health lanes host vs device, at
+        every bucket from 8 up to the capacity's."""
         g = 96
         results = {}
         for backend in ("host", "device"):
@@ -181,17 +183,21 @@ class TestDifferential:
             _fill_random(arrays, rows, rng)
             arrays.quorum_dirty[:] = False
             advanced = arrays.frame_tick(
-                *([np.empty(0, np.int64)] * 5), force_rows=rows
+                *([np.empty(0, np.int64)] * 5), force_rows=rows[:n_forced]
             )
             results[backend] = (
                 np.sort(np.asarray(advanced)).tobytes(),
                 arrays.commit_index[rows].tobytes(),
                 arrays.last_visible[rows].tobytes(),
+                *(getattr(arrays, lane).tobytes() for lane in _HEALTH_LANES),
             )
         assert results["host"] == results["device"]
 
 
 _EMPTY = np.empty(0, np.int64)
+# what the device fold reads back beside the lanes (host_tick computes
+# them on the host): the same values, row for row
+_HEALTH_LANES = ("health_max_lag", "health_under", "health_leaderless")
 
 
 class _Trio:
@@ -239,7 +245,7 @@ class _Trio:
         np.testing.assert_array_equal(
             adv["dev"], adv[against], err_msg=f"advanced rows, step {step}"
         )
-        for lane in GroupState._fields:
+        for lane in GroupState._fields + _HEALTH_LANES:
             np.testing.assert_array_equal(
                 getattr(a, lane), getattr(b, lane),
                 err_msg=f"{lane} against {against}, step {step}",
@@ -301,19 +307,21 @@ class TestResidentState:
     host wrote meanwhile to rows a fold does not touch, every fold has
     to leave the mirrors and the advanced rows of a whole upload."""
 
+    @pytest.mark.parametrize("cap", [16, 64])
     @pytest.mark.parametrize("seed", [3, 17, 2026])
     @pytest.mark.parametrize("by_the_rules", [True, False],
                              ids=["host_and_fresh", "fresh_wild"])
-    def test_folds_between_host_writes(self, monkeypatch, seed, by_the_rules):
+    def test_folds_between_host_writes(self, monkeypatch, seed, by_the_rules, cap):
         """A seeded history of folds and host-side writes, with a
-        capacity doubling in the middle. `host_and_fresh`: a written
+        capacity doubling in the middle, from 16 rows and from 64.
+        `host_and_fresh`: a written
         row gets no reply before a fold is forced to recompute it (the
         broker's own rule, and what makes host_tick's incremental
         sweep comparable), and all three agree after every fold.
         `fresh_wild`: replies land on written rows at once; the
         resident state still agrees with a whole upload."""
         rng = np.random.default_rng(seed)
-        cap, steps = 16, 36
+        steps = 36
         trio = _Trio(monkeypatch, cap)
         rows = [trio.each(lambda a: a.alloc_row()) for _ in range(cap)]
         for row in rows:
@@ -328,7 +336,7 @@ class TestResidentState:
         waiting: list[int] = []  # written, not yet forced
         for step in range(steps):
             if step == steps // 2:
-                # 17th row: every lane doubles, the resident state goes
+                # one row past the capacity: every lane doubles, the resident state goes
                 rows.append(trio.each(lambda a: a.alloc_row()))
                 assert trio.arrays["dev"].capacity == 2 * cap
                 assert trio.arrays["dev"]._resident is not None  # _grow's prewarm
@@ -431,8 +439,8 @@ assert per_fold[64] == per_fold[2048], per_fold
 slots = a.replica_slots
 for n, (up, down) in zip((1, 3, 4, 5, 20, 40), per_fold[64]):
     bucket = max(8, 1 << (2 * n - 1).bit_length())
-    assert up == bucket * (11 + 5 * slots) * 8, (n, up)
-    assert down == bucket * (2 + 3 * slots) * 8, (n, down)
+    assert up == bucket * (13 + 5 * slots) * 8, (n, up)
+    assert down == bucket * (5 + 3 * slots) * 8, (n, down)
 print("FOLD-COST-OK")
 """
 
@@ -480,7 +488,7 @@ for cap, most, programs in ((32, 100, 5), (64, 10, 4)):
         r = np.resize(rows, n_replies)
         s = np.resize(np.arange(1, 8, dtype=np.int64), n_replies)
         off = np.full(n_replies, seq, np.int64)
-        a.quorum_dirty[rows[:n_dirty]] = True
+        a.mark_quorum_dirty(rows[:n_dirty])
         a.device_tick(r, s, off, off, off, force_rows=rows[:1])
     assert compileguard.reports() == [], compileguard.reports()
     st = devplane.status()
