@@ -33,7 +33,7 @@ from typing import TYPE_CHECKING, Optional
 
 from ..models.fundamental import KAFKA_INTERNAL_NS, NTP, TopicNamespace
 from ..models.record import RecordBatch, RecordBatchBuilder, RecordBatchType
-from ..observability import trace
+from ..observability import devplane, trace
 from ..raft.consensus import NotLeaderError, ReplicateTimeout
 from ..rpc.server import Service, method
 from ..utils import serde
@@ -371,34 +371,45 @@ class TxCoordinator:
         payload: bytes,
         deadline: float,
         what: str,
-    ) -> None:
+    ) -> tuple[bool, int]:
         """Retry loop shared by both marker targets: resolve the
         leader of `ntp`, apply locally or RPC, retry on leadership
-        churn until the deadline."""
+        churn until the deadline. Returns (whether the leader that
+        took it was another broker's, the rounds past the first), which
+        it also counts (`devplane.status()["tx_markers"]`: `remote`,
+        `retried`)."""
+        retries = 0
         while True:
             leader = self.broker.metadata_cache.leader_of(ntp)
+            remote = leader != self.broker.node_id
             try:
-                if leader == self.broker.node_id:
+                if not remote:
                     await local_apply()
-                    return
+                    break
                 if leader is not None:
                     raw = await self.broker.send_rpc(
                         leader, method_id, payload, 5.0
                     )
                     reply = _MarkerReply.decode(raw)
                     if reply.code == "":
-                        return
+                        break
                     if not reply.code.startswith("not_leader"):
                         raise RuntimeError(reply.code)
             except (NotLeaderError, ConnectionError, asyncio.TimeoutError):
                 pass
             if asyncio.get_event_loop().time() > deadline:
                 raise TimeoutError(f"{what} delivery timed out")
+            retries += 1
             await asyncio.sleep(0.05)
+        if remote:
+            devplane.count_tx_marker("remote")
+        if retries:
+            devplane.count_tx_marker("retried")
+        return remote, retries
 
     async def _marker_to_partition(
         self, ntp: NTP, pid: int, epoch: int, commit: bool, deadline: float
-    ) -> None:
+    ) -> tuple[bool, int]:
         async def local() -> None:
             p = self.broker.partition_manager.get(ntp)
             if p is None:
@@ -413,9 +424,11 @@ class TxCoordinator:
             epoch=epoch,
             commit=1 if commit else 0,
         ).encode()
-        await self._deliver(
+        delivered = await self._deliver(
             ntp, local, WRITE_TX_MARKER, req, deadline, f"marker to {ntp}"
         )
+        devplane.count_tx_marker("data")
+        return delivered
 
     async def _marker_to_group(
         self, group: str, pid: int, epoch: int, commit: bool, deadline: float
@@ -444,6 +457,7 @@ class TxCoordinator:
             deadline,
             f"group marker to {group}",
         )
+        devplane.count_tx_marker("group")
 
     async def _complete(self, meta: TxMeta, commit: bool) -> None:
         """Phase 2+3: deliver markers, then clear to EMPTY. Caller
@@ -451,12 +465,16 @@ class TxCoordinator:
         In-memory state mutates only after the EMPTY record is durable
         — a failed persist must leave memory matching the log."""
         deadline = asyncio.get_event_loop().time() + 10.0
-        # markers sent -> every partition and group answered
-        with trace.span("tx.markers", "wait"):
+        # markers sent -> every partition and group answered, one
+        # after another
+        with trace.span("tx.markers", "wait", partitions=len(meta.partitions)):
             for ntp in sorted(meta.partitions, key=str):
-                await self._marker_to_partition(
-                    ntp, meta.pid, meta.epoch, commit, deadline
-                )
+                # sent -> the partition's leader wrote it, acks=all
+                with trace.span("tx.marker", "wait") as sp:
+                    remote, retries = await self._marker_to_partition(
+                        ntp, meta.pid, meta.epoch, commit, deadline
+                    )
+                    sp.tag(remote=int(remote), retries=retries)
             for group in sorted(meta.groups):
                 # sent -> the group coordinator's write acknowledged
                 with trace.span("tx.group_marker", "wait"):

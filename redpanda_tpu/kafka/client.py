@@ -36,6 +36,17 @@ from .protocol import produce_fast
 _SIZE = struct.Struct(">i")
 
 
+def _frame(head: bytes, body: bytes) -> tuple:
+    """A request's frame as the buffers writelines takes. An empty body
+    (ApiVersions v0-v2) is left out: CPython 3.12's socket transport
+    keeps a zero-length buffer that ends a writelines once the rest is
+    sent, so the socket's writer stays registered, the loop polls it on
+    every pass until the connection's next request, and close() waits
+    on it for ever."""
+    size = _SIZE.pack(len(head) + len(body))
+    return (size, head, body) if body else (size, head)
+
+
 class KafkaClientError(Exception):
     def __init__(self, code: int, context: str = ""):
         try:
@@ -297,9 +308,7 @@ class BrokerConnection:
                 # writelines joins once in the transport — no
                 # intermediate size+head+body concat of MB-scale
                 # produce frames here
-                self._writer.writelines(
-                    (_SIZE.pack(len(head) + len(body)), head, body)
-                )
+                self._writer.writelines(_frame(head, body))
                 await self._writer.drain()
             # belt-and-braces: if the read loop died while we drained,
             # our future was in _pending and is already failed; this
@@ -337,9 +346,7 @@ class BrokerConnection:
             rx = self._rx_proto
             if rx is not None:
                 rx.rx_t0 = -1.0  # arm: next data_received is the reply
-            self._writer.writelines(
-                (_SIZE.pack(len(head) + len(body)), head, body)
-            )
+            self._writer.writelines(_frame(head, body))
             await self._writer.drain()
             try:
                 raw_size = await self._reader.readexactly(4)
@@ -1420,54 +1427,99 @@ class TransactionalProducer:
         partition: int,
         records: Sequence[tuple[bytes | None, bytes | None]],
     ) -> int:
+        """One batch to one partition inside the open transaction; its
+        base offset."""
+        return (await self.produce_batches(topic, {partition: records}))[partition]
+
+    async def produce_batches(
+        self,
+        topic: str,
+        records: dict[int, Sequence[tuple[bytes | None, bytes | None]]],
+    ) -> dict[int, int]:
+        """Records for many partitions of `topic` inside the open
+        transaction, sent as a Kafka producer drains its accumulator:
+        the partitions not yet in the transaction go to the coordinator
+        in one AddPartitionsToTxn, each partition's records become one
+        batch with that partition's next sequence, and each leader gets
+        one ProduceRequest with every partition it leads, the requests
+        side by side. A partition answered not-leader goes again, with
+        the same batch, to its new leader. Returns {partition: base
+        offset}."""
         if self.pid < 0:
             raise RuntimeError("init() first")
-        tp = (topic, partition)
-        if tp not in self._in_tx:
-            await self._add_partitions([tp])
-            self._in_tx.add(tp)
-        seq = self._seqs.get(tp, 0)
-        builder = RecordBatchBuilder(
-            producer_id=self.pid,
-            producer_epoch=self.epoch,
-            base_sequence=seq,
-            transactional=True,
-        )
-        for key, value in records:
-            builder.add(value, key=key)
-        wire = builder.build().to_kafka_wire()
+        new = [(topic, p) for p in sorted(records) if (topic, p) not in self._in_tx]
+        if new:
+            await self._add_partitions(new)
+            self._in_tx.update(new)
+        left: dict[int, bytes] = {}
+        for p, recs in records.items():
+            builder = RecordBatchBuilder(
+                producer_id=self.pid,
+                producer_epoch=self.epoch,
+                base_sequence=self._seqs.get((topic, p), 0),
+                transactional=True,
+            )
+            for key, value in recs:
+                builder.add(value, key=key)
+            left[p] = builder.build().to_kafka_wire()
+        bases: dict[int, int] = {}
+        failed: Optional[KafkaClientError] = None
         retry = _LeaderRetry(self.client.LEADER_WAIT_S)
-        while retry.more():
+        while left and failed is None and retry.more():
             await retry.pause()
-            conn = await self.client.leader_conn(
-                topic, partition, refresh=retry.refresh
+            if retry.refresh:
+                await self.client.metadata([topic])
+            by_leader: dict[BrokerConnection, list[int]] = {}
+            for p in sorted(left):
+                conn = await self.client.leader_conn(topic, p)
+                by_leader.setdefault(conn, []).append(p)
+            answers = await asyncio.gather(
+                *(self._produce_to(conn, topic, {p: left[p] for p in ps})
+                  for conn, ps in by_leader.items())
             )
-            v = conn.pick_version(PRODUCE, 7)
-            req = Msg(
-                transactional_id=self.tx_id,
-                acks=-1,
-                timeout_ms=10000,
-                topics=[
-                    Msg(
-                        name=topic,
-                        partitions=[Msg(index=partition, records=wire)],
+            for pr in (pr for resp in answers for pr in resp):
+                p = pr.index
+                if pr.error_code == int(ErrorCode.not_leader_for_partition):
+                    continue   # asked again, after a metadata refresh
+                if pr.error_code != 0:
+                    failed = failed or KafkaClientError(
+                        pr.error_code, f"tx produce {topic}/{p}"
                     )
-                ],
+                else:
+                    bases[p] = pr.base_offset
+                    self._seqs[(topic, p)] = (
+                        self._seqs.get((topic, p), 0) + len(records[p])
+                    )
+                    del left[p]
+        if failed is not None:
+            raise failed
+        if left:
+            raise KafkaClientError(
+                int(ErrorCode.not_leader_for_partition),
+                f"tx produce {topic}/{min(left)}",
             )
-            resp = await conn.request(PRODUCE, req, v)
-            pr = resp.responses[0].partition_responses[0]
-            if pr.error_code == int(ErrorCode.not_leader_for_partition):
-                continue
-            if pr.error_code != 0:
-                raise KafkaClientError(
-                    pr.error_code, f"tx produce {topic}/{partition}"
+        return bases
+
+    async def _produce_to(
+        self, conn: BrokerConnection, topic: str, wires: dict[int, bytes]
+    ) -> list[Msg]:
+        """One ProduceRequest of this transaction to one broker: a
+        batch for each partition of `wires`. Its partition responses."""
+        req = Msg(
+            transactional_id=self.tx_id,
+            acks=-1,
+            timeout_ms=10000,
+            topics=[
+                Msg(
+                    name=topic,
+                    partitions=[
+                        Msg(index=p, records=w) for p, w in sorted(wires.items())
+                    ],
                 )
-            self._seqs[tp] = seq + len(records)
-            return pr.base_offset
-        raise KafkaClientError(
-            int(ErrorCode.not_leader_for_partition),
-            f"tx produce {topic}/{partition}",
+            ],
         )
+        resp = await conn.request(PRODUCE, req, conn.pick_version(PRODUCE, 7))
+        return [pr for t in resp.responses for pr in t.partition_responses]
 
     async def send_offsets(
         self,

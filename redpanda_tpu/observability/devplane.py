@@ -127,6 +127,13 @@ _GROUPS = registry.counter(
     "(OffsetFetch requests that answered a partition "
     "UNSTABLE_OFFSET_COMMIT)",
 )
+_TX_MARKERS = registry.counter(
+    "devplane_tx_markers_total",
+    "transaction markers the tx coordinator delivered, by event: data "
+    "(a data partition's control batch), group (a group's marker), "
+    "remote (either, delivered over RPC to another broker's leader), "
+    "retried (either, delivered after more than one round)",
+)
 _TICK_TRANSFERS = registry.counter(
     "devplane_tick_transfers_total",
     "device transfers/dispatches observed on the steady tick path "
@@ -153,6 +160,7 @@ TRANSFER_FAMILY = _TRANSFER_BYTES.name
 STATE_SEEDS_FAMILY = _STATE_SEEDS.name
 SEQUENCES_FAMILY = _SEQUENCES.name
 GROUPS_FAMILY = _GROUPS.name
+TX_MARKERS_FAMILY = _TX_MARKERS.name
 TICK_TRANSFER_FAMILY = _TICK_TRANSFERS.name
 COMPILES_FAMILY = _COMPILES.name
 COMPILE_SECS_FAMILY = _COMPILE_SECS.name
@@ -399,6 +407,18 @@ def count_group(event: str, n: int = 1) -> None:
         _GROUPS.inc(float(n), event=event)
 
 
+#: the events `count_tx_marker` takes, each always served (0 until it moves)
+TX_MARKER_EVENTS = ("data", "group", "remote", "retried")
+
+
+def count_tx_marker(event: str) -> None:
+    """One transaction marker delivered (`event` from TX_MARKER_EVENTS;
+    cluster/tx_coordinator.py `_complete`). A produce with producer id
+    -1 never gets here."""
+    if ENABLED:
+        _TX_MARKERS.inc(event=event)
+
+
 def count_state_seed() -> None:
     """The tick had no resident device state left and uploaded its
     lanes whole (ShardGroupArrays._fold_on_device)."""
@@ -567,6 +587,7 @@ def merged_status(snaps: list) -> dict:
     state_seeds = 0.0
     sequences: dict[str, float] = {}
     groups: dict[str, float] = dict.fromkeys(GROUP_EVENTS, 0.0)
+    markers: dict[str, float] = dict.fromkeys(TX_MARKER_EVENTS, 0.0)
     tick_violations = 0.0
     compiles: dict[str, dict] = {}
     jit_cache: dict[str, float] = {}
@@ -593,6 +614,9 @@ def merged_status(snaps: list) -> dict:
                 elif fam.name == GROUPS_FAMILY and "event" in lab:
                     e = lab["event"]
                     groups[e] = groups.get(e, 0.0) + s.value
+                elif fam.name == TX_MARKERS_FAMILY and "event" in lab:
+                    e = lab["event"]
+                    markers[e] = markers.get(e, 0.0) + s.value
                 elif fam.name == TICK_TRANSFER_FAMILY:
                     tick_violations += s.value
                 elif fam.name == COMPILES_FAMILY and "kernel" in lab:
@@ -653,6 +677,7 @@ def merged_status(snaps: list) -> dict:
             k: int(v) for k, v in sorted(sequences.items())
         },
         "group_coordinator": {k: int(v) for k, v in sorted(groups.items())},
+        "tx_markers": {k: int(v) for k, v in sorted(markers.items())},
         "tick_violations": int(tick_violations),
         "frame_ms": {
             k: _hist_digest(c) for k, c in sorted(frame_hist.items())

@@ -118,6 +118,25 @@ def test_list_offsets_and_empty_fetch(tmp_path):
     asyncio.run(run())
 
 
+@pytest.mark.parametrize("serial_reads", [False, True])
+def test_a_connection_idle_since_api_versions_closes(tmp_path, serial_reads):
+    """ApiVersions has an empty body: the connection's first request
+    leaves nothing buffered in its transport (a zero-length buffer
+    would never drain), so a connection that made no other request
+    closes at once."""
+    async def run():
+        async with broker_cluster(tmp_path, 1) as brokers:
+            client = KafkaClient([b.kafka_advertised for b in brokers],
+                                 serial_reads=serial_reads)
+            conn = await client.any_conn()
+            assert conn.api_versions
+            await asyncio.sleep(0.05)
+            assert not conn._writer.transport._buffer
+            await asyncio.wait_for(client.close(), 5.0)
+
+    asyncio.run(run())
+
+
 def test_create_errors(tmp_path):
     async def run():
         async with broker_cluster(tmp_path, 1) as brokers:

@@ -466,3 +466,143 @@ def test_send_offsets_finds_the_group_s_coordinator_once(tmp_path, monkeypatch):
     # six transactions: one lookup of the group's coordinator (key type
     # 0), and none of the transaction coordinator's, which init found
     assert asyncio.run(_find_coordinator_once(tmp_path, monkeypatch)) == [0]
+
+
+# -- transactions that span many partitions -----------------------------
+
+
+async def _many_partitions(tmp_path, partitions, monkeypatch):
+    """One transactional producer writes one batch to each of
+    `partitions` partitions of a 3-broker, RF=3 topic in one transaction
+    (one AddPartitionsToTxn, one ProduceRequest a leader), commits, does
+    it again and aborts, and a third time and commits; each partition is
+    read `read_committed` and `read_uncommitted`."""
+    from redpanda_tpu.kafka import client as client_mod
+    from redpanda_tpu.kafka.protocol import PRODUCE
+    from redpanda_tpu.kafka.protocol.tx_apis import ADD_PARTITIONS_TO_TXN
+
+    sent = []
+    request = client_mod.BrokerConnection.request
+    holder = {}
+
+    async def counted(self, api, req, version):
+        if api.key == PRODUCE.key:
+            # with the leaders as the client knew them when it routed
+            # this request: an election may move one before the end
+            view = {p: leader for (t, p), leader in holder["client"]._leaders.items()
+                    if t == "t"}
+            sent.append((self, sorted(p.index for t in req.topics for p in t.partitions),
+                         view))
+        elif api.key == ADD_PARTITIONS_TO_TXN.key:
+            sent.append(("add", sorted(p for t in req.topics for p in t.partitions)))
+        return await request(self, api, req, version)
+
+    monkeypatch.setattr(client_mod.BrokerConnection, "request", counted)
+    async with broker_cluster(tmp_path, 3) as brokers:
+        async with client_for(brokers) as client:
+            holder["client"] = client
+            await client.create_topic("t", partitions=partitions, replication_factor=3)
+            tx = TransactionalProducer(client, "tx-many")
+            await tx.init()
+            rounds = []
+            for n, commit in enumerate((True, False, True)):
+                tx.begin()
+                sent.clear()
+                bases = await tx.produce_batches("t", {
+                    p: [(b"k%d.%d.%d" % (n, p, i), b"v%d" % n) for i in range(p % 3 + 1)]
+                    for p in range(partitions)})
+                rounds.append((list(sent), bases))
+                await (tx.commit() if commit else tx.abort())
+            got = {}
+            for p in range(partitions):
+                committed = await client.fetch("t", p, 0, read_committed=True, max_wait_ms=50)
+                everything = await client.fetch("t", p, 0, max_wait_ms=50)
+                got[p] = (committed, everything)
+    return rounds, got
+
+
+@pytest.mark.parametrize("partitions", [3, 32])
+def test_a_transaction_of_many_partitions_commits_and_aborts_whole(
+        tmp_path, partitions, monkeypatch):
+    rounds, got = asyncio.run(_many_partitions(tmp_path, partitions, monkeypatch))
+    every = list(range(partitions))
+    first, second, third = rounds
+    # the first transaction adds every partition in one AddPartitionsToTxn
+    # and sends one ProduceRequest to each leader, with all it leads;
+    # later transactions add them again, each in one request. The
+    # requests go side by side, so the first ones, one a leader the
+    # client knew of, carry every partition; a partition answered
+    # not-leader goes again after them
+    for sent, bases in rounds:
+        assert sent[0] == ("add", every)
+        leaders = sent[1][2]
+        assert sorted(leaders) == every
+        n = len(set(leaders.values()))
+        produced = [ps for _conn, ps, _view in sent[1:n + 1]]
+        assert sorted(p for ps in produced for p in ps) == every
+        assert len({conn for conn, _ps, _view in sent[1:n + 1]}) == n
+        assert sorted(leaders[ps[0]] for ps in produced) == sorted(set(leaders.values()))
+        for ps in produced:
+            assert len({leaders[p] for p in ps}) == 1
+        assert sorted(bases) == every
+    for p in every:
+        committed, everything = got[p]
+        n = p % 3 + 1
+        # a commit marker after the first and the third batch, an abort
+        # marker after the second: each takes an offset
+        assert first[1][p] == 0
+        assert second[1][p] == n + 1 and third[1][p] == 2 * n + 2
+        assert [k for _o, k, _v in committed] == (
+            [b"k0.%d.%d" % (p, i) for i in range(n)]
+            + [b"k2.%d.%d" % (p, i) for i in range(n)])
+        assert [o for o, _k, _v in committed] == (
+            list(range(n)) + list(range(2 * n + 2, 3 * n + 2)))
+        assert len(everything) == 3 * n
+
+
+async def _read_committed_over_many_aborted_ranges(tmp_path, partitions, rounds):
+    """`rounds` transactions of one producer over every partition, every
+    other one aborted, then one read_committed FETCH of all partitions."""
+    from redpanda_tpu.kafka.client import decode_record_set
+    from redpanda_tpu.kafka.protocol import FETCH, Msg
+
+    async with broker_cluster(tmp_path, 1) as brokers:
+        async with client_for(brokers) as client:
+            await client.create_topic("t", partitions=partitions, replication_factor=1)
+            tx = TransactionalProducer(client, "tx-ranges")
+            await tx.init()
+            for n in range(rounds):
+                tx.begin()
+                await tx.produce_batches("t", {
+                    p: [(b"%d" % n, b"%d" % p)] * (n % 2 + 1) for p in range(partitions)})
+                await (tx.abort() if n % 2 else tx.commit())
+            conn = await client.leader_conn("t", 0)
+            resp = await conn.request(FETCH, Msg(
+                rack_id="", replica_id=-1, max_wait_ms=0, min_bytes=0, max_bytes=1 << 24,
+                isolation_level=1, session_id=0, session_epoch=-1, forgotten_topics_data=[],
+                topics=[Msg(topic="t", partitions=[
+                    Msg(partition=p, current_leader_epoch=-1, fetch_offset=0,
+                        log_start_offset=0, partition_max_bytes=1 << 20)
+                    for p in range(partitions)])]), conn.pick_version(FETCH, 11))
+            out = {}
+            for pr in resp.responses[0].partitions:
+                aborted = [(a.producer_id, a.first_offset) for a in pr.aborted_transactions]
+                out[pr.partition_index] = (
+                    pr.error_code, pr.high_watermark, pr.last_stable_offset, aborted,
+                    decode_record_set(pr.records, aborted=aborted))
+    return tx.pid, out
+
+
+def test_read_committed_drops_every_aborted_range_of_every_partition(tmp_path):
+    partitions, rounds = 8, 10
+    pid, out = asyncio.run(
+        _read_committed_over_many_aborted_ranges(tmp_path, partitions, rounds))
+    assert sorted(out) == list(range(partitions))
+    for p, (code, hw, lso, aborted, records) in out.items():
+        # committed transactions hold one record, aborted ones two; each
+        # transaction ends with its marker
+        assert code == 0 and hw == lso == (rounds // 2) * (1 + 2 + 2)
+        # each aborted transaction named once, at its first offset
+        assert aborted == [(pid, 5 * k + 2) for k in range(rounds // 2)]
+        assert [(k, v) for _o, k, v in records] == [
+            (b"%d" % n, b"%d" % p) for n in range(0, rounds, 2)]

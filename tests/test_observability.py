@@ -1386,13 +1386,19 @@ def test_the_transaction_path_s_spans_and_counters(tmp_path, window, monkeypatch
         assert len(named[name]) == 3
         assert {parent_of(s) for s in named[name]} == {"tx.end"}, name
     # one broker: the marker is written by a local call, under the wait
-    assert {parent_of(s) for s in named["tx.marker_append"]} == {"tx.markers"}
+    # for its partition, which hangs under the markers' wait
+    assert {parent_of(s) for s in named["tx.marker_append"]} == {"tx.marker"}
+    assert {parent_of(s) for s in named["tx.marker"]} == {"tx.markers"}
+    assert [s[7]["partitions"] for s in named["tx.markers"]] == [1, 1, 1]
+    # a transaction of one partition: one marker span, local, first time
+    assert [(s[7]["remote"], s[7]["retries"]) for s in named["tx.marker"]] == [(0, 0)] * 3
+    assert digest["tx_markers"] == {"data": 3, "group": 0, "remote": 0, "retried": 0}
     # the third transaction stored nothing (its batch was a duplicate):
     # there is nothing open for its marker to close, and none is written
     assert sorted(s[7]["commit"] for s in named["tx.marker_append"]) == [0, 1]
     # a coordinator write replicates under its stage
     under = {parent_of(s) for s in named["raft.append"]}
-    assert {"tx.add_partitions", "tx.prepare", "tx.markers", "tx.complete",
+    assert {"tx.add_partitions", "tx.prepare", "tx.marker", "tx.complete",
             "produce.ack_wait"} <= under
     assert all(s[7]["batches"] == s[7]["items"] for s in named["raft.append"])
     # the parked fetch: one pass that parks it, one after the abort's
@@ -1432,6 +1438,120 @@ def test_a_pass_through_produce_and_fetch_open_no_transaction_span(tmp_path, win
     assert [s[7]["reads"] for s in rows if s[0] == "kafka.fetch"] == [1]
     assert "producer_sequences" in devplane.merged_status([]) \
         and devplane.merged_status([])["producer_sequences"] == {}
+
+
+async def _three_brokers_transact(tmp_path, window):
+    """One transaction over three partitions led by three brokers, and
+    one over the same three that also commits a group's offset; returns
+    the raw spans, the devplane digest, each partition's leader and the
+    transaction coordinator's broker."""
+    from redpanda_tpu.kafka.client import TransactionalProducer
+    from redpanda_tpu.kafka.protocol import Msg
+    from redpanda_tpu.kafka.protocol.group_apis import FIND_COORDINATOR
+    from redpanda_tpu.observability import devplane
+
+    async def coordinator_of(key: str, key_type: int) -> int:
+        conn = await client.any_conn()
+        found = await conn.request(FIND_COORDINATOR, Msg(key=key, key_type=key_type),
+                                   conn.pick_version(FIND_COORDINATOR, 1))
+        return found.node_id
+
+    async with cluster(tmp_path, n=3) as (_net, brokers):
+        client = KafkaClient([b.kafka_advertised for b in brokers])
+        try:
+            await client.create_topic("fan", partitions=3, replication_factor=1)
+            for p in range(3):   # elected: the metadata names every leader
+                await client.produce("fan", p, [(None, b"first")])
+            md = await client.metadata(["fan"])
+            leaders = {q.partition_index: q.leader_id for q in md.topics[0].partitions}
+            tx = TransactionalProducer(client, "fan-1")
+            await tx.init()
+            await client.group("fan-group").coordinator()   # made and led
+            coordinators = (await coordinator_of("fan-1", 1),
+                            await coordinator_of("fan-group", 0))
+            devplane.reset()
+            window.keep_raw = True
+            window.reset()
+            tx.begin()
+            await tx.produce_batches("fan", {p: [(b"k", b"v%d" % p)] for p in range(3)})
+            await tx.commit()
+            tx.begin()
+            await tx.produce_batches("fan", {p: [(b"k", b"w%d" % p)] for p in range(3)})
+            await tx.send_offsets("fan-group", {("fan", 0): 1})
+            await tx.abort()
+            await asyncio.sleep(0.02)
+            return (window.status()["spans"], devplane.merged_status([devplane.snapshot()]),
+                    leaders, coordinators)
+        finally:
+            await client.close()
+
+
+@needs_trace
+def test_each_data_marker_is_a_span_of_its_own_under_the_markers(
+        tmp_path, window, monkeypatch):
+    from redpanda_tpu.observability import devplane
+
+    monkeypatch.setattr(devplane, "ENABLED", True)
+    rows, digest, leaders, (coordinator, group_coordinator) = asyncio.run(
+        _three_brokers_transact(tmp_path, window))
+    devplane.reset()
+    named = {}
+    for s in rows:
+        named.setdefault(s[0], []).append(s)
+    by_id = {s[4]: s for s in rows}
+    markers = named["tx.markers"]
+    assert [s[7]["partitions"] for s in markers] == [3, 3]
+    assert {s[1] for s in named["tx.marker"]} == {"wait"}
+    remote = sum(leader != coordinator for leader in leaders.values())
+    for whole in markers:
+        kids = sorted((s for s in named["tx.marker"] if s[5] == whole[4]),
+                      key=lambda s: s[2])
+        # one a data partition, one after another, inside their parent
+        assert len(kids) == 3
+        assert sum(s[7]["remote"] for s in kids) == remote
+        assert all(s[7]["retries"] == 0 for s in kids)
+        for a, b in zip(kids, kids[1:]):
+            assert a[2] + a[3] <= b[2]
+        assert whole[2] <= kids[0][2] and kids[-1][2] + kids[-1][3] <= whole[2] + whole[3]
+        assert sum(s[3] for s in kids) <= whole[3]
+    # the group's marker has a span of its own and no `tx.marker`
+    (group,) = named["tx.group_marker"]
+    assert by_id[group[5]][0] == "tx.markers"
+    assert len(named["tx.marker"]) == 6
+    # placed over the three brokers: the coordinator's own and the others'
+    assert len(set(leaders.values())) == 3 and remote == 2
+    assert digest["tx_markers"] == {
+        "data": 6, "group": 1,
+        "remote": 2 * remote + (group_coordinator != coordinator), "retried": 0}
+
+
+@needs_trace
+def test_a_plain_produce_and_fetch_count_no_marker(tmp_path, window, monkeypatch):
+    from redpanda_tpu.observability import devplane
+
+    monkeypatch.setattr(devplane, "ENABLED", True)
+
+    async def drive():
+        async with cluster(tmp_path, n=1) as (_net, brokers):
+            client = KafkaClient([brokers[0].kafka_advertised])
+            try:
+                await client.create_topic("plain", partitions=2, replication_factor=1)
+                devplane.reset()
+                window.keep_raw = True
+                window.reset()
+                for p in range(2):
+                    await client.produce("plain", p, [(None, b"v")])
+                    assert len(await client.fetch("plain", p, 0)) == 1
+                await asyncio.sleep(0.02)
+                return window.status()["spans"], devplane.merged_status(
+                    [devplane.snapshot()])
+            finally:
+                await client.close()
+
+    rows, digest = asyncio.run(drive())
+    devplane.reset()
+    assert not {s[0] for s in rows} & {"tx.markers", "tx.marker", "tx.group_marker"}
+    assert digest["tx_markers"] == {"data": 0, "group": 0, "remote": 0, "retried": 0}
 
 
 # -- the group coordinator's spans and counters ---------------------------
